@@ -37,7 +37,6 @@ from videop2p_tpu.obs.history import (
 )
 from videop2p_tpu.obs.ledger import RunLedger, read_ledger
 from videop2p_tpu.parallel import make_mesh
-from videop2p_tpu.parallel.ring import shard_map_compat
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -408,9 +407,10 @@ def test_replica_divergence_detects_injected_perturbation(mesh8):
     # inject a per-data-replica offset UNDER shard_map (out_specs claims
     # replication over data, the values say otherwise — exactly the bug
     # class the probe exists to catch)
-    perturbed = shard_map_compat(
+    perturbed = jax.shard_map(
         lambda v: v + jax.lax.axis_index("data").astype(jnp.float32) * 0.25,
         mesh=mesh, in_specs=(P("frames"),), out_specs=P("frames"),
+        check_vma=False,
     )(x)
     div = replica_divergence(perturbed, mesh, axes=("data",), spec=P("frames"))
     assert float(div) == 0.25

@@ -798,7 +798,8 @@ def test_fleet_collector_acceptance_healthy_vs_chaos(programs, tmp_path):
     assert h_record["signals"]["burn_alerts"] == 0
     h_events = [e for e in read_ledger(h_ledger)
                 if e["event"] == "fleet_signals"]
-    assert h_events and h_events[-1]["scale_advice"] == "hold"
+    assert h_events and h_events[-1]["scale_advice"] == "hold", (
+        h_events[-1].get("reasons"))
     assert all(not e["burn_alert"] for e in h_events)
     # the scrape loop genuinely watched all three surfaces
     assert h_record["signals"]["targets"] == 3

@@ -83,12 +83,18 @@ def test_fused_grad_falls_back_to_chunked():
 def test_auto_dispatch_off_tpu_is_dense():
     # "auto" resolves per-backend: dense (None) on CPU, fused on TPU
     assert make_frame_attention_fn("auto") is None
-    # "fused" off-TPU falls back to chunked for large sites (still exact)
+    # "fused" asked for by name off-TPU is an error at the large sites, not
+    # a quiet drop to chunked; the small-site dense path needs no kernel
+    import pytest
+
     fn = make_frame_attention_fn("fused", min_large_tokens=1024)
     q, k, v = _rand_qkv(jax.random.key(7), N=2048, D=4)
-    out = jax.jit(fn)(q, k, v)
+    with pytest.raises(RuntimeError, match="Pallas TPU kernel"):
+        jax.jit(fn)(q, k, v)
+    q, k, v = _rand_qkv(jax.random.key(7), N=64, D=4)
     np.testing.assert_allclose(
-        np.asarray(out), np.asarray(dense_frame_attention(q, k, v)), atol=1e-5
+        np.asarray(fn(q, k, v)),
+        np.asarray(dense_frame_attention(q, k, v)), atol=1e-5,
     )
 
 

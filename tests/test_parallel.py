@@ -3,14 +3,16 @@ standard fake-pod recipe, set up in conftest.py).
 
 Covers: mesh construction, ring attention exactness vs dense attention,
 sequence-parallel UNet forward equivalence, and the sharded train step.
+
+Every test but ``test_megatron_out_dot_unit`` is ``slow`` (multi-device mesh
+compiles); that one is seconds and stays in tier-1 so the partial-manual
+``jax.shard_map(axis_names={"tensor"})`` seam is guarded on every run.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-pytestmark = pytest.mark.slow
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from videop2p_tpu.parallel import (
@@ -29,6 +31,7 @@ def mesh8():
     return make_mesh((1, 8, 1))
 
 
+@pytest.mark.slow
 def test_make_mesh_validates():
     with pytest.raises(ValueError, match="devices"):
         make_mesh((3, 1, 1))
@@ -36,6 +39,7 @@ def test_make_mesh_validates():
     assert m.shape == {"data": 2, "frames": 4, "tensor": 1}
 
 
+@pytest.mark.slow
 def test_ring_attention_matches_dense(mesh8):
     B, H, S, D = 2, 3, 16, 8  # S=16 over 8 shards → 2 per shard
     kq, kk, kv = jax.random.split(jax.random.key(0), 3)
@@ -50,6 +54,7 @@ def test_ring_attention_matches_dense(mesh8):
     np.testing.assert_allclose(np.asarray(out_ring), np.asarray(out_dense), atol=1e-5)
 
 
+@pytest.mark.slow
 def test_ring_attention_bf16(mesh8):
     B, H, S, D = 1, 2, 8, 4
     q = jax.random.normal(jax.random.key(0), (B, H, S, D), jnp.bfloat16)
@@ -60,6 +65,7 @@ def test_ring_attention_bf16(mesh8):
     assert np.isfinite(np.asarray(out, dtype=np.float32)).all()
 
 
+@pytest.mark.slow
 def test_sequence_parallel_unet_forward(mesh8):
     """The full UNet forward under jit with the frame axis sharded across the
     8-device mesh must equal the single-device result — XLA inserts the
@@ -86,6 +92,7 @@ def test_sequence_parallel_unet_forward(mesh8):
     )
 
 
+@pytest.mark.slow
 def test_sharded_train_step(mesh8):
     """train_step jitted over the mesh with frame-sharded latents: loss must
     match the unsharded step bit-for-better-than-bf16 tolerance (the psum the
@@ -117,6 +124,7 @@ def test_sharded_train_step(mesh8):
     assert int(new_state.step) == 1
 
 
+@pytest.mark.slow
 def test_param_shardings_tensor_parallel(mesh8):
     """Tensor-parallel rules: qkv kernels column-shard, to_out row-shards,
     everything else replicates."""
@@ -142,6 +150,7 @@ def test_param_shardings_tensor_parallel(mesh8):
     jax.device_put(params, shardings)
 
 
+@pytest.mark.slow
 def test_ring_temporal_unet_forward(mesh8):
     """UNet forward with ring attention at the temporal sites over the
     frame-sharded mesh must equal the dense single-device forward (the
@@ -169,6 +178,7 @@ def test_ring_temporal_unet_forward(mesh8):
     )
 
 
+@pytest.mark.slow
 def test_sharded_frame_attention_matches_dense(mesh8):
     """The shard_map frame-attention wrapper (queries split over frames,
     frame-0 K/V replicated) must equal the single-device kernel — both at the
@@ -214,6 +224,7 @@ def test_sharded_frame_attention_matches_dense(mesh8):
     )
 
 
+@pytest.mark.slow
 def test_sharded_controlled_edit_matches_unsharded(mesh8):
     """The full attention-controlled edit (refine + equalizer + LocalBlend)
     jitted over the frame-sharded mesh must match the single-device edit —
@@ -263,6 +274,7 @@ def test_sharded_controlled_edit_matches_unsharded(mesh8):
     )
 
 
+@pytest.mark.slow
 def test_sharded_cached_source_edit_matches_unsharded(mesh8):
     """The cached-source fast mode (pipelines/cached.py) under a (1,4,2)
     frames×tensor mesh: GSPMD shards the capture trees (cross maps over the
@@ -348,6 +360,7 @@ def test_sharded_cached_source_edit_matches_unsharded(mesh8):
     np.testing.assert_array_equal(np.asarray(out28[0]), np.asarray(s_x0[0]))
 
 
+@pytest.mark.slow
 def test_sharded_group_norm_matches_reference(mesh8):
     """The shard_map GroupNorm wrapper (VERDICT r5 next-round #5): the
     fused one-pass kernel runs per-shard on sample-split slabs and must
@@ -396,6 +409,7 @@ def test_sharded_group_norm_matches_reference(mesh8):
     )
 
 
+@pytest.mark.slow
 def test_setup_mesh_wires_sharded_group_norm():
     """setup_mesh no longer forces group_norm='xla' on sharded meshes — it
     wires the shard_map GroupNorm seam instead, leaving the config knob
@@ -413,6 +427,7 @@ def test_setup_mesh_wires_sharded_group_norm():
     assert bundle.unet.config.group_norm == "auto"  # knob not clobbered
 
 
+@pytest.mark.slow
 def test_hybrid_mesh_single_slice_and_distributed_noop():
     """make_hybrid_mesh on one slice equals the plain reshape;
     initialize_distributed is a no-op without multi-host config."""
@@ -433,6 +448,7 @@ def _dense_reference(q, k, v):
                       v.astype(jnp.float32))
 
 
+@pytest.mark.slow
 def test_ring_variants_match_dense(mesh8):
     """ISSUE 10 satellite: every rotation schedule — the serial baseline,
     the double-buffered n−1 default, and the bidirectional split-halves
@@ -454,6 +470,7 @@ def test_ring_variants_match_dense(mesh8):
         ring_attention_sharded(q, k, v, mesh8, variant="bogus")
 
 
+@pytest.mark.slow
 def test_ring_variants_odd_shards_and_odd_halves():
     """Odd shard counts (a 5-device sub-mesh) and an odd per-shard
     sequence length (unequal bidirectional halves) stay exact."""
@@ -472,6 +489,7 @@ def test_ring_variants_odd_shards_and_odd_halves():
                                    atol=1e-5, err_msg=variant)
 
 
+@pytest.mark.slow
 def test_ring_variants_bf16(mesh8):
     """bf16 inputs: fp32 accumulators inside, bf16 out, finite — and a
     1-frame-per-shard bidir degenerates to overlap instead of failing."""
@@ -497,8 +515,7 @@ def test_megatron_out_dot_unit():
     dn = (((2,), (0,)), ((), ()))
     lhs = jax.random.normal(jax.random.key(0), (2, 8, 16))
     rhs = jax.random.normal(jax.random.key(1), (16, 6))
-    # the scatter path exists under jit (partial-auto shard_map needs a
-    # surrounding trace on legacy jax); eager calls fall back to plain dot
+    # the scatter path under an outer jit, and called eagerly
     np.testing.assert_allclose(
         np.asarray(jax.jit(lambda l, r: dot(l, r, dn))(lhs, rhs)),
         np.asarray(lhs @ rhs), atol=1e-5,
@@ -522,6 +539,7 @@ def test_megatron_out_dot_unit():
     )
 
 
+@pytest.mark.slow
 def test_megatron_unet_forward_matches_gspmd(mesh8):
     """The tensor-parallel UNet forward with the explicit psum_scatter
     output seam must match both the declarative GSPMD forward and the
@@ -550,6 +568,7 @@ def test_megatron_unet_forward_matches_gspmd(mesh8):
                                atol=2e-4)
 
 
+@pytest.mark.slow
 def test_setup_mesh_ring_and_tp_knobs():
     """setup_mesh validates and wires the new schedule knobs: a bad ring
     variant / tp_collectives raises, and psum_scatter on a tp>1 mesh

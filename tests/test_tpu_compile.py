@@ -1,0 +1,155 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e — no chip.
+
+The TPU compiler is installed here and compiles for a topology that is
+described, not attached (``v5e:2x2``): it refuses what the chip's compiler
+would refuse — a slice off the tiling, a kernel over its VMEM budget, a
+kernel that cannot be partitioned — which Pallas interpret mode on the CPU
+never shows. Each case compiles one kernel at an SD-1.5 shape of the edit
+path, bare or under ``jax.shard_map`` on a four-device mesh with the specs
+``parallel/mesh.py`` uses, and asserts the kernel is IN the compiled text
+(``tpu_custom_call``). A compile that passes is not a run: nothing executes,
+no result and no time comes out of this file.
+
+Rules this file keeps (on-chip-measurement guide §2): the topology is
+described inside a module-scoped fixture that skips when it cannot be —
+never at import or collection time, never in ``conftest.py`` — because only
+one process may load the TPU library and every xdist worker imports every
+test file; all cases stay in THIS one file so one worker holds the library;
+the persistent compile cache is off around the compiles (an entry compiled
+for a described chip cannot be read back without one).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from videop2p_tpu.obs.introspect import tpu_custom_call_counts
+from videop2p_tpu.ops.attention import fused_frame_attention
+from videop2p_tpu.ops.groupnorm import fits_fused_group_norm, fused_group_norm
+from videop2p_tpu.parallel.mesh import AXIS_DATA, AXIS_FRAMES, AXIS_TENSOR
+
+# (B, F, H, N, D) of the SD-1.5 frame-attention sites that pass the
+# min_large_tokens=1024 gate: 64² and 32² latents at the 2-stream cached
+# edit batch, and the 24-frame long-video shape
+ATTENTION_SHAPES = [(2, 8, 8, 4096, 40), (2, 8, 8, 1024, 80),
+                    (2, 24, 8, 4096, 40)]
+# (rows, C) slab classes of the SD-1.5 GroupNorm sites the kernel covers
+GROUP_NORM_SLABS = [(4096, 320), (1024, 640), (256, 1280), (512, 1280)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    """``--mesh 1,4,1`` on the described devices: frames over four chips."""
+    import numpy as np
+
+    return Mesh(np.asarray(topo.devices).reshape(1, 4, 1),
+                (AXIS_DATA, AXIS_FRAMES, AXIS_TENSOR))
+
+
+def _kernels(fn, *shapes):
+    """Compile ``fn`` at abstract ``shapes``; the kernels in its text."""
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    return tpu_custom_call_counts(text)
+
+
+def _attention_shapes(shape, q_sharding, kv_sharding):
+    b, f, h, n, d = shape
+    q = jax.ShapeDtypeStruct((b, f, h, n, d), jnp.bfloat16, sharding=q_sharding)
+    kv = jax.ShapeDtypeStruct((b, h, n, d), jnp.bfloat16, sharding=kv_sharding)
+    return q, kv, kv
+
+
+@pytest.mark.parametrize("shape", ATTENTION_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_fused_frame_attention_compiles(one_chip, shape):
+    kernels = _kernels(
+        lambda q, k, v: fused_frame_attention(q, k, v, 256),
+        *_attention_shapes(shape, one_chip, one_chip),
+    )
+    assert kernels == {"fused_frame_attention": 1}
+
+
+@pytest.mark.parametrize("act", ["none", "silu"])
+@pytest.mark.parametrize("slab", GROUP_NORM_SLABS,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_fused_group_norm_compiles(one_chip, slab, act):
+    rows, c = slab
+    assert fits_fused_group_norm(rows, c, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((16, rows, c), jnp.bfloat16, sharding=one_chip)
+    sb = jax.ShapeDtypeStruct((c,), jnp.float32, sharding=one_chip)
+    kernels = _kernels(
+        functools.partial(fused_group_norm, num_groups=32, eps=1e-5, act=act),
+        x, sb, sb,
+    )
+    assert kernels == {"fused_group_norm": 1}
+
+
+@pytest.mark.parametrize("shape", ATTENTION_SHAPES[:2],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_sharded_frame_attention_compiles(mesh4, shape):
+    """The kernel under ``jax.shard_map`` with the specs of
+    ``parallel.mesh.make_sharded_frame_attention_fn``: queries over
+    ``frames``, the frame-0 K/V replicated across it."""
+    qspec = P(AXIS_DATA, AXIS_FRAMES, AXIS_TENSOR, None, None)
+    kvspec = P(AXIS_DATA, AXIS_TENSOR, None, None)
+    fn = jax.shard_map(
+        lambda q, k, v: fused_frame_attention(q, k, v, 256), mesh=mesh4,
+        in_specs=(qspec, kvspec, kvspec), out_specs=qspec, check_vma=False,
+    )
+    kernels = _kernels(fn, *_attention_shapes(
+        shape, NamedSharding(mesh4, qspec), NamedSharding(mesh4, kvspec)
+    ))
+    assert kernels == {"fused_frame_attention": 1}
+
+
+@pytest.mark.parametrize("slab", GROUP_NORM_SLABS[:2],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_sharded_group_norm_compiles(mesh4, slab):
+    """The kernel under ``jax.shard_map`` with the specs of
+    ``parallel.mesh.make_sharded_group_norm_fn``: the sample axis over
+    ``data × frames``, scale and bias replicated."""
+    rows, c = slab
+    sample_spec = P((AXIS_DATA, AXIS_FRAMES), None, None)
+    fn = jax.shard_map(
+        functools.partial(fused_group_norm, num_groups=32, eps=1e-5,
+                          act="silu"),
+        mesh=mesh4, in_specs=(sample_spec, P(None), P(None)),
+        out_specs=sample_spec, check_vma=False,
+    )
+    x = jax.ShapeDtypeStruct((16, rows, c), jnp.bfloat16,
+                             sharding=NamedSharding(mesh4, sample_spec))
+    sb = jax.ShapeDtypeStruct((c,), jnp.float32,
+                              sharding=NamedSharding(mesh4, P(None)))
+    kernels = _kernels(fn, x, sb, sb)
+    assert kernels == {"fused_group_norm": 1}

@@ -100,24 +100,31 @@ def make_run_ledger(
     return led
 
 
-def enable_compile_cache(env_var: str = "VIDEOP2P_COMPILE_CACHE") -> None:
-    """Persist compiled TPU executables across CLI invocations.
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
 
-    The Stage-2 graph alone costs minutes of compile on a cold start (the
-    round-3 CLI drive spent ~2 min in the first VAE decode, nearly all
-    compile); a content-addressed on-disk cache makes every later run warm.
-    Called at the binary boundary (the CLI entry points and bench.py) — a
-    library import must not mutate global jax config. A cache dir configured
-    earlier in the process (e.g. the test suite's conftest) wins: this is a
-    default, not an override."""
-    if jax.config.jax_compilation_cache_dir:
-        return
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.environ.get(env_var,
-                       os.path.expanduser("~/.cache/videop2p_jax_tpu_cache")),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    The Stage-2 graph alone costs minutes of compile on a cold start; a
+    content-addressed on-disk cache makes every later run warm. The
+    directory is placed from OUTSIDE the program: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    nothing is touched; otherwise the cache lives in
+    ``<checkout>/.jax_cache`` (git-ignored; derived from this package's own
+    location, because the path is part of the cache key and a directory
+    that moves never hits). Called at the binary boundary (CLI entry
+    points, tools, bench.py, the test suite's conftest) — a library import
+    must not mutate global jax config; a second call in one process changes
+    nothing. Entries are per backend: a cache filled by CPU runs is of no
+    use on the chip."""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)
+        )))
+        cache_dir = os.path.join(checkout, ".jax_cache")
+    if jax.config.jax_compilation_cache_dir != cache_dir:
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    return cache_dir
 
 
 def setup_mesh(bundle: "ModelBundle", mesh_spec: str, video_len: int,

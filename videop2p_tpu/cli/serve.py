@@ -195,14 +195,13 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main(argv=None) -> int:
-    args = build_parser().parse_args(argv)
-    enable_compile_cache()
-    from videop2p_tpu.parallel import initialize_distributed
-
-    initialize_distributed()
+def build_engine(args: argparse.Namespace):
+    """The engine exactly as this CLI serves it, from parsed arguments:
+    spec, store, scheduler, resilience knobs, and the startup warm-up
+    (unless ``--no_warm``). ``main`` puts the HTTP server in front of it;
+    in-process callers (``chip_smoke.py``) do the same with
+    :func:`videop2p_tpu.serve.http.make_server`."""
     from videop2p_tpu.serve import EditEngine, FaultPlan, ProgramSpec
-    from videop2p_tpu.serve.http import make_server
 
     spec = ProgramSpec(
         checkpoint=args.checkpoint, width=args.width,
@@ -253,6 +252,18 @@ def main(argv=None) -> int:
               f"step buckets {info['steps']}, "
               f"reuse {info['reuse']}, quant {info['quant']}, "
               f"student {info['student']})")
+    return engine
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    from videop2p_tpu.parallel import initialize_distributed
+
+    initialize_distributed()
+    from videop2p_tpu.serve.http import make_server
+
+    engine = build_engine(args)
     server = make_server(engine, host=args.host, port=args.port)
     print(f"[serve] listening on {server.url}  "
           f"(ledger: {engine.ledger.path})")

@@ -188,7 +188,7 @@ def unet3d_params_from_torch(
                 f"shape mismatch for {torch_key!r}: torch {arr.shape} vs "
                 f"flax {tuple(leaf.shape)}"
             )
-        out[path] = arr.astype(np.asarray(leaf).dtype if hasattr(leaf, "__array__") else leaf.dtype)
+        out[path] = arr.astype(leaf.dtype)  # (no np.asarray: that fetches the leaf)
         used.add(torch_key)
     unused = [k for k in state_dict if k not in used]
     return traverse_util.unflatten_dict(out), {"kept_init": kept_init, "unused": unused}

@@ -123,10 +123,16 @@ def load_pipeline(
     abstract = jax.eval_shape(
         lambda: unet.init(init_key, sample, jnp.asarray(0), text)
     )["params"]
-    # materialize inits only for params the checkpoint may not carry
-    init_params = jax.jit(unet.init)(init_key, sample, jnp.asarray(0), text)["params"]
     sd = convert.load_state_dict(_find_weights(unet_dir))
-    unet_params, report = convert.unet3d_params_from_torch(sd, init_params)
+    unet_params, report = convert.unet3d_params_from_torch(sd, abstract)
+    if report["kept_init"]:
+        # a 2-D checkpoint: the temporal params it does not carry keep a
+        # fresh init — only then is one materialized (at SD width the init
+        # is 3.4 GB computed, fetched and thrown away for a tuned 3-D dir)
+        init_params = jax.jit(unet.init)(
+            init_key, sample, jnp.asarray(0), text
+        )["params"]
+        unet_params, report = convert.unet3d_params_from_torch(sd, init_params)
 
     vae = vae_params = None
     vae_dir = os.path.join(path, "vae")

@@ -226,10 +226,9 @@ def _spec_axes(spec) -> Tuple[str, ...]:
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    from videop2p_tpu.parallel.ring import shard_map_compat
-
-    return shard_map_compat(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
 
 

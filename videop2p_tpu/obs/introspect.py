@@ -41,6 +41,7 @@ __all__ = [
     "compile_abstract",
     "hlo_fingerprint",
     "instruction_histogram",
+    "tpu_custom_call_counts",
     "abstractify_args",
     "PROGRAM_METRICS",
 ]
@@ -61,6 +62,18 @@ PROGRAM_METRICS = (
 )
 
 _METADATA_RE = re.compile(r",?\s*metadata=\{[^}]*\}")
+# the module-level source-location tables the compiler prints ahead of the
+# computations (`FileNames` / `FunctionNames` / `FileLocations` /
+# `StackFrames`, each a header line then numbered rows) — where the program
+# was traced from, never what it computes
+_LOCATION_TABLE_RE = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:\d+ .*\n)*",
+    re.MULTILINE,
+)
+# a Pallas TPU kernel's custom call, with the op_name of its metadata
+_TPU_KERNEL_RE = re.compile(
+    r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"'
+)
 # one optimized-HLO instruction: `%name = type[...] opcode(...`
 _INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\S+\s+([\w\-]+)\(",
                        re.MULTILINE)
@@ -69,15 +82,33 @@ _INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\S+\s+([\w\-]+)\(",
 def hlo_fingerprint(hlo_text: str) -> str:
     """Stable 16-hex-char fingerprint of an optimized-HLO module.
 
-    ``metadata={...}`` annotations (op names, source file/line) are the only
-    part of the text that varies with how the program was traced rather
-    than what it computes — strip them, hash the rest. Same program → same
+    ``metadata={...}`` annotations (op names, stack-frame ids) and the
+    module's source-location tables are the only part of the text that
+    varies with how the program was traced rather than what it computes —
+    strip them, hash the rest. Same program → same
     fingerprint across processes; a changed fingerprint across runs means
     XLA built a structurally different executable.
     """
     return hashlib.sha256(
-        _METADATA_RE.sub("", hlo_text).encode()
+        _METADATA_RE.sub("", _LOCATION_TABLE_RE.sub("", hlo_text)).encode()
     ).hexdigest()[:16]
+
+
+def tpu_custom_call_counts(hlo_text: str) -> Dict[str, int]:
+    """Pallas TPU kernels in an optimized-HLO module, by kernel name:
+    ``{"fused_group_norm": 12, ...}``. A kernel is a ``tpu_custom_call``
+    custom call; its name is the ``pallas_call(name=...)`` scope ahead of
+    ``/pallas_call`` in the instruction's ``op_name``. Static counts — a
+    kernel inside a ``while`` body counts once. Empty off the TPU: this is
+    how a run says whether it took the kernel branch or the XLA fallback."""
+    counts: Dict[str, int] = {}
+    for m in _TPU_KERNEL_RE.finditer(hlo_text):
+        parts = m.group(1).split("/")
+        if parts[-1].startswith("pallas_call"):
+            parts = parts[:-1]
+        name = parts[-1] if parts else "?"
+        counts[name] = counts.get(name, 0) + 1
+    return dict(sorted(counts.items()))
 
 
 def instruction_histogram(hlo_text: str) -> Dict[str, int]:
@@ -146,6 +177,9 @@ def analyze_compiled(compiled) -> Dict[str, Any]:
         rec["hlo_fingerprint"] = hlo_fingerprint(text)
         rec["hlo_instructions"] = sum(hist.values())
         rec["hlo_histogram"] = hist
+        kernels = tpu_custom_call_counts(text)
+        if kernels:  # CPU records keep their pinned schema
+            rec["tpu_custom_calls"] = kernels
     except Exception:  # noqa: BLE001
         pass
     return rec

@@ -218,16 +218,12 @@ class RunLedger:
                      else str(mesh)),
         }
         if device_info:
-            # callers create the ledger after first device use, so this
-            # cannot be the call that hangs on an unhealthy backend — but
-            # guard anyway: metadata must never kill a run
-            try:
-                devs = jax.devices()
-                start["backend"] = devs[0].platform
-                start["device_count"] = len(devs)
-                start["device_kind"] = devs[0].device_kind
-            except Exception:  # noqa: BLE001
-                start["backend"] = None
+            # a run that cannot name its device has no device: a failed
+            # jax.devices() raises here instead of recording backend=None
+            devs = jax.devices()
+            start["backend"] = devs[0].platform
+            start["device_count"] = len(devs)
+            start["device_kind"] = devs[0].device_kind
         if meta:
             start.update(meta)
         self.event("run_start", **start)

@@ -64,7 +64,7 @@ PROBE_EVENT_FIELDS = ("probe", "target", "ok", "latency_s",
 PROBE_AUDIT_FIELDS = ("fingerprint", "targets", "hashes", "divergent",
                       "replica_a", "hash_a", "replica_b", "hash_b")
 
-# the taxonomy, in suite execution order (docs/OBSERVABILITY.md Layer 9)
+# the naming scheme, in suite execution order (docs/OBSERVABILITY.md Layer 9)
 PROBE_KINDS = (
     "cached_replay",
     "determinism",

@@ -44,13 +44,13 @@ SPAN_EVENT_FIELDS = (
     "trace_id",    # 32 hex chars — shared by every span of one request
     "span_id",     # 16 hex chars — this span
     "parent_id",   # 16 hex chars or None — the causal parent
-    "name",        # dotted taxonomy: serve.request, serve.dispatch, ...
+    "name",        # dotted naming scheme: serve.request, serve.dispatch, ...
     "wall_ns",     # int epoch nanoseconds at span start (time.time_ns())
     "duration_s",  # float seconds, monotonic-measured
     "status",      # "ok" | terminal request status | "cached"
 )
 
-# The critical-path taxonomy: span name → segment label. obs/history.py
+# The critical-path naming scheme: span name → segment label. obs/history.py
 # aggregates per-trace durations under these labels into the `segments`
 # section (queue/resolve/dispatch/decode p50/p99), and trace_view renders
 # the same split per trace.

@@ -140,6 +140,9 @@ def _fused_rect(q3: jax.Array, k: jax.Array, v: jax.Array, q_blk: int,
     grid = (bh, m // q_blk)
     return pl.pallas_call(
         functools.partial(_fused_kernel, scale=d ** -0.5),
+        # explicit name: the compiled program's custom call and the trace
+        # events carry it (obs/introspect.tpu_custom_call_counts)
+        name="fused_frame_attention",
         out_shape=jax.ShapeDtypeStruct((bh, m, d), q3.dtype),
         grid=grid,
         in_specs=[
@@ -220,7 +223,10 @@ def make_frame_attention_fn(
         kernel below keeps everything in VMEM.
       * "fused" — custom Pallas kernel for the frame-0-KV structure: K/V
         resident in VMEM, query blocks stream, exact full-row softmax. The
-        memory-optimal AND compute-optimal inference path.
+        memory-optimal AND compute-optimal inference path. Asked for by
+        name on a backend with no Pallas TPU lowering it is an error, not
+        a quiet drop to ``chunked`` — a run that "works" must not be the
+        XLA fallback (only ``auto`` chooses by backend).
       * "dense" — plain einsum; the small-site (16²/8²) and CPU path.
       * "chunked" — the TRAINING path: exact attention scanned over query
         blocks with ``jax.checkpoint``; the backward pass never materializes
@@ -248,8 +254,14 @@ def make_frame_attention_fn(
         if n < min_large_tokens:
             return dense_frame_attention(q, k, v)
         if impl == "fused":
+            if jax.default_backend() != "tpu":
+                raise RuntimeError(
+                    "frame attention impl 'fused' is the Pallas TPU kernel "
+                    f"and the backend is {jax.default_backend()!r} — use "
+                    "'auto' to choose by backend, or 'chunked'/'dense'"
+                )
             q_blk = 256
-            if (f * n) % q_blk == 0 and d <= 128 and jax.default_backend() == "tpu":
+            if (f * n) % q_blk == 0 and d <= 128:
                 return fused_frame_attention(q, k, v, q_blk)
             return chunked_frame_attention(q, k, v, q_chunk=q_chunk)
         flash_ok = (d <= 128 or d % 128 == 0) and jax.default_backend() == "tpu"

@@ -103,17 +103,6 @@ def make_sharded_frame_attention_fn(mesh: Mesh, impl: str = "auto"):
     from videop2p_tpu.ops import dense_frame_attention, make_frame_attention_fn
 
     resolved = make_frame_attention_fn(impl)
-    if resolved is None and not hasattr(jax, "shard_map"):
-        # dense-einsum path on a legacy-shard_map jax (no ``jax.shard_map``,
-        # only ``jax.experimental.shard_map``): GSPMD partitions the plain
-        # einsum natively — the wrapper is only REQUIRED for Pallas custom
-        # calls — and the legacy shard_map embedded inside the scanned edit
-        # program MISCOMPILES: on jax 0.4.37 the cached edit's passthrough
-        # source stream came back corrupted (max err 4.15 on a pure copy;
-        # __graft_entry__'s dryrun asserts that stream bit-exact). The
-        # standalone kernel is fine — only the scan-embedded program breaks,
-        # so the bypass is gated on the jax API generation, not the backend.
-        return dense_frame_attention
     inner = resolved or dense_frame_attention
 
     def fn(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
@@ -132,11 +121,9 @@ def make_sharded_frame_attention_fn(mesh: Mesh, impl: str = "auto"):
             )
         qspec = P(ax_d, AXIS_FRAMES, ax_t, None, None)
         kvspec = P(ax_d, ax_t, None, None)
-        from videop2p_tpu.parallel.ring import shard_map_compat
-
-        return shard_map_compat(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
-            out_specs=qspec,
+            out_specs=qspec, check_vma=False,
         )(q, k, v)
 
     return fn
@@ -180,17 +167,15 @@ def make_sharded_group_norm_fn(mesh: Mesh, impl: str = "auto"):
             return None
         import functools
 
-        from videop2p_tpu.parallel.ring import shard_map_compat
-
         inner = functools.partial(
             fused_group_norm, num_groups=num_groups, eps=eps, act=act,
             interpret=interpret,
         )
         sample_spec = P((AXIS_DATA, AXIS_FRAMES), None, None)
-        return shard_map_compat(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(sample_spec, P(None), P(None)),
-            out_specs=sample_spec,
+            out_specs=sample_spec, check_vma=False,
         )(x2, scale, bias)
 
     return fn
@@ -234,8 +219,9 @@ def make_megatron_out_dot(mesh: Mesh):
     the declarative form leaves a partial-sum matmul behind which GSPMD
     inserts an **all-reduce** of the FULL (…, tokens, C) result on every
     chip. The explicit form computes the local partial inside ``shard_map``
-    (manual over ``tensor`` only — ``data``/``frames`` stay in GSPMD's
-    hands via ``auto``) and reduces with ``lax.psum_scatter`` along the
+    (manual over ``tensor`` only, ``axis_names={"tensor"}`` —
+    ``data``/``frames`` stay in GSPMD's hands) and reduces with
+    ``lax.psum_scatter`` along the
     token axis: each chip receives 1/tp of the result bytes (the
     reduce-scatter half of the all-reduce), and the all-gather half is
     deferred to wherever the partitioner actually needs the full token
@@ -250,10 +236,7 @@ def make_megatron_out_dot(mesh: Mesh):
     dims, non-2D kernel, token/feature axes not divisible by tp, tp == 1)
     — so it is always safe to thread.
     """
-    from videop2p_tpu.parallel.ring import shard_map_compat
-
     tp = mesh.shape[AXIS_TENSOR]
-    auto = frozenset(a for a in mesh.axis_names if a != AXIS_TENSOR)
 
     def dot(lhs, rhs, dimension_numbers, precision=None,
             preferred_element_type=None, **kwargs):
@@ -273,11 +256,6 @@ def make_megatron_out_dot(mesh: Mesh):
             or tuple(rc) != (0,)
             or lhs.shape[-1] % tp
             or lhs.shape[lhs.ndim - 2] % tp
-            # partial-auto shard_map only exists under a surrounding jit
-            # trace on legacy jax; eager calls take the plain dot (the
-            # seam is a compiled-program optimization — eager numerics
-            # are identical either way)
-            or not isinstance(lhs, jax.core.Tracer)
         ):
             return plain(lhs, rhs)
         tok = lhs.ndim - 2
@@ -291,12 +269,14 @@ def make_megatron_out_dot(mesh: Mesh):
         lhs_spec = P(*([None] * (lhs.ndim - 1)), AXIS_TENSOR)
         out_parts = [None] * lhs.ndim
         out_parts[tok] = AXIS_TENSOR
-        return shard_map_compat(
+        # partial-manual shard_map has no eager form; the jit wrapper is
+        # inlined under a surrounding trace and makes an eager call work
+        return jax.jit(jax.shard_map(
             local, mesh=mesh,
             in_specs=(lhs_spec, P(AXIS_TENSOR, None)),
             out_specs=P(*out_parts),
-            auto=auto,
-        )(lhs, rhs)
+            axis_names={AXIS_TENSOR}, check_vma=False,
+        ))(lhs, rhs)
 
     return dot
 

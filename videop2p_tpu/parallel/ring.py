@@ -65,7 +65,6 @@ __all__ = [
     "ring_attention",
     "ring_attention_sharded",
     "make_ring_temporal_fn",
-    "shard_map_compat",
 ]
 
 RING_VARIANTS = ("overlap", "bidir", "serial")
@@ -76,29 +75,6 @@ def default_ring_variant() -> str:
     (one of ``overlap``/``bidir``/``serial``), else ``overlap``."""
     v = os.environ.get("VIDEOP2P_RING_VARIANT", "overlap").strip().lower()
     return v if v in RING_VARIANTS else "overlap"
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, auto=None):
-    """``jax.shard_map`` across the API rename: new jax spells it
-    ``jax.shard_map(..., check_vma=...)``, older releases only have
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    Replication checking stays off in both spellings (the ring kernel's
-    collectives confuse it). ``auto`` passes through a frozenset of mesh
-    axes left to GSPMD (partial-manual mode — the megatron out-projection
-    seam shards only over ``tensor`` and lets GSPMD keep managing
-    ``data``/``frames``)."""
-    kwargs = {} if auto is None else {"auto": frozenset(auto)}
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False, **kwargs,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, **kwargs,
-    )
 
 
 def _block_update(q32, k_blk, v_blk, scale, m, l, o):
@@ -225,8 +201,10 @@ def ring_attention_sharded(
     spec = P(*spec_parts)
 
     fn = functools.partial(ring_attention, axis_name=axis_name, variant=variant)
-    return shard_map_compat(
-        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
+    # replication checking stays off: the ring's collectives confuse it
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
     )(q, k, v)
 
 

@@ -15,7 +15,7 @@ Plan DSL (comma-separated directives; also accepted as a JSON object):
   * ``hang@K:S``     — dispatch attempt K sleeps S seconds before the
     device call (the watchdog/deadline path must bound it);
   * ``unavail@A-B``  — dispatch attempts A..B (inclusive) raise
-    backend-unavailable (the ``BENCH_r04``/``r05`` outage, in miniature —
+    backend-unavailable (the round-4/5 chip outage, in miniature —
     long enough windows must trip the circuit breaker);
   * ``corrupt:PAT``  — persisted store entries whose key contains ``PAT``
     (``*`` = every key) load corrupted (the rehydration path must detect
@@ -146,7 +146,7 @@ class EngineUnavailable(RuntimeError):
 
 
 # transient markers seen in real jax/XLA runtime errors when a backend
-# drops mid-run (the repo's own BENCH_r04/r05 recorded `backend_unavailable`)
+# drops mid-run (the repo's own round-5 bench recorded `backend_unavailable`)
 _TRANSIENT_MARKERS = (
     "unavailable", "resource exhausted", "deadline exceeded",
     "connection reset", "socket closed", "failed precondition",
