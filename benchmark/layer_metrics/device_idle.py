@@ -1,0 +1,8 @@
+"""device: 1 - (union of device-op intervals) / (traced window), in %."""
+
+
+def read(ctx):
+    tr = ctx.get("trace")
+    if not tr or not tr.get("window_s"):
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
