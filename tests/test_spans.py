@@ -1,0 +1,496 @@
+"""The span primitive (ISSUE 26, obs/spans.py ``span``): nesting and ids
+through the context variable, write-on-close, inertness with no ledger,
+compile-time attribution by the jax.monitoring listener, ``program.analysis``,
+``phase_timer`` as a thin caller, the device-side named scopes, and the
+vocabulary the benchmark reads held against a tiny ``run_tuning.main``."""
+
+import contextvars
+import importlib.util
+import os
+import re
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from videop2p_tpu.obs import RunLedger, instrumented_jit, read_ledger
+from videop2p_tpu.obs import ledger as obs_ledger
+from videop2p_tpu.obs import spans as obs_spans
+from videop2p_tpu.obs.ledger import program_label
+from videop2p_tpu.obs.spans import (
+    BENCHMARK_SPAN_NAMES,
+    SPAN_EVENT_FIELDS,
+    Tracer,
+    current_span,
+    span,
+)
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _spans(path):
+    return [e for e in read_ledger(path) if e["event"] == "span"]
+
+
+def _one(spans, name):
+    found = [s for s in spans if s["name"] == name]
+    assert len(found) == 1, (name, [s["name"] for s in spans])
+    return found[0]
+
+
+# -------------------------------------------------- the primitive itself ---
+
+
+def test_span_nesting_ids_and_worker_threads(tmp_path):
+    """parent_id is the enclosing span's and trace_id the run's, through the
+    context variable; a copied context carries the parent into a worker
+    thread, a bare thread starts a root of the same run."""
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path) as led:
+        with span("outer", a=1) as outer:
+            assert current_span() is outer
+            with span("inner"):
+                pass
+            ctx = contextvars.copy_context()
+            carried = threading.Thread(
+                target=lambda: ctx.run(lambda: span("carried").__enter__()
+                                       .end()))
+            bare = threading.Thread(
+                target=lambda: span("bare").__enter__().end())
+            for t in (carried, bare):
+                t.start()
+                t.join()
+        assert current_span() is None
+        with span("request", trace_id="ab" * 16, parent_id="cd" * 8):
+            with span("inherits"):
+                pass
+    spans = _spans(path)
+    outer_ev = _one(spans, "outer")
+    for s in spans:
+        assert set(SPAN_EVENT_FIELDS) <= set(s)
+        assert len(s["span_id"]) == 16 and s["duration_s"] >= 0
+    assert outer_ev["parent_id"] is None and outer_ev["a"] == 1
+    assert outer_ev["trace_id"] == led.trace_id and len(led.trace_id) == 32
+    assert _one(spans, "inner")["parent_id"] == outer_ev["span_id"]
+    assert _one(spans, "carried")["parent_id"] == outer_ev["span_id"]
+    assert _one(spans, "bare")["parent_id"] is None
+    assert _one(spans, "bare")["trace_id"] == led.trace_id
+    # an explicit trace (the engine's request) is inherited by what it encloses
+    req = _one(spans, "request")
+    assert req["trace_id"] == "ab" * 16 and req["parent_id"] == "cd" * 8
+    inherits = _one(spans, "inherits")
+    assert inherits["trace_id"] == "ab" * 16
+    assert inherits["parent_id"] == req["span_id"]
+    # children close, and are written, before their parents
+    order = [s["name"] for s in spans]
+    assert order.index("inner") < order.index("outer")
+
+
+def test_span_is_on_disk_when_an_exception_unwinds_and_close_never_runs(
+        tmp_path):
+    """The benchmark ends ``main`` by raising through it: ``close()`` is never
+    reached, and every span that closed is in the file all the same."""
+    path = str(tmp_path / "ledger.jsonl")
+    led = RunLedger(path).activate()
+
+    class WindowClosed(Exception):
+        pass
+
+    def main():
+        with span("main.root") as root:
+            with span("main.first"):
+                pass
+            root.end()  # the handle: closes early; later closes do nothing
+            with span("main.loop"):
+                raise WindowClosed()
+
+    try:
+        with pytest.raises(WindowClosed):
+            main()
+        # read while the ledger is still open and active: nothing is buffered
+        events = read_ledger(path)
+        assert not any(e["event"] == "run_end" for e in events)
+        spans = [e for e in events if e["event"] == "span"]
+        assert [s["name"] for s in spans] == ["main.first", "main.root",
+                                              "main.loop"]
+        assert _one(spans, "main.root")["status"] == "ok"
+        assert _one(spans, "main.loop")["status"] == "error"
+        # main.loop opened after the root's end(): a root of its own
+        assert _one(spans, "main.loop")["parent_id"] is None
+        assert current_span() is None
+    finally:
+        led.close()
+
+
+def test_no_ledger_or_tracing_off_writes_nothing_and_mints_no_id(
+        tmp_path, monkeypatch):
+    minted = []
+    real = obs_spans.make_span_id
+    monkeypatch.setattr(obs_spans, "make_span_id",
+                        lambda: minted.append(1) or real())
+    assert obs_ledger.current_ledger() is None
+    with span("nobody.listens", x=1) as s:
+        assert current_span() is None and not s.live
+        with span("nor.here"):
+            pass
+    assert s.span_id is None and s.elapsed() >= 0 and minted == []
+    # a ledger whose tracer is off (the engine with tracing off) is the same
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path) as led:
+        led.tracer = Tracer(led, enabled=False)
+        with span("tracing.off") as s:
+            pass
+        f = instrumented_jit(lambda x: x + 1, program="toy_off",
+                             analyze=False)
+        f(jnp.ones(3))
+    assert s.span_id is None and minted == []
+    events = read_ledger(path)
+    assert not any(e["event"] == "span" for e in events)
+    # ... and the program_call event keeps its fields either way
+    call, = [e for e in events if e["event"] == "program_call"]
+    assert {"program", "cache_miss", "dispatch_s"} <= set(call)
+
+
+def test_handle_end_is_idempotent_and_counters_accumulate(tmp_path):
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path):
+        with span("counted") as s:
+            s.count("steps", 2)
+            s.count("steps", 3)
+            s.set(note="x")
+            s.end()
+            s.end(status="error")  # nothing: it has closed
+    ev = _one(_spans(path), "counted")
+    assert ev["steps"] == 5 and ev["note"] == "x"
+    assert ev["status"] == "ok"
+
+
+# ------------------------------------------- compile-time attribution ---
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def test_listener_turns_durations_into_children_and_sums_unlabelled(tmp_path):
+    """jax's trace / lower / backend-compile durations fired under a program
+    label become children of the open span (nested ones collapse into the
+    outermost); fired with no label they are summed on the open span, or on
+    the ledger where none is open — never one line each."""
+    fire = jax.monitoring.record_event_duration_secs
+
+    def took(event, seconds, slept=None):
+        # jax reports a duration when the work ends: spend it, then report
+        time.sleep(seconds if slept is None else slept)
+        fire(event, seconds)
+
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path) as led:
+        n_compile_events = len(led.compile_seconds)
+        with program_label("prog"), span("program.call", program="prog"):
+            with span("program.execute"):
+                took(_TRACE, 0.002)             # an inner jit's trace ...
+                took(_TRACE, 0.010, slept=0.006)  # ... inside the outer one
+                took(_LOWER, 0.003)
+                jax.monitoring.record_event(
+                    "/jax/compilation_cache/compile_requests_use_cache")
+                jax.monitoring.record_event(
+                    "/jax/compilation_cache/cache_hits")
+                time.sleep(0.005)
+                fire(_RETRIEVAL, 0.004)
+                fire(_BACKEND, 0.005)
+        assert len(led.compile_seconds) == n_compile_events + 1
+        with span("eager.region"):
+            for _ in range(3):
+                fire(_TRACE, 0.001)
+            fire(_BACKEND, 0.25)
+        fire(_BACKEND, 0.5)  # no label, no span open: the ledger's counter
+        fire(_LOWER, 0.001)
+        assert led.counters == {"unspanned_backend_compile_s": 0.5,
+                                "unspanned_backend_compiles": 1,
+                                "unspanned_trace_lower_events": 1}
+    events = read_ledger(path)
+    spans = [e for e in events if e["event"] == "span"]
+    call = _one(spans, "program.call")
+    kids = [s for s in spans if s["parent_id"] == call["span_id"]]
+    assert sorted(s["name"] for s in kids) == [
+        "program.backend_compile", "program.execute", "program.lower",
+        "program.trace"]
+    trace = _one(kids, "program.trace")
+    assert trace["duration_s"] == pytest.approx(0.010) and trace["nested"] == 1
+    backend = _one(kids, "program.backend_compile")
+    assert backend["cache_hit"] is True
+    assert backend["cache_retrieval_s"] == pytest.approx(0.004)
+    # start = now − duration: the child lies inside its parent
+    assert backend["wall_ns"] >= call["wall_ns"] - int(0.02e9)
+    eager = _one(spans, "eager.region")
+    assert eager["unspanned_trace_lower_events"] == 3
+    assert eager["unspanned_backend_compiles"] == 1
+    assert eager["unspanned_backend_compile_s"] == pytest.approx(0.25)
+    assert len(spans) == 6  # the call, its four children, the eager region
+    end, = [e for e in events if e["event"] == "run_end"]
+    assert end["unspanned_backend_compiles"] == 1
+
+
+def test_program_analysis_encloses_the_analysis_and_only_it(tmp_path):
+    """On a jit-cache miss the introspection pass runs under
+    ``program.analysis``: what it traces, lowers and compiles again are ITS
+    children and stay out of the run's compile totals; the call's own are
+    the call's; ``program.execute`` starts where the compile ended; a hit
+    has neither compile children nor an analysis."""
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path, latency=True) as led:
+        f = instrumented_jit(lambda x: jnp.tanh(x) @ x.T,
+                             program="toy_analysis",
+                             span_attrs=lambda x: {"rows": x.shape[0]})
+        x = jnp.ones((32, 32)) * 0.37
+        f(x)
+        n_compiles = len(led.compile_seconds)
+        f(x)
+        assert len(led.compile_seconds) == n_compiles
+    events = read_ledger(path)
+    spans = [e for e in events if e["event"] == "span"]
+    miss, hit = [s for s in spans if s["name"] == "program.call"]
+    assert miss["cache_miss"] is True and hit["cache_miss"] is False
+    assert miss["program"] == "toy_analysis" and miss["rows"] == 32
+
+    def kids(parent, name=None):
+        return [s for s in spans if s["parent_id"] == parent["span_id"]
+                and (name is None or s["name"] == name)]
+
+    analysis, = kids(miss, "program.analysis")
+    execute, = kids(miss, "program.execute")
+    own_compile, = kids(miss, "program.backend_compile")
+    assert kids(miss, "program.trace") and kids(miss, "program.lower")
+    # the analysis' own trace / lower / compile hang under it, not the call
+    assert {s["name"] for s in kids(analysis)} <= {
+        "program.trace", "program.lower", "program.backend_compile"}
+    assert len(kids(miss, "program.backend_compile")) == 1
+    # exactly one `compile` event for the program: the analysis' recompile
+    # stays out of the totals, as before
+    assert len([e for e in events if e["event"] == "compile"
+                and e["program"] == "toy_analysis"]) == 1
+    # it encloses the analysis: the program_analysis event is written inside
+    # its interval, the program_call event before it opens
+    t_end = {s["span_id"]: s["t"] for s in spans}
+    pa, = [e for e in events if e["event"] == "program_analysis"]
+    pc = [e for e in events if e["event"] == "program_call"][0]
+    assert (t_end[analysis["span_id"]] - analysis["duration_s"] - 1e-3
+            <= pa["t"] <= t_end[analysis["span_id"]])
+    assert pc["t"] <= t_end[analysis["span_id"]] - analysis["duration_s"] + 1e-3
+    # execute starts where the call's compile ended, and ends before analysis
+    compile_end = own_compile["wall_ns"] * 1e-9 + own_compile["duration_s"]
+    assert execute["wall_ns"] * 1e-9 >= compile_end - 1e-3
+    assert (execute["wall_ns"] * 1e-9 + execute["duration_s"]
+            <= analysis["wall_ns"] * 1e-9 + 1e-3)
+    assert "blocked" not in execute  # --latency: the wrapper blocked
+    # the hit: execute only
+    assert [s["name"] for s in kids(hit)] == ["program.execute"]
+    # the children of the miss make it up (within the wrapper's own overhead)
+    assert sum(s["duration_s"] for s in kids(miss)) <= miss["duration_s"] + 1e-3
+
+
+def test_execute_span_never_adds_a_sync(tmp_path, monkeypatch):
+    """Where the run does not block (no --latency) the span is the dispatch
+    alone and says so; it calls block_until_ready nowhere."""
+    monkeypatch.delenv("VIDEOP2P_OBS_LATENCY", raising=False)
+    blocked = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: blocked.append(1) or real(x))
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path):
+        f = instrumented_jit(lambda x: x * 2, program="toy_async",
+                             analyze=False)
+        f(jnp.ones(4))
+    assert blocked == []
+    execute = _one(_spans(path), "program.execute")
+    assert execute["blocked"] is False
+
+
+def test_phase_timer_still_prints_and_writes_phase_and_is_a_span(
+        tmp_path, capsys):
+    from videop2p_tpu.utils.profiling import phase_timer
+
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path):
+        with span("enclosing"):
+            with phase_timer("some_phase", count=4, unit="it"):
+                pass
+    with phase_timer("no_ledger_phase"):
+        pass
+    out = capsys.readouterr().out
+    assert "[phase] some_phase:" in out and "ms/it" in out
+    assert "[phase] no_ledger_phase:" in out
+    events = read_ledger(path)
+    phase, = [e for e in events if e["event"] == "phase"]
+    assert phase["name"] == "some_phase" and phase["count"] == 4
+    spans = [e for e in events if e["event"] == "span"]
+    ph = _one(spans, "some_phase")
+    assert ph["parent_id"] == _one(spans, "enclosing")["span_id"]
+    assert ph["count"] == 4 and ph["unit"] == "it"
+    assert abs(ph["duration_s"] - phase["seconds"]) < 0.05
+
+
+# ----------------------------------------------------- device-side names ---
+
+
+def test_train_steps_lowering_carries_scope_names_forward_and_backward():
+    """``jax.named_scope`` names are metadata on the ops: the lowered tune
+    program carries ``ops.frame_attention`` and ``ops.group_norm`` under
+    ``train.loss`` on forward (jvp) AND backward (transpose) ops, and
+    ``train.noise`` / ``train.optimizer`` beside them."""
+    from videop2p_tpu.core import DDPMScheduler
+    from videop2p_tpu.models import UNet3DConditionModel, UNet3DConfig
+    from videop2p_tpu.pipelines import make_unet_fn
+    from videop2p_tpu.train import (TrainState, TuneConfig, make_optimizer,
+                                    train_steps)
+
+    cfg = UNet3DConfig.tiny()
+    cfg = type(cfg)(**{**cfg.__dict__, "frame_attention": "chunked",
+                       "gradient_checkpointing": True})
+    model = UNet3DConditionModel(config=cfg)
+    latents = jnp.zeros((1, 2, 8, 8, 4))
+    text = jnp.zeros((1, 7, cfg.cross_attention_dim))
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.key(2), latents, jnp.asarray(0), text))
+    tx = make_optimizer(TuneConfig(learning_rate=1e-3))
+    state = jax.eval_shape(
+        lambda p: TrainState.create(p, tx), variables["params"])
+    lowered = jax.jit(
+        lambda s, k: train_steps(make_unet_fn(model), tx, s,
+                                 DDPMScheduler.create_sd(), latents, text, k,
+                                 num_steps=2)
+    ).lower(state, jax.random.key(0))
+    names = set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
+
+    def some(*parts):
+        return [n for n in names if all(p in n for p in parts)]
+
+    # forward ops: train.loss/jvp(<module>)/<flax path>/ops.frame_attention/…
+    # backward ops: train.loss/transpose(jvp(<module>))/…, the recompute of a
+    # checkpointed block under …/checkpoint/rematted_computation/…
+    forward = [n for n in names if n.startswith("train.loss/jvp(")]
+    backward = [n for n in names if n.startswith("train.loss/transpose(jvp(")]
+    for scope in ("ops.frame_attention", "ops.group_norm"):
+        assert [n for n in forward if scope in n], scope
+        assert [n for n in backward if scope in n], scope
+    assert [n for n in backward if "rematted_computation" in n]
+    assert some("train.noise/") and some("train.optimizer/")
+    # the optimizer's ops are not the loss's, nor the other way round
+    assert not some("train.optimizer", "train.loss")
+
+
+# ------------------------------- the vocabulary the benchmark reads ---
+
+
+def _benchmark_spans_module():
+    spec = importlib.util.spec_from_file_location(
+        "bench_harness_spans",
+        os.path.join(_REPO, "benchmark", "harness", "spans.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def tiny_tune_ledger(tmp_path_factory):
+    """A tiny ``run_tuning.main`` ended the way the benchmark's driver ends
+    it: an exception raised through ``main`` at the third call, so that
+    ``run_ledger.close()`` is never reached."""
+    from videop2p_tpu.cli import run_tuning
+    from videop2p_tpu.cli.common import load_config
+
+    class WindowClosed(Exception):
+        pass
+
+    out = tmp_path_factory.mktemp("tiny_tune")
+    cfg = load_config(os.path.join(_REPO, "configs", "rabbit-jump-tune.yaml"))
+    cfg["output_dir"] = str(out / "run")
+    cfg["train_data"].update(
+        video_path=os.path.join(_REPO, "data", "rabbit"),
+        n_sample_frames=2, width=16, height=16)
+    cfg.update(max_train_steps=10 ** 6, steps_per_call=2, log_every=2,
+               checkpointing_steps=0, validation_steps=0, latency=True)
+    path = str(out / "ledger.jsonl")
+    real_jit = run_tuning.instrumented_jit
+    calls = []
+
+    def counting_jit(fn, **kw):
+        prog = real_jit(fn, **kw)
+
+        def steps_fn(*args):
+            if len(calls) == 3:
+                raise WindowClosed()
+            calls.append(1)
+            return prog(*args)
+
+        return steps_fn
+
+    run_tuning.instrumented_jit = counting_jit
+    try:
+        with pytest.raises(WindowClosed):
+            run_tuning.main(**cfg, tiny=True, ledger=path)
+    finally:
+        run_tuning.instrumented_jit = real_jit
+        led = obs_ledger.current_ledger()
+        events = read_ledger(path)  # before anything closes it
+        if led is not None and led.path == path:
+            led.close()
+    return events
+
+
+def test_the_names_the_benchmark_reads_are_one_tuple_and_main_emits_each(
+        tiny_tune_ledger):
+    """A rename of a span the benchmark reads fails HERE, before it turns a
+    listed metric to null: the benchmark's helper (which may import nothing
+    of the program) keeps the same tuple, and a tiny ``main`` emits each."""
+    assert _benchmark_spans_module().READ_NAMES == BENCHMARK_SPAN_NAMES
+    assert not any(e["event"] == "run_end" for e in tiny_tune_ledger)
+    spans = [e for e in tiny_tune_ledger if e["event"] == "span"]
+    names = {s["name"] for s in spans}
+    assert set(BENCHMARK_SPAN_NAMES) <= names, set(BENCHMARK_SPAN_NAMES) - names
+    # ... and the sites the benchmark does not read yet
+    assert {"models.init_or_load", "tune.metrics_logger",
+            "tune.flush_losses"} <= names
+    root = _one(spans, "tune.setup")
+    in_order = [s["name"] for s in sorted(
+        (s for s in spans if s["parent_id"] == root["span_id"]),
+        key=lambda s: s["wall_ns"])]
+    assert in_order == [
+        "tune.build_models", "tune.load_clip", "tune.vae_encode",
+        "tune.text_encode", "tune.state_create", "tune.metrics_logger",
+        "program.call", "tune.flush_losses"]
+    calls = sorted((s for s in spans if s["name"] == "program.call"),
+                   key=lambda s: s["wall_ns"])
+    assert len(calls) == 3 and all(c["steps"] == 2 for c in calls)
+    assert all(c["program"] == "train_steps" for c in calls)
+    # the root closed at the end of the first chunk's bookkeeping: the later
+    # calls are roots of the same run
+    assert [c["parent_id"] for c in calls[1:]] == [None, None]
+    assert {c["trace_id"] for c in calls} == {root["trace_id"]}
+    # the phase event keeps its place beside the span of its name
+    assert [e["name"] for e in tiny_tune_ledger
+            if e["event"] == "phase"] == ["tune.vae_encode"]
+
+
+def test_the_benchmarks_readers_make_up_the_setup_on_a_tiny_main(
+        tiny_tune_ledger, tmp_path, monkeypatch):
+    bench = _benchmark_spans_module()
+    path = tmp_path / "ledger.jsonl"
+    import json
+
+    path.write_text("".join(json.dumps(e) + "\n" for e in tiny_tune_ledger))
+    monkeypatch.setattr(bench, "ledger_path", lambda ctx: str(path))
+    ctx = {"cell": {"name": "x"}, "window": {"calls": [{}, {}]}}
+    parts = bench.setup_parts(ctx)
+    assert all(v is not None for v in parts.values()), parts
+    named = sum(parts[k] for k in ("models", "clip", "trace_lower", "load",
+                                   "analysis", "execute", "unattributed"))
+    assert named == pytest.approx(parts["setup"])
+    # the analysis pass traced and compiled again, under its own span
+    assert parts["analysis"] > 0 and parts["trace_lower"] > 0
+    assert bench.host_between_calls_ms(ctx) > 0

@@ -18,6 +18,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from videop2p_tpu.obs.spans import span
+
 __all__ = [
     "load_config",
     "add_dependent_args",
@@ -213,10 +215,11 @@ def setup_mesh(bundle: "ModelBundle", mesh_spec: str, video_len: int,
         bundle.unet = bundle.unet.clone(
             row_parallel_dot=make_megatron_out_dot(device_mesh)
         )
-    bundle.unet_params = jax.device_put(
-        bundle.unet_params,
-        param_shardings(device_mesh, bundle.unet_params, tensor_parallel=tp > 1),
-    )
+    with span("models.handover", model="unet", mesh=str(mesh_spec)):
+        bundle.unet_params = jax.device_put(
+            bundle.unet_params,
+            param_shardings(device_mesh, bundle.unet_params, tensor_parallel=tp > 1),
+        )
     return device_mesh
 
 
@@ -467,12 +470,14 @@ def build_models(
     if has_ckpt:
         from videop2p_tpu.models.pipeline_io import load_pipeline
 
-        loaded = load_pipeline(
-            pretrained_model_path,
-            dtype=dtype,
-            frame_attention=frame_attention,
-            gradient_checkpointing=gradient_checkpointing,
-        )
+        with span("models.init_or_load", model="pipeline",
+                  source=pretrained_model_path):
+            loaded = load_pipeline(
+                pretrained_model_path,
+                dtype=dtype,
+                frame_attention=frame_attention,
+                gradient_checkpointing=gradient_checkpointing,
+            )
         if loaded.inflation_report["kept_init"]:
             print(
                 f"[build_models] inflated 2D checkpoint: "
@@ -499,18 +504,20 @@ def build_models(
             if vae is None:
                 vcfg = VAEConfig.tiny() if small else VAEConfig()
                 vae = AutoencoderKL(config=vcfg, dtype=dtype)
-                vae_params = dict(jax.jit(vae.init)(
-                    key, jnp.zeros((1, 64, 64, vcfg.in_channels), dtype), key
-                ))
+                with span("models.init_or_load", model="vae"):
+                    vae_params = dict(jax.jit(vae.init)(
+                        key, jnp.zeros((1, 64, 64, vcfg.in_channels), dtype), key
+                    ))
             if text_encoder is None:
                 ccfg = (
                     CLIPTextConfig.tiny(hidden_size=ucfg.cross_attention_dim)
                     if small else CLIPTextConfig()
                 )
                 text_encoder = CLIPTextEncoder(config=ccfg, dtype=dtype)
-                text_params = dict(jax.jit(text_encoder.init)(
-                    key, jnp.zeros((1, 8), jnp.int32)
-                ))
+                with span("models.init_or_load", model="text_encoder"):
+                    text_params = dict(jax.jit(text_encoder.init)(
+                        key, jnp.zeros((1, 8), jnp.int32)
+                    ))
         return ModelBundle(
             unet=loaded.unet,
             unet_params=loaded.unet_params,
@@ -545,9 +552,15 @@ def build_models(
     s = ucfg.sample_size
     probe = jnp.zeros((1, 2, s, s, ucfg.in_channels), dtype)
     tprobe = jnp.zeros((1, 77, ucfg.cross_attention_dim), dtype)
-    unet_params = jax.jit(unet.init)(key, probe, jnp.asarray(0), tprobe)
-    vae_params = jax.jit(vae.init)(key, jnp.zeros((1, 64, 64, vcfg.in_channels), dtype), key)
-    text_params = jax.jit(text_encoder.init)(key, jnp.zeros((1, 8), jnp.int32))
+    # one span per model: the init program's trace, compile (or cache load)
+    # and dispatch. Its execution is asynchronous: whoever blocks first on
+    # the device pays for it (the tuning CLI's VAE encode), no sync is added
+    with span("models.init_or_load", model="unet"):
+        unet_params = jax.jit(unet.init)(key, probe, jnp.asarray(0), tprobe)
+    with span("models.init_or_load", model="vae"):
+        vae_params = jax.jit(vae.init)(key, jnp.zeros((1, 64, 64, vcfg.in_channels), dtype), key)
+    with span("models.init_or_load", model="text_encoder"):
+        text_params = jax.jit(text_encoder.init)(key, jnp.zeros((1, 8), jnp.int32))
     return ModelBundle(
         unet=unet,
         unet_params=dict(unet_params),

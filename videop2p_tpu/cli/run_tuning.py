@@ -37,6 +37,7 @@ from videop2p_tpu.cli.common import (
     enable_compile_cache,
 )
 from videop2p_tpu.obs import instrumented_jit
+from videop2p_tpu.obs.spans import span
 from videop2p_tpu.core import DDIMScheduler, DDPMScheduler, DependentNoiseSampler
 from videop2p_tpu.data import SingleVideoDataset
 from videop2p_tpu.models import decode_video, encode_video
@@ -158,23 +159,17 @@ def main(
     **unused,
 ) -> str:
     del unused
-    enable_compile_cache()
     n_frames = int(train_data.get("n_sample_frames", 8))
     output_dir = output_dir + dependent_suffix(
         dependent=dependent, decay_rate=decay_rate, window_size=window_size,
         ar_sample=ar_sample, ar_coeff=ar_coeff, eta=eta,
         dependent_weights=dependent_weights,
     )
-    os.makedirs(output_dir, exist_ok=True)
-    with open(os.path.join(output_dir, "config.json"), "w") as f:
-        json.dump({k: v for k, v in locals().items()
-                   if isinstance(v, (str, int, float, bool, dict, list, tuple, type(None)))},
-                  f, indent=2, default=str)
-
-    # unified run record (videop2p_tpu/obs): phases, compile events, train
-    # metrics and telemetry land in one JSONL stream, line-flushed. The
-    # flags→ledger wiring is shared with run_videop2p and the serving
-    # engine (cli/common.make_run_ledger).
+    # unified run record (videop2p_tpu/obs): spans, phases, compile events,
+    # train metrics and telemetry land in one JSONL stream, line-flushed.
+    # The flags→ledger wiring is shared with run_videop2p and the serving
+    # engine (cli/common.make_run_ledger). Made first, so that the root span
+    # finds it.
     run_ledger = make_run_ledger(
         os.path.join(output_dir, "run_ledger.jsonl"),
         ledger=ledger, mesh=mesh,
@@ -183,262 +178,283 @@ def main(
         latency=latency, trace_analysis=trace_analysis,
         program_analysis=program_analysis, incidents=incidents,
     )
+    # root span: what a user waits for before the second chunk starts. It is
+    # closed by hand at the end of the first chunk's bookkeeping, in the
+    # loop below; the `with` closes it where the run ends before that.
+    with span("tune.setup") as setup_span:
+        enable_compile_cache()
+        os.makedirs(output_dir, exist_ok=True)
+        with open(os.path.join(output_dir, "config.json"), "w") as f:
+            json.dump({k: v for k, v in locals().items()
+                       if isinstance(v, (str, int, float, bool, dict, list, tuple, type(None)))},
+                      f, indent=2, default=str)
 
-    sampler = None
-    if dependent:
-        if num_frames != n_frames:
-            print(f"[tune] dependent sampler uses the clip's {n_frames} frames "
-                  f"(--num_frames {num_frames} would not match the data)")
-        sampler = DependentNoiseSampler.create(
-            num_frames=n_frames, decay_rate=decay_rate,
-            window_size=min(window_size, n_frames), ar_sample=ar_sample,
-            ar_coeff=ar_coeff,
-        )
-
-    dtype = {"fp16": jnp.bfloat16, "bf16": jnp.bfloat16, "no": jnp.float32}[mixed_precision]
-    bundle = build_models(
-        pretrained_model_path, dtype=dtype, frame_attention="chunked",
-        gradient_checkpointing=gradient_checkpointing, tiny=tiny,
-        seed=seed or 0,
-    )
-
-    # data → latents (VAE encode once; the clip is fixed, run_tuning.py:282-287)
-    ds = SingleVideoDataset(
-        video_path=train_data["video_path"],
-        prompt=train_data["prompt"],
-        width=int(train_data.get("width", 512)),
-        height=int(train_data.get("height", 512)),
-        n_sample_frames=n_frames,
-        sample_start_idx=int(train_data.get("sample_start_idx", 0)),
-        sample_frame_rate=int(train_data.get("sample_frame_rate", 1)),
-    )
-    video = jnp.asarray(ds.load())[None]  # (1, F, H, W, 3)
-    key = jax.random.key(seed if seed is not None else 0)
-    key, ek = jax.random.split(key)
-    with phase_timer("vae_encode"):
-        # one program, not an op-by-op walk of the encoder (each eager op is
-        # its own compile on a cold start)
-        latents = jax.jit(
-            lambda vp, v, k: encode_video(bundle.vae, vp, v, k)
-        )(bundle.vae_params, video.astype(dtype), ek)
-        latents = jax.block_until_ready(latents.astype(jnp.float32))
-    text_emb = encode_prompts(bundle, [train_data["prompt"]])
-
-    tune_cfg = TuneConfig(
-        learning_rate=learning_rate,
-        scale_lr=scale_lr,
-        lr_scheduler=lr_scheduler,
-        lr_warmup_steps=lr_warmup_steps,
-        max_train_steps=max_train_steps,
-        max_grad_norm=max_grad_norm,
-        gradient_accumulation_steps=gradient_accumulation_steps,
-        trainable_modules=tuple(trainable_modules),
-        train_batch_size=train_batch_size,
-    )
-    tx = make_optimizer(tune_cfg)
-    if mesh:
-        from videop2p_tpu.parallel import latent_sharding
-
-        # shard the bundle BEFORE TrainState.create so the partitioned
-        # trainable/frozen trees (and the optimizer state initialized from
-        # them) inherit the placements
-        device_mesh = setup_mesh(bundle, mesh, n_frames)
-        latents = jax.device_put(latents, latent_sharding(device_mesh))
-    params = bundle.unet_params["params"]
-    state = TrainState.create(params, tx, tune_cfg.trainable_modules)
-
-    first_step = 0
-    if resume_from_checkpoint:
-        path = (
-            latest_checkpoint(output_dir)
-            if resume_from_checkpoint == "latest"
-            else resume_from_checkpoint
-        )
-        if path:
-            state = restore_checkpoint(path, state)
-            first_step = int(state.step)
-            print(f"[tune] resumed from {path} at step {first_step}")
-
-    noise_sched = DDPMScheduler.create_sd(prediction_type=prediction_type)
-    unet_fn = make_unet_fn(bundle.unet)
-    # multiple steps per device call (lax.scan over the per-step keys): one
-    # dispatch per program instead of one per step (train/tuner.py
-    # train_steps)
-    # the state (params + Adam moments) is donated: the carry tree would
-    # otherwise be held twice (in + out) inside the program and copied —
-    # nothing else reads bundle.unet_params after TrainState.create above
-    steps_fn = instrumented_jit(
-        lambda s, k, n: train_steps(
-            unet_fn, tx, s, noise_sched, latents, text_emb, k, num_steps=n,
-            dependent_sampler=sampler, telemetry=telemetry,
-        ),
-        program="train_steps",
-        static_argnums=2,
-        donate_argnums=(0,),
-    )
-
-    # per-step train_loss/lr tracker (the reference's accelerator.log /
-    # TensorBoard trackers, run_tuning.py:234,337,377-378); with an active
-    # ledger every logged step also becomes a ledger `metric` event
-    lr_schedule = make_lr_schedule(tune_cfg)
-    metrics = MetricsLogger(output_dir, ledger=run_ledger)
-    losses = []
-    grad_norms = []  # telemetry mode only: per-step pre-clip global norm
-
-    def flush_losses(next_step):
-        # one sync for the whole buffer (per-step float() would serialize
-        # host dispatch against device compute)
-        flat = np.asarray(jax.block_until_ready(jnp.concatenate(losses)))
-        gflat = (np.asarray(jax.block_until_ready(jnp.concatenate(grad_norms)))
-                 if grad_norms else None)
-        start = next_step - len(flat)
-        for j, lv in enumerate(flat):
-            rec = {"train_loss": float(lv), "lr": float(lr_schedule(start + j))}
-            if gflat is not None:
-                rec["grad_norm"] = float(gflat[j])
-            metrics.log(start + j + 1, rec)
-        losses.clear()
-        grad_norms.clear()
-        return float(flat[-1])
-
-    # chunks align with the periodic boundaries so per-step losses,
-    # checkpoints and validation keep their exact cadence; a cadence of
-    # 0/None disables that feature entirely
-    import math
-
-    steps_per_call = max(int(steps_per_call), 1)
-    cadences = [p for p in (log_every, checkpointing_steps, validation_steps)
-                if p and p > 0]
-    # distinct chunk lengths each compile their own scan program
-    # (static_argnums) — round steps_per_call down to divide the cadences'
-    # gcd when that keeps a useful chunk, so the loop reuses ONE executable
-    g = math.gcd(*cadences) if cadences else steps_per_call
-    if g > 1 and steps_per_call % g and g % steps_per_call:
-        aligned = math.gcd(steps_per_call, g)
-        if aligned >= 5:
-            print(
-                f"[tune] steps_per_call {steps_per_call} → {aligned} to align "
-                f"with the log/checkpoint/validation cadences (gcd {g}); "
-                "smaller chunks amortize the per-call dispatch overhead less "
-                "— align the cadences to a multiple of steps_per_call to "
-                "keep the full chunk"
+        sampler = None
+        if dependent:
+            if num_frames != n_frames:
+                print(f"[tune] dependent sampler uses the clip's {n_frames} frames "
+                      f"(--num_frames {num_frames} would not match the data)")
+            sampler = DependentNoiseSampler.create(
+                num_frames=n_frames, decay_rate=decay_rate,
+                window_size=min(window_size, n_frames), ar_sample=ar_sample,
+                ar_coeff=ar_coeff,
             )
-            steps_per_call = aligned
-    t0 = time.perf_counter()
-    # per-step noise keys derive from (this run key, absolute step) inside
-    # train_steps — logging/checkpoint cadence and resume points cannot
-    # change the training noise sequence
-    key, train_key = jax.random.split(key)
-    i = first_step
-    traced_chunk = False
-    preempted = False
-    restore_signals = _install_preempt_handlers()
-    while i < max_train_steps:
-        nxt = min(
-            [max_train_steps, i + steps_per_call]
-            + [(i // p + 1) * p for p in cadences]
-        )
-        # --trace_analysis: capture ONE post-compile chunk (the second —
-        # the first is dominated by the scan compile) and mine it into a
-        # trace_analysis ledger event; tracing every chunk would write
-        # gigabytes of xplane protos for a long tune
-        do_trace = trace_analysis and not traced_chunk and i > first_step
-        if do_trace:
-            from videop2p_tpu.obs.trace import trace_window
 
-            chunk_ctx = trace_window("train_steps_chunk")
-        else:
-            chunk_ctx = contextlib.nullcontext()
-        with chunk_ctx:
-            out = steps_fn(state, train_key, nxt - i)
+        dtype = {"fp16": jnp.bfloat16, "bf16": jnp.bfloat16, "no": jnp.float32}[mixed_precision]
+        with span("tune.build_models"):
+            bundle = build_models(
+                pretrained_model_path, dtype=dtype, frame_attention="chunked",
+                gradient_checkpointing=gradient_checkpointing, tiny=tiny,
+                seed=seed or 0,
+            )
+
+        # data → latents (VAE encode once; the clip is fixed, run_tuning.py:282-287)
+        with span("tune.load_clip", frames=n_frames):
+            ds = SingleVideoDataset(
+                video_path=train_data["video_path"],
+                prompt=train_data["prompt"],
+                width=int(train_data.get("width", 512)),
+                height=int(train_data.get("height", 512)),
+                n_sample_frames=n_frames,
+                sample_start_idx=int(train_data.get("sample_start_idx", 0)),
+                sample_frame_rate=int(train_data.get("sample_frame_rate", 1)),
+            )
+            video = jnp.asarray(ds.load())[None]  # (1, F, H, W, 3)
+        key = jax.random.key(seed if seed is not None else 0)
+        key, ek = jax.random.split(key)
+        with phase_timer("tune.vae_encode"):
+            # one program, not an op-by-op walk of the encoder (each eager op is
+            # its own compile on a cold start)
+            latents = jax.jit(
+                lambda vp, v, k: encode_video(bundle.vae, vp, v, k)
+            )(bundle.vae_params, video.astype(dtype), ek)
+            latents = jax.block_until_ready(latents.astype(jnp.float32))
+        with span("tune.text_encode"):
+            text_emb = encode_prompts(bundle, [train_data["prompt"]])
+
+        tune_cfg = TuneConfig(
+            learning_rate=learning_rate,
+            scale_lr=scale_lr,
+            lr_scheduler=lr_scheduler,
+            lr_warmup_steps=lr_warmup_steps,
+            max_train_steps=max_train_steps,
+            max_grad_norm=max_grad_norm,
+            gradient_accumulation_steps=gradient_accumulation_steps,
+            trainable_modules=tuple(trainable_modules),
+            train_batch_size=train_batch_size,
+        )
+        tx = make_optimizer(tune_cfg)
+        if mesh:
+            from videop2p_tpu.parallel import latent_sharding
+
+            # shard the bundle BEFORE TrainState.create so the partitioned
+            # trainable/frozen trees (and the optimizer state initialized from
+            # them) inherit the placements
+            device_mesh = setup_mesh(bundle, mesh, n_frames)
+            latents = jax.device_put(latents, latent_sharding(device_mesh))
+        first_step = 0
+        with span("tune.state_create"):
+            params = bundle.unet_params["params"]
+            state = TrainState.create(params, tx, tune_cfg.trainable_modules)
+            if resume_from_checkpoint:
+                path = (
+                    latest_checkpoint(output_dir)
+                    if resume_from_checkpoint == "latest"
+                    else resume_from_checkpoint
+                )
+                if path:
+                    state = restore_checkpoint(path, state)
+                    first_step = int(state.step)
+                    print(f"[tune] resumed from {path} at step {first_step}")
+
+        noise_sched = DDPMScheduler.create_sd(prediction_type=prediction_type)
+        unet_fn = make_unet_fn(bundle.unet)
+        # multiple steps per device call (lax.scan over the per-step keys): one
+        # dispatch per program instead of one per step (train/tuner.py
+        # train_steps)
+        # the state (params + Adam moments) is donated: the carry tree would
+        # otherwise be held twice (in + out) inside the program and copied —
+        # nothing else reads bundle.unet_params after TrainState.create above
+        steps_fn = instrumented_jit(
+            lambda s, k, n: train_steps(
+                unet_fn, tx, s, noise_sched, latents, text_emb, k, num_steps=n,
+                dependent_sampler=sampler, telemetry=telemetry,
+            ),
+            program="train_steps",
+            span_attrs=lambda s, k, n: {"steps": n},
+            static_argnums=2,
+            donate_argnums=(0,),
+        )
+
+        # per-step train_loss/lr tracker (the reference's accelerator.log /
+        # TensorBoard trackers, run_tuning.py:234,337,377-378); with an active
+        # ledger every logged step also becomes a ledger `metric` event
+        lr_schedule = make_lr_schedule(tune_cfg)
+        with span("tune.metrics_logger"):  # imports TensorBoard's writer
+            metrics = MetricsLogger(output_dir, ledger=run_ledger)
+        losses = []
+        grad_norms = []  # telemetry mode only: per-step pre-clip global norm
+
+        def flush_losses(next_step):
+            with span("tune.flush_losses") as flush_span:
+                # one sync for the whole buffer (per-step float() would
+                # serialize host dispatch against device compute)
+                flat = np.asarray(jax.block_until_ready(jnp.concatenate(losses)))
+                gflat = (np.asarray(jax.block_until_ready(jnp.concatenate(grad_norms)))
+                         if grad_norms else None)
+                flush_span.set(steps=len(flat))
+                start = next_step - len(flat)
+                for j, lv in enumerate(flat):
+                    rec = {"train_loss": float(lv), "lr": float(lr_schedule(start + j))}
+                    if gflat is not None:
+                        rec["grad_norm"] = float(gflat[j])
+                    metrics.log(start + j + 1, rec)
+                losses.clear()
+                grad_norms.clear()
+                return float(flat[-1])
+
+        # chunks align with the periodic boundaries so per-step losses,
+        # checkpoints and validation keep their exact cadence; a cadence of
+        # 0/None disables that feature entirely
+        import math
+
+        steps_per_call = max(int(steps_per_call), 1)
+        cadences = [p for p in (log_every, checkpointing_steps, validation_steps)
+                    if p and p > 0]
+        # distinct chunk lengths each compile their own scan program
+        # (static_argnums) — round steps_per_call down to divide the cadences'
+        # gcd when that keeps a useful chunk, so the loop reuses ONE executable
+        g = math.gcd(*cadences) if cadences else steps_per_call
+        if g > 1 and steps_per_call % g and g % steps_per_call:
+            aligned = math.gcd(steps_per_call, g)
+            if aligned >= 5:
+                print(
+                    f"[tune] steps_per_call {steps_per_call} → {aligned} to align "
+                    f"with the log/checkpoint/validation cadences (gcd {g}); "
+                    "smaller chunks amortize the per-call dispatch overhead less "
+                    "— align the cadences to a multiple of steps_per_call to "
+                    "keep the full chunk"
+                )
+                steps_per_call = aligned
+        t0 = time.perf_counter()
+        # per-step noise keys derive from (this run key, absolute step) inside
+        # train_steps — logging/checkpoint cadence and resume points cannot
+        # change the training noise sequence
+        key, train_key = jax.random.split(key)
+        i = first_step
+        traced_chunk = False
+        preempted = False
+        restore_signals = _install_preempt_handlers()
+        while i < max_train_steps:
+            nxt = min(
+                [max_train_steps, i + steps_per_call]
+                + [(i // p + 1) * p for p in cadences]
+            )
+            # --trace_analysis: capture ONE post-compile chunk (the second —
+            # the first is dominated by the scan compile) and mine it into a
+            # trace_analysis ledger event; tracing every chunk would write
+            # gigabytes of xplane protos for a long tune
+            do_trace = trace_analysis and not traced_chunk and i > first_step
             if do_trace:
-                jax.block_until_ready(out)  # the capture must hold the work
-                traced_chunk = True
-        if telemetry:
-            state, chunk_losses, chunk_gnorms = out
-            grad_norms.append(chunk_gnorms)
-        else:
-            state, chunk_losses = out
-        losses.append(chunk_losses)  # device-side; no per-chunk host sync
-        first_chunk = i == first_step
-        i = nxt
-        if _PREEMPT_EVENT.is_set():
-            # SIGTERM/SIGINT landed: save the final checkpoint at this
-            # chunk boundary and exit cleanly (skip validation/export —
-            # the resumed run redoes them); handled after the loop
-            preempted = True
-            break
-        if (log_every and i % log_every == 0) or i == max_train_steps or first_chunk:
-            loss = flush_losses(i)
-            rate = (i - first_step) / max(time.perf_counter() - t0, 1e-9)
-            print(f"[tune] step {i}/{max_train_steps} loss={loss:.4f} "
-                  f"({rate:.2f} it/s)")
-        if checkpointing_steps and i % checkpointing_steps == 0:
-            save_checkpoint(output_dir, jax.device_get(state), i)
-        if (validation_steps and i % validation_steps == 0) or i == max_train_steps:
-            _validate(
-                bundle, state, latents, validation_data, output_dir, i,
-                dependent_weights=dependent_weights, sampler=sampler,
-                text_emb=text_emb, key=key,
-            )
-    restore_signals()
-    if preempted:
-        if losses:
-            flush_losses(i)
-        metrics.close()
-        ckpt_path = save_checkpoint(output_dir, jax.device_get(state), i)
-        print(f"[tune] preempted at step {i} — checkpoint saved to "
-              f"{ckpt_path}; resume with resume_from_checkpoint: latest")
-        if run_ledger is not None:
-            run_ledger.event("preempted", step=i, checkpoint=ckpt_path)
-            run_ledger.close()
-        return output_dir
-    if losses:  # flush the tail of the buffer
-        flush_losses(max_train_steps)
-    metrics.close()
-    if run_ledger is not None:
-        run_ledger.memory_snapshot(note="after_training")
-    if device_telemetry and mesh:
-        # the tuned params must be IDENTICAL on every mesh replica (dp=1
-        # single-clip tuning replicates non-tensor-parallel params over the
-        # whole mesh); a nonzero divergence means a replica desynced — the
-        # ledger event joins the zero-noise-floor COMM_RULES gate
-        from videop2p_tpu.obs.comm import tree_replica_divergence
+                from videop2p_tpu.obs.trace import trace_window
 
-        div_axes = tuple(
-            a for a in device_mesh.axis_names if device_mesh.shape[a] > 1
-        )
-        if div_axes:
-            div = float(tree_replica_divergence(
-                state.params, device_mesh, axes=div_axes
-            ))
+                chunk_ctx = trace_window("train_steps_chunk")
+            else:
+                chunk_ctx = contextlib.nullcontext()
+            with chunk_ctx:
+                out = steps_fn(state, train_key, nxt - i)
+                if do_trace:
+                    jax.block_until_ready(out)  # the capture must hold the work
+                    traced_chunk = True
+            if telemetry:
+                state, chunk_losses, chunk_gnorms = out
+                grad_norms.append(chunk_gnorms)
+            else:
+                state, chunk_losses = out
+            losses.append(chunk_losses)  # device-side; no per-chunk host sync
+            first_chunk = i == first_step
+            i = nxt
+            if _PREEMPT_EVENT.is_set():
+                # SIGTERM/SIGINT landed: save the final checkpoint at this
+                # chunk boundary and exit cleanly (skip validation/export —
+                # the resumed run redoes them); handled after the loop
+                preempted = True
+                break
+            if (log_every and i % log_every == 0) or i == max_train_steps or first_chunk:
+                loss = flush_losses(i)
+                rate = (i - first_step) / max(time.perf_counter() - t0, 1e-9)
+                print(f"[tune] step {i}/{max_train_steps} loss={loss:.4f} "
+                      f"({rate:.2f} it/s)")
+            if checkpointing_steps and i % checkpointing_steps == 0:
+                with span("tune.checkpoint", step=i):
+                    save_checkpoint(output_dir, jax.device_get(state), i)
+            if (validation_steps and i % validation_steps == 0) or i == max_train_steps:
+                with span("tune.validate", step=i):
+                    _validate(
+                        bundle, state, latents, validation_data, output_dir, i,
+                        dependent_weights=dependent_weights, sampler=sampler,
+                        text_emb=text_emb, key=key,
+                    )
+            setup_span.end()  # the first chunk's bookkeeping is done (later: no-op)
+        restore_signals()
+        if preempted:
+            if losses:
+                flush_losses(i)
+            metrics.close()
+            with span("tune.checkpoint", step=i):
+                ckpt_path = save_checkpoint(output_dir, jax.device_get(state), i)
+            print(f"[tune] preempted at step {i} — checkpoint saved to "
+                  f"{ckpt_path}; resume with resume_from_checkpoint: latest")
             if run_ledger is not None:
-                run_ledger.divergence("params_after_training", div,
-                                      axes=list(div_axes))
-            print(f"[tune] param replica divergence over {div_axes}: {div}"
-                  + ("  <-- REPLICAS DIVERGED (must be 0.0)" if div else ""))
+                run_ledger.event("preempted", step=i, checkpoint=ckpt_path)
+                run_ledger.close()
+            return output_dir
+        if losses:  # flush the tail of the buffer
+            flush_losses(max_train_steps)
+        metrics.close()
+        if run_ledger is not None:
+            run_ledger.memory_snapshot(note="after_training")
+        if device_telemetry and mesh:
+            # the tuned params must be IDENTICAL on every mesh replica (dp=1
+            # single-clip tuning replicates non-tensor-parallel params over the
+            # whole mesh); a nonzero divergence means a replica desynced — the
+            # ledger event joins the zero-noise-floor COMM_RULES gate
+            from videop2p_tpu.obs.comm import tree_replica_divergence
 
-    save_pipeline(
-        output_dir,
-        bundle.unet.config,
-        {"params": state.params},
-        source_dir=bundle.source_dir,
-        scheduler_config={
-            "_class_name": "DDIMScheduler",
-            "beta_start": 0.00085,
-            "beta_end": 0.012,
-            "beta_schedule": "scaled_linear",
-            "clip_sample": False,
-            "set_alpha_to_one": False,
-            "steps_offset": 1,
-        },
-    )
-    print(f"[tune] saved pipeline to {output_dir}")
-    if run_ledger is not None:
-        run_ledger.event("artifacts", pipeline_dir=output_dir)
-        run_ledger.close()
-        print(f"[tune] run ledger: {run_ledger.path}")
-    return output_dir
+            div_axes = tuple(
+                a for a in device_mesh.axis_names if device_mesh.shape[a] > 1
+            )
+            if div_axes:
+                div = float(tree_replica_divergence(
+                    state.params, device_mesh, axes=div_axes
+                ))
+                if run_ledger is not None:
+                    run_ledger.divergence("params_after_training", div,
+                                          axes=list(div_axes))
+                print(f"[tune] param replica divergence over {div_axes}: {div}"
+                      + ("  <-- REPLICAS DIVERGED (must be 0.0)" if div else ""))
+
+        save_pipeline(
+            output_dir,
+            bundle.unet.config,
+            {"params": state.params},
+            source_dir=bundle.source_dir,
+            scheduler_config={
+                "_class_name": "DDIMScheduler",
+                "beta_start": 0.00085,
+                "beta_end": 0.012,
+                "beta_schedule": "scaled_linear",
+                "clip_sample": False,
+                "set_alpha_to_one": False,
+                "steps_offset": 1,
+            },
+        )
+        print(f"[tune] saved pipeline to {output_dir}")
+        if run_ledger is not None:
+            run_ledger.event("artifacts", pipeline_dir=output_dir)
+            run_ledger.close()
+            print(f"[tune] run ledger: {run_ledger.path}")
+        return output_dir
 
 
 def run_distillation(

@@ -14,6 +14,10 @@ executed, and measured:
     at compile time (:func:`program_label` / :func:`instrumented_jit`);
   * ``program_call`` — per-jitted-program cache hit/miss + dispatch
     wall-clock from :func:`instrumented_jit`;
+  * ``span`` — every timed region (:class:`videop2p_tpu.obs.spans.span`):
+    phases, ``program.call`` with its ``program.trace`` / ``program.lower``
+    / ``program.backend_compile`` / ``program.execute`` /
+    ``program.analysis`` children, and the CLI's own sites;
   * ``telemetry`` — decoded in-program telemetry summaries
     (:mod:`videop2p_tpu.obs.telemetry`);
   * ``memory`` — per-device ``memory_stats()`` snapshots where the
@@ -37,6 +41,8 @@ import uuid
 from typing import Any, Dict, Iterator, List, Optional
 
 import jax
+
+from videop2p_tpu.obs.spans import Tracer, current_span, make_trace_id, span
 
 __all__ = [
     "RunLedger",
@@ -66,6 +72,19 @@ _SUPPRESS_COMPILE: contextvars.ContextVar[bool] = contextvars.ContextVar(
 )
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# jax's duration events → the child span each becomes under the open
+# `program.call` / `program.analysis` (start = now − duration)
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "program.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "program.lower",
+    _COMPILE_EVENT: "program.backend_compile",
+}
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# what the persistent cache said since this thread's last backend compile:
+# the events fire inside the compile they belong to, just before its duration
+_CACHE_SEEN = threading.local()
 _LISTENER_INSTALLED = False
 
 # kill-switch for the automatic compiled-program introspection (the AOT
@@ -109,24 +128,78 @@ def suppress_compile_events() -> Iterator[None]:
         _SUPPRESS_COMPILE.reset(token)
 
 
+def _compile_sink() -> Optional[span]:
+    """The open span a compile event becomes a child of: the innermost live
+    one, past ``program.execute`` — which then starts over, since what ran
+    before the compile ended was not the execution."""
+    cur = current_span()
+    if cur is None or cur.name != "program.execute":
+        return cur
+    cur.restart()
+    return cur.parent
+
+
 def _install_compile_listener() -> None:
-    """Register ONE process-wide jax.monitoring listener that forwards
-    backend-compile durations to the active ledger. jax 0.4.x has no
-    per-listener unregister, so the listener is a permanent no-op when no
-    ledger is active rather than something we add/remove per run."""
+    """Register the ONE process-wide pair of jax.monitoring listeners.
+
+    Backend-compile durations go to the active ledger's ``compile`` events
+    and totals, as ever. Besides, trace / lower / backend-compile durations
+    fired under a program label become child spans of the open span
+    (``program.trace``, ``program.lower``, ``program.backend_compile`` with
+    ``cache_hit`` and ``cache_retrieval_s`` from the persistent cache's own
+    events); fired with no label — every eager ``jnp`` op does — they are
+    summed into ``unspanned_*`` counters of the open span, or of the ledger
+    where none is open (``run_end`` carries those), never one line each.
+    jax has no per-listener unregister, so the listeners are permanent
+    no-ops when no ledger is active rather than something we add/remove per
+    run."""
     global _LISTENER_INSTALLED
     if _LISTENER_INSTALLED:
         return
 
     def on_duration(event: str, duration: float, **kw) -> None:
-        if event != _COMPILE_EVENT or _SUPPRESS_COMPILE.get():
+        if event == _CACHE_RETRIEVAL_EVENT:
+            _CACHE_SEEN.retrieval_s = duration
+            return
+        name = _COMPILE_SPANS.get(event)
+        if name is None:
             return
         led = current_ledger()
-        if led is not None:
-            led._on_compile(duration, _PROGRAM.get())
+        if led is None:
+            return
+        program = _PROGRAM.get()
+        backend = event == _COMPILE_EVENT
+        if backend and not _SUPPRESS_COMPILE.get():
+            led._on_compile(duration, program)
+        attrs = {}
+        if backend:  # popped either way: they belong to this compile only
+            attrs["cache_hit"] = _CACHE_SEEN.__dict__.pop("hit", None)
+            retrieval = _CACHE_SEEN.__dict__.pop("retrieval_s", None)
+            if retrieval is not None:
+                attrs["cache_retrieval_s"] = round(retrieval, 6)
+        if not led.tracer.enabled:
+            return
+        sink = _compile_sink()
+        if program is not None and sink is not None:
+            sink.child(name, time.time_ns() - int(duration * 1e9), duration,
+                       **attrs)
+            return
+        counters = sink if sink is not None else led
+        if backend:
+            counters.count("unspanned_backend_compile_s", duration)
+            counters.count("unspanned_backend_compiles")
+        else:
+            counters.count("unspanned_trace_lower_events")
+
+    def on_event(event: str, **kw) -> None:
+        if event == _CACHE_REQUEST_EVENT:
+            _CACHE_SEEN.hit = False
+        elif event == _CACHE_HIT_EVENT:
+            _CACHE_SEEN.hit = True
 
     try:
         jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(on_event)
     except Exception:  # noqa: BLE001 — observability must never break a run
         return
     _LISTENER_INSTALLED = True
@@ -196,6 +269,15 @@ class RunLedger:
         self._closed = False
         self._activated = False
         self.compile_seconds: List[float] = []  # drained by bench records
+        # spans (obs/spans.py): every `span(...)` that finds this ledger
+        # active writes through this tracer, under this run's trace id. The
+        # serving engine puts its own tracer here, so that its `tracing`
+        # switch governs every span of its ledger.
+        self.trace_id = make_trace_id()
+        self.tracer = Tracer(self, enabled=True)
+        # compile events fired with no program label and no span open
+        # (count()); `run_end` carries them
+        self.counters: Dict[str, float] = {}
         # per-dispatch execute-timing reservoirs (obs/timing.py): opt-in
         # via the constructor (the CLIs' --latency) or the process-wide
         # VIDEOP2P_OBS_LATENCY env var; summaries flush as execute_timing
@@ -405,6 +487,10 @@ class RunLedger:
         for program, summary in self.execute_timing_summary().items():
             self.event("execute_timing", program=program, **summary)
 
+    def count(self, key: str, n: float = 1) -> None:
+        with self._timing_lock:
+            self.counters[key] = self.counters.get(key, 0) + n
+
     def _on_compile(self, seconds: float, program: Optional[str]) -> None:
         self.compile_seconds.append(float(seconds))
         self.event("compile", seconds=round(float(seconds), 4),
@@ -484,7 +570,8 @@ class RunLedger:
             self.flush_execute_timing()
         except Exception:  # noqa: BLE001 — closing must always succeed
             pass
-        self.event("run_end", compile_events=len(self.compile_seconds))
+        self.event("run_end", compile_events=len(self.compile_seconds),
+                   **self.counters)
         with self._lock:
             self._closed = True
             try:
@@ -544,10 +631,16 @@ def _analyze_into_ledger(led: "RunLedger", jitted, program: str,
         led.comm_analysis(program, comm_rec)
 
 
-def instrumented_jit(fun, *, program: str, analyze: bool = True, **jit_kwargs):
+def instrumented_jit(fun, *, program: str, analyze: bool = True,
+                     span_attrs=None, **jit_kwargs):
     """``jax.jit`` plus ledger instrumentation.
 
-    Each call through the wrapper records a ``program_call`` event with the
+    Each call through the wrapper runs under a ``program.call`` span
+    (``program``, ``cache_miss``, and whatever ``span_attrs(*args,
+    **kwargs)`` returns — the tuning CLI's ``steps``) with the children
+    ``program.trace`` / ``program.lower`` / ``program.backend_compile``
+    (jax's own durations, on a miss), ``program.execute`` and, on a miss,
+    ``program.analysis``; and records a ``program_call`` event with the
     program label, whether the call MISSED the jit cache (compiled), and
     the dispatch wall-clock; compile events fired inside the call are
     attributed to the label. On a cache miss (with ``analyze=True``, the
@@ -591,46 +684,59 @@ def instrumented_jit(fun, *, program: str, analyze: bool = True, **jit_kwargs):
                 abs_args, abs_kwargs = abstractify_args(args, kwargs)
             except Exception:  # noqa: BLE001
                 skip_reason = "abstractify_failed"
-        t0 = time.perf_counter()
-        with program_label(program):
-            out = jitted(*args, **kwargs)
-        dt = time.perf_counter() - t0
-        blocked_dt = None
-        if led.timing_enabled():
-            # opt-in only: blocking here trades away async-dispatch
-            # overlap for a measured end-to-end latency — values are
-            # untouched either way (host-side timing cannot change
-            # device results), so the off path stays bit-exact AND
-            # overlap-preserving
-            try:
-                jax.block_until_ready(out)
-                blocked_dt = time.perf_counter() - t0
-                led.record_execute(program, dt, blocked_dt)
-            except Exception:  # noqa: BLE001 — obs never kills a run
+        try:
+            attrs = span_attrs(*args, **kwargs) if span_attrs else {}
+        except Exception:  # noqa: BLE001 — obs never kills a run
+            attrs = {}
+        with program_label(program), \
+                span("program.call", program=program, **attrs) as call:
+            # program.execute: dispatch → ready where the run blocks anyway
+            # (--latency); the dispatch alone, `blocked: false`, where it
+            # does not — the span never adds a sync of its own. A compile
+            # at the head of the call is not part of it (_compile_sink).
+            with span("program.execute") as execute:
+                out = jitted(*args, **kwargs)
+                dt = call.elapsed()
                 blocked_dt = None
-        miss = None
-        if before is not None:
-            try:
-                miss = jitted._cache_size() > before
-            except Exception:  # noqa: BLE001
-                miss = None
-        call_fields = {"program": program, "cache_miss": miss,
-                       "dispatch_s": round(dt, 4)}
-        if blocked_dt is not None:
-            call_fields["blocked_s"] = round(blocked_dt, 4)
-        led.event("program_call", **call_fields)
-        if miss:
-            if skip_reason is None:
+                if led.timing_enabled():
+                    # opt-in only: blocking here trades away async-dispatch
+                    # overlap for a measured end-to-end latency — values
+                    # are untouched either way (host-side timing cannot
+                    # change device results), so the off path stays
+                    # bit-exact AND overlap-preserving
+                    try:
+                        jax.block_until_ready(out)
+                        blocked_dt = call.elapsed()
+                        led.record_execute(program, dt, blocked_dt)
+                    except Exception:  # noqa: BLE001 — obs never kills a run
+                        blocked_dt = None
+                if blocked_dt is None:
+                    execute.set(blocked=False)
+            miss = None
+            if before is not None:
                 try:
-                    _analyze_into_ledger(
-                        led, jitted, program, abs_args, abs_kwargs
-                    )
-                except Exception:  # noqa: BLE001 — obs never kills a run
+                    miss = jitted._cache_size() > before
+                except Exception:  # noqa: BLE001
+                    miss = None
+            call.set(cache_miss=miss)
+            call_fields = {"program": program, "cache_miss": miss,
+                           "dispatch_s": round(dt, 4)}
+            if blocked_dt is not None:
+                call_fields["blocked_s"] = round(blocked_dt, 4)
+            led.event("program_call", **call_fields)
+            if miss:
+                if skip_reason is None:
+                    try:
+                        with span("program.analysis"):
+                            _analyze_into_ledger(
+                                led, jitted, program, abs_args, abs_kwargs
+                            )
+                    except Exception:  # noqa: BLE001 — obs never kills a run
+                        led.event("program_analysis_skipped",
+                                  program=program, reason="analysis_error")
+                else:
                     led.event("program_analysis_skipped", program=program,
-                              reason="analysis_error")
-            else:
-                led.event("program_analysis_skipped", program=program,
-                          reason=skip_reason)
+                              reason=skip_reason)
         return out
 
     wrapper._jitted = jitted  # escape hatch (lower/compile introspection)

@@ -699,9 +699,9 @@ def _find_sidecar(events, ledger_path: str) -> Optional[str]:
 
 
 def _mine_trace_events(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
-    """ISSUE 6 satellite: a run that captured device traces via
-    ``utils.profiling.trace`` (VIDEOP2P_TRACE_DIR) recorded only a
-    ``trace`` event (name + directory) — mine any such directory that
+    """ISSUE 6 satellite: a ledger may hold ``trace`` events (name +
+    directory of a device trace; runs before ISSUE 26 wrote them through
+    ``utils.profiling.trace``) — mine any such directory that
     still exists on disk into a synthetic ``trace_analysis`` event for
     the "Where time goes" section, instead of silently ignoring it.
     Windows that already have a ``trace_analysis`` (trace_window runs)

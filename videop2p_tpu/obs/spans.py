@@ -16,24 +16,38 @@ ledgers back into the tree.
 
 House pattern: tracing is OFF by default. A disabled :class:`Tracer` is
 inert — no ids are minted, no events written, the serving path stays
-bit-exact (pinned by tests/test_tracing.py). Stdlib only; the import-guard
-test walks this module.
+bit-exact (pinned by tests/test_tracing.py). Stdlib + jax only; the
+import-guard test walks this module.
+
+:class:`span` (ISSUE 26) is the ONE way the program times a region: a
+context manager that writes one such ``span`` event when it closes, through
+the :class:`Tracer` of the active ledger, and enters a
+``jax.profiler.TraceAnnotation`` of the same name for the same interval, so
+that an open profiler session shows the region in the xplane's host plane
+on the clock the device ops are on. ``utils.profiling.phase_timer`` and
+``obs.ledger.instrumented_jit`` are thin callers of it.
 """
 
 from __future__ import annotations
 
+import contextvars
 import time
 import uuid
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
+
+import jax
 
 __all__ = [
+    "BENCHMARK_SPAN_NAMES",
     "SPAN_EVENT_FIELDS",
     "SPAN_SEGMENTS",
     "Tracer",
+    "current_span",
     "format_traceparent",
     "make_span_id",
     "make_trace_id",
     "parse_traceparent",
+    "span",
 ]
 
 # Schema pin: every `span` ledger event carries AT LEAST these keys
@@ -59,7 +73,28 @@ SPAN_SEGMENTS = {
     "serve.resolve": "resolve",
     "serve.dispatch": "dispatch",
     "serve.decode": "decode",
+    "serve.gif_write": "gif_write",
 }
+
+# The span names `benchmark/layer_metrics/` reads from the tune path's
+# ledger (benchmark/harness/spans.py keeps the same tuple as READ_NAMES; it
+# may import nothing of the program). tests/test_spans.py holds a tiny
+# `run_tuning.main` to emitting each of them: a rename here fails a test
+# before it turns a listed metric to null.
+BENCHMARK_SPAN_NAMES = (
+    "tune.setup",
+    "tune.build_models",
+    "tune.load_clip",
+    "tune.vae_encode",
+    "tune.text_encode",
+    "tune.state_create",
+    "program.call",
+    "program.trace",
+    "program.lower",
+    "program.backend_compile",
+    "program.analysis",
+    "program.execute",
+)
 
 
 def make_trace_id() -> str:
@@ -135,3 +170,173 @@ class Tracer:
         fields.update(attrs)
         self.ledger.event("span", **fields)
         return fields
+
+
+# the innermost open LIVE span of this thread / context: a child's
+# `parent_id` is its `span_id`, and a trace id given to it (the engine's
+# request) is inherited. A plain `threading.Thread` starts with an empty
+# context, so its spans are roots; `contextvars.copy_context().run` carries
+# the parent across.
+_CURRENT: contextvars.ContextVar[Optional["span"]] = contextvars.ContextVar(
+    "videop2p_obs_span", default=None
+)
+
+# nested intervals closer than this to their enclosing one count as inside it
+_NEST_EPS_S = 1e-4
+
+
+def current_span() -> Optional["span"]:
+    """The innermost open live span of this context, or None."""
+    return _CURRENT.get()
+
+
+_current_ledger = None  # obs.ledger.current_ledger, bound at first use
+
+
+def _active_tracer() -> Optional[Tracer]:
+    global _current_ledger
+    if _current_ledger is None:
+        # lazy: obs.ledger imports this module at its top
+        from videop2p_tpu.obs.ledger import current_ledger
+
+        _current_ledger = current_ledger
+    led = _current_ledger()
+    return None if led is None else led.tracer
+
+
+class span:
+    """Time a region: ``with span("tune.load_clip", frames=8): ...``.
+
+    LIVE (an active ledger whose tracer is enabled, or an enabled
+    ``tracer=`` handed in — the engine's, since several in-process engines
+    each own a ledger): mints a ``span_id``, takes ``parent_id`` and the
+    trace id from the enclosing live span (else ``trace_id=`` /
+    ``parent_id=``, else the ledger's own ``trace_id``: one per run), reads
+    ``wall_ns`` at entry and a monotonic ``duration_s``, and writes ONE
+    ``span`` event **when it closes** — not at ``RunLedger.close()``: a run
+    that ends by an exception unwinding through ``main`` never gets there,
+    and the span is on disk all the same (``status`` ``"error"``).
+
+    Not live (no ledger, or tracing off): nothing but the
+    ``TraceAnnotation`` and one clock read — no id minted, no event.
+
+    For the one region that is not lexical, the same object is a handle:
+    ``.end()`` closes it early (a later ``.end()`` / ``__exit__`` does
+    nothing). ``.set(**attrs)`` adds fields, ``.count(key, n)`` adds to a
+    counter field, ``.child(...)`` records a child measured by someone else
+    (jax's compile listeners): children nested in another collapse into
+    the outermost, and all are written just before the parent.
+    """
+
+    __slots__ = ("name", "attrs", "span_id", "parent", "duration_s",
+                 "_trace_id", "_parent_id", "_tracer", "_wall_ns", "_t0",
+                 "_token", "_annotation", "_children", "_open")
+
+    def __init__(self, name: str, *, trace_id: Optional[str] = None,
+                 parent_id: Optional[str] = None,
+                 tracer: Optional[Tracer] = None, **attrs: Any):
+        self.name = name
+        self.attrs = attrs
+        self.span_id: Optional[str] = None
+        self.parent: Optional["span"] = None
+        self.duration_s: Optional[float] = None
+        self._trace_id = trace_id
+        self._parent_id = parent_id
+        self._tracer = tracer
+        self._children: Optional[List[Tuple[str, int, float, Dict]]] = None
+        self._open = False
+
+    @property
+    def live(self) -> bool:
+        return self.span_id is not None
+
+    def __enter__(self) -> "span":
+        tracer = self._tracer if self._tracer is not None else _active_tracer()
+        self._tracer = tracer if tracer is not None and tracer.enabled else None
+        if self._tracer is not None:
+            parent = self.parent = _CURRENT.get()
+            if parent is not None:
+                if self._parent_id is None:
+                    self._parent_id = parent.span_id
+                if self._trace_id is None:
+                    self._trace_id = parent._trace_id
+            self.span_id = make_span_id()
+            self._token = _CURRENT.set(self)
+            self._wall_ns = time.time_ns()
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self._open = True
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.end(status="ok" if exc_type is None else "error")
+
+    def elapsed(self) -> float:
+        """Seconds since entry (or since :meth:`restart`)."""
+        return time.perf_counter() - self._t0
+
+    def restart(self) -> None:
+        """Move the start to now: what ran since entry was someone else's
+        (``program.execute`` after the compile its call began with)."""
+        self._t0 = time.perf_counter()
+        if self.live:
+            self._wall_ns = time.time_ns()
+
+    def set(self, **attrs: Any) -> None:
+        self.attrs.update(attrs)
+
+    def count(self, key: str, n: float = 1) -> None:
+        self.attrs[key] = self.attrs.get(key, 0) + n
+
+    def child(self, name: str, wall_ns: int, duration_s: float,
+              **attrs: Any) -> None:
+        if self.live:
+            if self._children is None:
+                self._children = []
+            self._children.append((name, int(wall_ns), float(duration_s),
+                                   attrs))
+
+    def end(self, status: str = "ok") -> None:
+        if not self._open:
+            return
+        self._open = False
+        self.duration_s = time.perf_counter() - self._t0
+        self._annotation.__exit__(None, None, None)
+        if not self.live:
+            return
+        try:
+            _CURRENT.reset(self._token)
+        except ValueError:  # closed in another context than it opened in
+            pass
+        for key, value in self.attrs.items():
+            if isinstance(value, float):
+                self.attrs[key] = round(value, 6)
+        trace_id = self._trace_id or self._tracer.ledger.trace_id
+        for name, wall_ns, dur, child_attrs in self._outermost_children():
+            self._tracer.emit(
+                name, trace_id=trace_id, span_id=make_span_id(),
+                parent_id=self.span_id, wall_ns=wall_ns, duration_s=dur,
+                **child_attrs)
+        self._tracer.emit(
+            self.name, trace_id=trace_id, span_id=self.span_id,
+            parent_id=self._parent_id, wall_ns=self._wall_ns,
+            duration_s=self.duration_s, status=status, **self.attrs)
+
+    def _outermost_children(self):
+        """Children in start order; one that lies inside an earlier one
+        (jax traces the inner jits of a program inside the outer trace, and
+        helper functions inside the lowering, and reports each) is counted
+        on that one (``nested``) and not written."""
+        kept: List[list] = []
+        reach = None  # (end, row) of the last child written
+        for name, wall_ns, dur, attrs in sorted(
+                self._children or (), key=lambda c: (c[1], -c[2])):
+            end = wall_ns * 1e-9 + dur
+            if reach is not None and end <= reach[0] + _NEST_EPS_S:
+                reach[1][3]["nested"] = reach[1][3].get("nested", 0) + 1
+                continue
+            row = [name, wall_ns, dur, dict(attrs)]
+            kept.append(row)
+            reach = (end, row)
+        return kept

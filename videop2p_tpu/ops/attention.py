@@ -47,7 +47,14 @@ __all__ = [
 # shapes: q (B, F, H, N, D); k, v (B, H, N, D) — frame-0 KV shared across F
 FrameAttentionFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
 
+# every implementation below runs under this named scope (metadata on the
+# ops, no device work): the profiler's device events carry it, the chunked
+# forward's lax.map body and its checkpointed backward included. Where an
+# implementation hands over to another, the scope opens after that return.
+_SCOPE = "ops.frame_attention"
 
+
+@jax.named_scope(_SCOPE)
 def dense_frame_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     scale = q.shape[-1] ** -0.5
     sim = jnp.einsum("bfhqd,bhkd->bfhqk", q, k) * scale
@@ -65,7 +72,6 @@ def chunked_frame_attention(
     if n % q_chunk != 0 or n <= q_chunk:
         return dense_frame_attention(q, k, v)
     nc = n // q_chunk
-    qc = jnp.moveaxis(q.reshape(b, f, h, nc, q_chunk, d), 3, 0)  # (nc,B,F,H,C,D)
 
     @jax.checkpoint
     def one_chunk(q_blk):
@@ -74,10 +80,13 @@ def chunked_frame_attention(
         probs = jax.nn.softmax(sim.astype(jnp.float32), axis=-1).astype(q.dtype)
         return jnp.einsum("bfhqk,bhkd->bfhqd", probs, v)
 
-    out = jax.lax.map(one_chunk, qc)  # (nc, B, F, H, C, D)
-    return jnp.moveaxis(out, 0, 3).reshape(b, f, h, n, d)
+    with jax.named_scope(_SCOPE):
+        qc = jnp.moveaxis(q.reshape(b, f, h, nc, q_chunk, d), 3, 0)  # (nc,B,F,H,C,D)
+        out = jax.lax.map(one_chunk, qc)  # (nc, B, F, H, C, D)
+        return jnp.moveaxis(out, 0, 3).reshape(b, f, h, n, d)
 
 
+@jax.named_scope(_SCOPE)
 def flash_frame_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     """Pallas TPU flash attention with the frame axis folded into batch and
     the shared frame-0 KV broadcast per frame."""
@@ -91,6 +100,7 @@ def flash_frame_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array
     return out.reshape(b, f, h, n, d)
 
 
+@jax.named_scope(_SCOPE)
 def flash_rect_frame_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     """Pallas TPU flash attention with frames folded into the QUERY length.
 
@@ -184,11 +194,12 @@ def fused_frame_attention(
         # the grid would silently drop the remainder queries — fall back to
         # the exact chunked kernel (same convention as its own fallback)
         return chunked_frame_attention(q, k, v)
-    qr = q.transpose(0, 2, 1, 3, 4).reshape(b * h, f * n, d)
-    kr = k.reshape(b * h, n, d)
-    vr = v.reshape(b * h, n, d)
-    out = _fused_rect(qr, kr, vr, q_blk, interpret)
-    return out.reshape(b, h, f, n, d).transpose(0, 2, 1, 3, 4)
+    with jax.named_scope(_SCOPE):
+        qr = q.transpose(0, 2, 1, 3, 4).reshape(b * h, f * n, d)
+        kr = k.reshape(b * h, n, d)
+        vr = v.reshape(b * h, n, d)
+        out = _fused_rect(qr, kr, vr, q_blk, interpret)
+        return out.reshape(b, h, f, n, d).transpose(0, 2, 1, 3, 4)
 
 
 def _fused_fwd(q, k, v, q_blk, interpret):

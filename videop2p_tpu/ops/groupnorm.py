@@ -144,7 +144,8 @@ def fused_group_norm(
     through :func:`group_norm_reference` (same convention as the fused
     attention kernel — the Pallas body itself is inference-path).
     """
-    return _fused_gn(x, scale, bias, num_groups, eps, act, interpret)
+    with jax.named_scope("ops.group_norm"):
+        return _fused_gn(x, scale, bias, num_groups, eps, act, interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -210,6 +211,7 @@ def _fused_gn_bwd(num_groups, eps, act, interpret, res, g):
 _fused_gn.defvjp(_fused_gn_fwd, _fused_gn_bwd)
 
 
+@jax.named_scope("ops.group_norm")
 def group_norm_reference(
     x: jax.Array,
     scale: jax.Array,

@@ -106,6 +106,7 @@ from videop2p_tpu.obs.spans import (
     Tracer,
     make_span_id,
     make_trace_id,
+    span,
     parse_traceparent,
 )
 from videop2p_tpu.serve.programs import ProgramSet, ProgramSpec
@@ -343,7 +344,10 @@ class EditEngine:
                   "tracing": bool(tracing)},
             mesh=spec.mesh,
         )
-        self.tracer = Tracer(self.ledger, enabled=tracing)
+        # the engine's `tracing` switch governs every span of its ledger,
+        # the `program.call` spans of its dispatches included (obs/spans.py
+        # `span` writes through the active ledger's tracer)
+        self.tracer = self.ledger.tracer = Tracer(self.ledger, enabled=tracing)
         self._tracing = self.tracer.enabled
         self._slo = bool(slo)
         # cost & capacity plane (ISSUE 19 — obs/cost.py): static program
@@ -1042,113 +1046,107 @@ class EditEngine:
                 duration_s=queue_wait_s, rid=rid,
             )
         try:
-            ps = self.programs
-            steps = int(request.steps) if request.steps else self.spec.steps
-            controller_kwargs = dict(
-                is_word_swap=request.is_word_swap,
-                cross_replace_steps=request.cross_replace_steps,
-                self_replace_steps=request.self_replace_steps,
-                blend_word=request.blend_word,
-                eq_params=request.eq_params,
-            )
-            # the BASE-steps controller keys the store/capture (inversions
-            # are always captured at the base grid); a few-step request
-            # additionally builds its own subset-space controller below
-            ctx = ps.controller(list(request.prompts), **controller_kwargs)
-            cond_all = ps.encode_prompts(list(request.prompts))
-            uncond = ps.encode_prompts([""])[0]
-            key = self._store_key(request, ctx)
-            products = self.store.get(key)
-            source = "memory" if products is not None else None
-            _, ik = jax.random.split(jax.random.key(request.seed))
-            if products is None:
-                # lazy crash-recovery rehydration: the persisted trajectory's
-                # leading entry IS the encoded source latents, so the warm
-                # inversion program rebuilds bit-identical capture products
-                # from it — no frame IO, no VAE encode, no cold compile,
-                # and no NEW inversion-from-frames on the books
-                traj_np = self.store.load_disk(key)
-                if traj_np is not None and traj_np.shape[0] == self.spec.steps + 1:
-                    anchor = jnp.asarray(traj_np[0])
-                    _, cached = ps.invert_capture(
-                        anchor, ps.encode_prompts([request.prompt]), ctx, ik
-                    )[:2]
-                    products = (cached, anchor)
-                    source = "disk"
-                    self._count("rehydrations")
-                    # resident again; already on disk — no re-persist
-                    self.store.put(key, products)
-            if products is None:
-                if request.frames is not None:
-                    frames = np.asarray(request.frames)
-                else:
-                    from videop2p_tpu.data import load_frame_sequence
+            # the resolve segment, worker pickup → prepared arguments
+            # (inert with tracing off: Tracer.enabled gates every span)
+            with span("serve.resolve", tracer=self.tracer, trace_id=tid,
+                      parent_id=root_span, rid=rid) as resolve_span:
+                ps = self.programs
+                steps = int(request.steps) if request.steps else self.spec.steps
+                controller_kwargs = dict(
+                    is_word_swap=request.is_word_swap,
+                    cross_replace_steps=request.cross_replace_steps,
+                    self_replace_steps=request.self_replace_steps,
+                    blend_word=request.blend_word,
+                    eq_params=request.eq_params,
+                )
+                # the BASE-steps controller keys the store/capture (inversions
+                # are always captured at the base grid); a few-step request
+                # additionally builds its own subset-space controller below
+                ctx = ps.controller(list(request.prompts), **controller_kwargs)
+                cond_all = ps.encode_prompts(list(request.prompts))
+                uncond = ps.encode_prompts([""])[0]
+                key = self._store_key(request, ctx)
+                products = self.store.get(key)
+                source = "memory" if products is not None else None
+                _, ik = jax.random.split(jax.random.key(request.seed))
+                if products is None:
+                    # lazy crash-recovery rehydration: the persisted trajectory's
+                    # leading entry IS the encoded source latents, so the warm
+                    # inversion program rebuilds bit-identical capture products
+                    # from it — no frame IO, no VAE encode, no cold compile,
+                    # and no NEW inversion-from-frames on the books
+                    traj_np = self.store.load_disk(key)
+                    if traj_np is not None and traj_np.shape[0] == self.spec.steps + 1:
+                        anchor = jnp.asarray(traj_np[0])
+                        _, cached = ps.invert_capture(
+                            anchor, ps.encode_prompts([request.prompt]), ctx, ik
+                        )[:2]
+                        products = (cached, anchor)
+                        source = "disk"
+                        self._count("rehydrations")
+                        # resident again; already on disk — no re-persist
+                        self.store.put(key, products)
+                if products is None:
+                    if request.frames is not None:
+                        frames = np.asarray(request.frames)
+                    else:
+                        from videop2p_tpu.data import load_frame_sequence
 
-                    frames = load_frame_sequence(
-                        request.image_path, size=self.spec.width,
-                        num_frames=self.spec.video_len,
+                        frames = load_frame_sequence(
+                            request.image_path, size=self.spec.width,
+                            num_frames=self.spec.video_len,
+                        )
+                    latents = ps.encode(
+                        ps.frames_to_video(frames), jax.random.key(request.seed)
                     )
-                latents = ps.encode(
-                    ps.frames_to_video(frames), jax.random.key(request.seed)
-                )
-                traj, cached = ps.invert_capture(
-                    latents, ps.encode_prompts([request.prompt]), ctx, ik
-                )[:2]
-                products = (cached, latents)
-                source = "fresh"
-                self._count("fresh_inversions")
-                self.store.put(
-                    key, products,
-                    trajectory=(np.asarray(jax.device_get(traj))
-                                if self.store.persist_dir else None),
-                    meta={"image_path": request.image_path,
-                          "prompt": request.prompt,
-                          "steps": self.spec.steps,
-                          "width": self.spec.width,
-                          "video_len": self.spec.video_len},
-                )
-            if source == "fresh":
-                # the measured price one store hit avoids: this clip's
-                # encode + capture-inversion resolve seconds (slightly
-                # over the pure inversion — the controller/prompt-encode
-                # share is common to hits too, and small next to it).
-                # The same seconds are PRICED to this request as a
-                # singleton serve_invert attribution: a cold request
-                # carries its inversion in the cost vector, so a store
-                # hit's attributed cost is measurably lower — and the
-                # inversion seconds stay inside the conservation books
-                # (busy += attributed, no padding).
-                inv_s = time.perf_counter() - t0
-                self.cost.note_fresh_inversion(inv_s)
-                self._resolve_costs[rid] = self.cost.price_dispatch(
-                    inv_s, real=1, padded=1, program="serve_invert")
-            cached, anchor = products
-            ctx_edit = ctx
-            if steps != self.spec.steps:
-                from videop2p_tpu.pipelines.cached import check_subset_windows
+                    traj, cached = ps.invert_capture(
+                        latents, ps.encode_prompts([request.prompt]), ctx, ik
+                    )[:2]
+                    products = (cached, latents)
+                    source = "fresh"
+                    self._count("fresh_inversions")
+                    self.store.put(
+                        key, products,
+                        trajectory=(np.asarray(jax.device_get(traj))
+                                    if self.store.persist_dir else None),
+                        meta={"image_path": request.image_path,
+                              "prompt": request.prompt,
+                              "steps": self.spec.steps,
+                              "width": self.spec.width,
+                              "video_len": self.spec.video_len},
+                    )
+                if source == "fresh":
+                    # the measured price one store hit avoids: this clip's
+                    # encode + capture-inversion resolve seconds (slightly
+                    # over the pure inversion — the controller/prompt-encode
+                    # share is common to hits too, and small next to it).
+                    # The same seconds are PRICED to this request as a
+                    # singleton serve_invert attribution: a cold request
+                    # carries its inversion in the cost vector, so a store
+                    # hit's attributed cost is measurably lower — and the
+                    # inversion seconds stay inside the conservation books
+                    # (busy += attributed, no padding).
+                    inv_s = time.perf_counter() - t0
+                    self.cost.note_fresh_inversion(inv_s)
+                    self._resolve_costs[rid] = self.cost.price_dispatch(
+                        inv_s, real=1, padded=1, program="serve_invert")
+                cached, anchor = products
+                ctx_edit = ctx
+                if steps != self.spec.steps:
+                    from videop2p_tpu.pipelines.cached import check_subset_windows
 
-                ctx_edit = ps.controller(
-                    list(request.prompts), steps=steps, **controller_kwargs
-                )
-                _, positions = ps.step_plan(steps)
-                check_subset_windows(ctx_edit, cached, positions, steps)
-            args = (cached, cond_all, uncond, ctx_edit, anchor)
-            dt = time.perf_counter() - t0
-            self.ledger.record_execute("serve_resolve", dt, dt, tid)
-            self._update(rid, store_hit=source in ("memory", "disk"),
-                         store_source=source, store_key=key, steps=steps,
-                         resolve_s=round(dt, 4))
-            if tid:
-                # resolve started at worker pickup (t0): anchor = submit
-                # wall + the monotonic offset since submit
-                self.tracer.emit(
-                    "serve.resolve", trace_id=tid, span_id=make_span_id(),
-                    parent_id=root_span,
-                    wall_ns=(wall0 + int((t0 - submitted) * 1e9)
-                             if wall0 is not None and submitted else None),
-                    duration_s=dt, rid=rid, store_source=source,
-                    steps=steps,
-                )
+                    ctx_edit = ps.controller(
+                        list(request.prompts), steps=steps, **controller_kwargs
+                    )
+                    _, positions = ps.step_plan(steps)
+                    check_subset_windows(ctx_edit, cached, positions, steps)
+                args = (cached, cond_all, uncond, ctx_edit, anchor)
+                dt = time.perf_counter() - t0
+                self.ledger.record_execute("serve_resolve", dt, dt, tid)
+                self._update(rid, store_hit=source in ("memory", "disk"),
+                             store_source=source, store_key=key, steps=steps,
+                             resolve_s=round(dt, 4))
+                resolve_span.set(store_source=source, steps=steps)
             reuse = (request.reuse_schedule
                      if request.reuse_schedule is not None
                      else self.spec.reuse_schedule)
@@ -1393,49 +1391,46 @@ class EditEngine:
 
         rec = self.poll(rid)
         req = rec["request"]
-        if self.faults is not None and self.faults.wrong:
-            # silent wrong-answer seam (wrong:PAT): deterministically
-            # perturb the tensor — the replica stays self-consistent
-            # (same bytes every replay, 200s, healthy /healthz) but its
-            # content hash diverges from the fleet's, which only the
-            # cross-replica answer audit (obs/probe.py) catches
-            if self.faults.wrongs(rec.get("store_key") or rid):
-                videos = np.ascontiguousarray(np.asarray(videos)[..., ::-1])
-        # stable answer identity: byte hash of the full video tensor —
-        # the determinism probe and the bit-exactness tests compare THIS,
-        # not re-hashed GIF artifacts
-        content_sha256 = hashlib.sha256(
-            np.ascontiguousarray(np.asarray(videos)).tobytes()).hexdigest()
-        quality = None
-        if rec.get("tenant") == PROBE_TENANT:
-            # golden-quality canary metrics — computed ONLY for the
-            # reserved probe tenant (this one check is the entire
-            # probe-off overhead on the serving hot path)
-            from videop2p_tpu.obs.quality import psnr, ssim
-            quality = {
-                "edit_psnr": round(float(psnr(videos[1], videos[0])), 4),
-                "edit_ssim": round(float(ssim(videos[1], videos[0])), 4),
-            }
         tid = rec.get("trace_id") if self._tracing else None
-        t_dec0 = time.perf_counter() if tid else None
-        req_dir = os.path.join(self.out_dir, rid)
-        os.makedirs(req_dir, exist_ok=True)
-        inversion_gif = os.path.join(req_dir, "inversion.gif")
-        edit_gif = os.path.join(req_dir, f"{req.get('save_name', 'edit')}.gif")
-        save_video_gif(videos[0], inversion_gif, fps=4)
-        save_video_gif(videos[1], edit_gif, fps=4)
+        # the decode segment: answer identity, canary metrics, the request's
+        # directory; the GIF writing that used to hide in it is
+        # serve.gif_write (inert with tracing off)
+        with span("serve.decode", tracer=self.tracer, trace_id=tid,
+                  parent_id=rec.get("span_id"), rid=rid):
+            if self.faults is not None and self.faults.wrong:
+                # silent wrong-answer seam (wrong:PAT): deterministically
+                # perturb the tensor — the replica stays self-consistent
+                # (same bytes every replay, 200s, healthy /healthz) but its
+                # content hash diverges from the fleet's, which only the
+                # cross-replica answer audit (obs/probe.py) catches
+                if self.faults.wrongs(rec.get("store_key") or rid):
+                    videos = np.ascontiguousarray(np.asarray(videos)[..., ::-1])
+            # stable answer identity: byte hash of the full video tensor —
+            # the determinism probe and the bit-exactness tests compare
+            # THIS, not re-hashed GIF artifacts
+            content_sha256 = hashlib.sha256(
+                np.ascontiguousarray(np.asarray(videos)).tobytes()).hexdigest()
+            quality = None
+            if rec.get("tenant") == PROBE_TENANT:
+                # golden-quality canary metrics — computed ONLY for the
+                # reserved probe tenant (this one check is the entire
+                # probe-off overhead on the serving hot path)
+                from videop2p_tpu.obs.quality import psnr, ssim
+                quality = {
+                    "edit_psnr": round(float(psnr(videos[1], videos[0])), 4),
+                    "edit_ssim": round(float(ssim(videos[1], videos[0])), 4),
+                }
+            req_dir = os.path.join(self.out_dir, rid)
+            os.makedirs(req_dir, exist_ok=True)
+            inversion_gif = os.path.join(req_dir, "inversion.gif")
+            edit_gif = os.path.join(req_dir, f"{req.get('save_name', 'edit')}.gif")
+        with span("serve.gif_write", tracer=self.tracer, trace_id=tid,
+                  parent_id=rec.get("span_id"), rid=rid):
+            save_video_gif(videos[0], inversion_gif, fps=4)
+            save_video_gif(videos[1], edit_gif, fps=4)
         if self.keep_videos:
             self._videos[rid] = videos
         total = time.perf_counter() - rec["submitted_s"]
-        if tid:
-            wall0 = rec.get("_wall_ns")
-            self.tracer.emit(
-                "serve.decode", trace_id=tid, span_id=make_span_id(),
-                parent_id=rec.get("span_id"),
-                wall_ns=(wall0 + int((t_dec0 - rec["submitted_s"]) * 1e9)
-                         if wall0 is not None else None),
-                duration_s=time.perf_counter() - t_dec0, rid=rid,
-            )
         self.ledger.record_execute("serve_request_e2e", total, total, tid)
         compile_events = (len(self.ledger.compile_seconds)
                           - rec.get("compile_events_before", 0))

@@ -177,16 +177,19 @@ def train_step(
     PRE-clip global gradient norm (the quantity ``max_grad_norm`` gates),
     the standard training-health telemetry signal.
     """
-    noise_key, t_key = jax.random.split(key)
-    if dependent_sampler is not None:
-        noise = dependent_sampler.sample_like(noise_key, latents)
-    else:
-        noise = jax.random.normal(noise_key, latents.shape, latents.dtype)
-    timesteps = jax.random.randint(
-        t_key, (latents.shape[0],), 0, scheduler.num_train_timesteps
-    )
-    noisy = scheduler.add_noise(latents, noise, timesteps)
-    target = scheduler.training_target(latents, noise, timesteps)
+    # the three named scopes are metadata on the ops (the profiler's device
+    # events carry them, forward and backward); they add no device work
+    with jax.named_scope("train.noise"):
+        noise_key, t_key = jax.random.split(key)
+        if dependent_sampler is not None:
+            noise = dependent_sampler.sample_like(noise_key, latents)
+        else:
+            noise = jax.random.normal(noise_key, latents.shape, latents.dtype)
+        timesteps = jax.random.randint(
+            t_key, (latents.shape[0],), 0, scheduler.num_train_timesteps
+        )
+        noisy = scheduler.add_noise(latents, noise, timesteps)
+        target = scheduler.training_target(latents, noise, timesteps)
 
     def loss_fn(trainable):
         # differentiate only the trainable subtree; unet_fn takes the full
@@ -195,9 +198,11 @@ def train_step(
         pred, _ = unet_fn({"params": params}, noisy, timesteps, text_embeddings, None)
         return jnp.mean((pred.astype(jnp.float32) - target.astype(jnp.float32)) ** 2)
 
-    loss, grads = jax.value_and_grad(loss_fn)(state.trainable)
-    updates, opt_state = tx.update(grads, state.opt_state, state.trainable)
-    trainable = optax.apply_updates(state.trainable, updates)
+    with jax.named_scope("train.loss"):
+        loss, grads = jax.value_and_grad(loss_fn)(state.trainable)
+    with jax.named_scope("train.optimizer"):
+        updates, opt_state = tx.update(grads, state.opt_state, state.trainable)
+        trainable = optax.apply_updates(state.trainable, updates)
     new_state = TrainState(
         step=state.step + 1,
         trainable=trainable,

@@ -4,20 +4,19 @@ north-star metric is wall-clock, so per-phase timing is first-class here).
 ``phase_timer`` prints wall-clock per named phase and keeps a process-local
 record for reporting — with ``count`` it also reports per-unit time (e.g.
 ms per null-text inner Adam step, the official mode's dominant unit of
-work); ``trace`` wraps ``jax.profiler`` for TensorBoard-viewable device
-traces when a trace dir is set (VIDEOP2P_TRACE_DIR env var).
+work).
 
 All timing uses ``time.perf_counter`` (monotonic): ``time.time`` is
 wall-clock and steps under NTP adjustment, which corrupted phase records.
-When a :class:`videop2p_tpu.obs.ledger.RunLedger` is active, every phase
-additionally lands in the ledger as a ``phase`` event — callers need no
-changes to get their timings into the run record.
+Every phase is a :class:`videop2p_tpu.obs.spans.span` of its name: when a
+:class:`videop2p_tpu.obs.ledger.RunLedger` is active it lands in the ledger
+as a ``span`` event beside its ``phase`` event, and in an open profiler
+session as a host-plane ``TraceAnnotation`` — callers need no changes.
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 import time
 from typing import Dict, Iterator, List, Optional, Tuple
@@ -27,7 +26,6 @@ __all__ = [
     "phase_records",
     "last_phase_seconds",
     "reset",
-    "trace",
 ]
 
 # guarded by _RECORDS_LOCK: phase_timer regions can close on worker threads
@@ -78,55 +76,28 @@ def phase_timer(
     the printed line (``[phase] null_text_optimization: 207.10s
     (414.2 ms/inner-step)``) — an upper bound when the region early-stops
     below ``count`` units."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        with _RECORDS_LOCK:
-            _RECORDS.append((name, dt))
-        # lazy import: utils must stay importable without obs (and obs
-        # imports nothing from here — no cycle either way)
-        try:
-            from videop2p_tpu.obs.ledger import current_ledger
-
-            led = current_ledger()
-        except Exception:  # noqa: BLE001 — observability never breaks timing
-            led = None
-        if led is not None:
-            extra = {"count": count, "unit": unit} if count else {}
-            led.phase(name, dt, **extra)
-        if verbose:
-            per = f" ({dt / count * 1e3:.1f} ms/{unit})" if count else ""
-            print(f"[phase] {name}: {dt:.2f}s{per}")
-
-
-@contextlib.contextmanager
-def trace(name: str) -> Iterator[None]:
-    """jax.profiler trace when VIDEOP2P_TRACE_DIR is set, else a no-op.
-
-    With an active :class:`~videop2p_tpu.obs.ledger.RunLedger`, a
-    ``trace`` event (name + trace directory) is emitted once the region
-    closes — so ``ledger_summary``/the edit report can link the device
-    trace to the phase that produced it instead of the path living only
-    in the operator's shell history.
-    """
-    trace_dir = os.environ.get("VIDEOP2P_TRACE_DIR")
-    if not trace_dir:
-        with phase_timer(name):
-            yield
-        return
-    import jax
-
-    target = os.path.join(trace_dir, name)
-    with jax.profiler.trace(target):
-        with phase_timer(name):
-            yield
+    extra = {"count": count, "unit": unit} if count else {}
+    # lazy import: utils must stay importable without obs (and obs
+    # imports nothing from here — no cycle either way)
     try:
         from videop2p_tpu.obs.ledger import current_ledger
+        from videop2p_tpu.obs.spans import span
 
-        led = current_ledger()
-    except Exception:  # noqa: BLE001 — observability never breaks tracing
-        led = None
-    if led is not None:
-        led.event("trace", name=name, trace_dir=target)
+        region = span(name, **extra)
+    except ImportError:  # utils without obs: the print and the record stay
+        current_ledger = lambda: None  # noqa: E731
+        region = contextlib.nullcontext()
+    with region:
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            dt = time.perf_counter() - t0
+            with _RECORDS_LOCK:
+                _RECORDS.append((name, dt))
+            led = current_ledger()
+            if led is not None:
+                led.phase(name, dt, **extra)
+            if verbose:
+                per = f" ({dt / count * 1e3:.1f} ms/{unit})" if count else ""
+                print(f"[phase] {name}: {dt:.2f}s{per}")
