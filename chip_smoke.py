@@ -234,6 +234,7 @@ def phase_tune(ctx: dict) -> dict:
     from videop2p_tpu.cli.common import build_models, load_config
     from videop2p_tpu.cli.run_tuning import main as tune
     from videop2p_tpu.models.convert import unet3d_params_to_torch
+    from videop2p_tpu.ops.attention import training_frame_attention
     from videop2p_tpu.train.masking import trainable_mask
 
     cfg = load_config(os.path.join(REPO, "configs", "rabbit-jump-tune.yaml"))
@@ -273,7 +274,7 @@ def phase_tune(ctx: dict) -> dict:
     # host one tensor at a time, so no copy of the weights crosses over and
     # none sits in host memory next to the compiler's.
     init = build_models(
-        None, dtype=jax.numpy.bfloat16, frame_attention="chunked",
+        None, dtype=jax.numpy.bfloat16,
         gradient_checkpointing=True, tiny=ctx["rehearse"], seed=cfg["seed"],
     ).unet_params["params"]
     sums = jax.device_get(jax.jit(lambda tree: jax.tree.map(
@@ -308,8 +309,9 @@ def phase_tune(ctx: dict) -> dict:
         "steps": TUNE_STEPS, "losses": losses,
         "trainable_leaves_moved": sum(expect.values()),
         "frozen_leaves_moved": 0,
-        "attention": "chunked (exact, memory-bounded backward — by design "
-                     "the tune step never takes the fused attention kernel)",
+        # what run_tuning.main asked build_models for; the kernels that are
+        # in the compiled step are under programs.train_steps
+        "attention": training_frame_attention(),
         "programs": digest["programs"], "phases": digest["phases"],
         "cache": ctx["cache"].since(cache0),
         "peak_bytes_in_use": peaks,

@@ -4,8 +4,9 @@ The TPU compiler is installed here and compiles for a topology that is
 described, not attached (``v5e:2x2``): it refuses what the chip's compiler
 would refuse — a slice off the tiling, a kernel over its VMEM budget, a
 kernel that cannot be partitioned — which Pallas interpret mode on the CPU
-never shows. Each case compiles one kernel at an SD-1.5 shape of the edit
-path, bare or under ``jax.shard_map`` on a four-device mesh with the specs
+never shows. Each case compiles one kernel (or, for Stage-1 tuning, the
+forward / backward pair through ``jax.grad``) at an SD-1.5 shape, bare or
+under ``jax.shard_map`` on a four-device mesh with the specs
 ``parallel/mesh.py`` uses, and asserts the kernel is IN the compiled text
 (``tpu_custom_call``). A compile that passes is not a run: nothing executes,
 no result and no time comes out of this file.
@@ -27,7 +28,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from videop2p_tpu.obs.introspect import tpu_custom_call_counts
-from videop2p_tpu.ops.attention import fused_frame_attention
+from videop2p_tpu.ops.attention import fused_bwd_block, fused_frame_attention
 from videop2p_tpu.ops.groupnorm import fits_fused_group_norm, fused_group_norm
 from videop2p_tpu.parallel.mesh import AXIS_DATA, AXIS_FRAMES, AXIS_TENSOR
 
@@ -36,6 +37,10 @@ from videop2p_tpu.parallel.mesh import AXIS_DATA, AXIS_FRAMES, AXIS_TENSOR
 # edit batch, and the 24-frame long-video shape
 ATTENTION_SHAPES = [(2, 8, 8, 4096, 40), (2, 8, 8, 1024, 80),
                     (2, 24, 8, 4096, 40)]
+# the same sites as Stage-1 tuning differentiates through them (batch 1),
+# and a 24-frame clip
+TUNE_ATTENTION_SHAPES = [(1, 8, 8, 4096, 40), (1, 8, 8, 1024, 80),
+                         (1, 24, 8, 4096, 40)]
 # (rows, C) slab classes of the SD-1.5 GroupNorm sites the kernel covers
 GROUP_NORM_SLABS = [(4096, 320), (1024, 640), (256, 1280), (512, 1280)]
 
@@ -100,6 +105,29 @@ def test_fused_frame_attention_compiles(one_chip, shape):
     assert kernels == {"fused_frame_attention": 1}
 
 
+def _attention_grad(fn):
+    """dQ, dK, dV through ``fn``: the forward kernel and the backward one."""
+    return jax.grad(
+        lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2),
+        argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("shape", TUNE_ATTENTION_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_fused_frame_attention_grad_compiles(one_chip, shape):
+    """Forward + backward at the tune's shapes: the backward's VMEM budget
+    (``ops.attention._fused_bwd_vmem_bytes``, handed to the compiler as the
+    kernel's limit) is one the chip's compiler accepts."""
+    b, f, h, n, d = shape
+    assert fused_bwd_block(f * n, n, d, jnp.bfloat16) is not None
+    kernels = _kernels(
+        _attention_grad(lambda q, k, v: fused_frame_attention(q, k, v, 256)),
+        *_attention_shapes(shape, one_chip, one_chip),
+    )
+    assert kernels == {"fused_frame_attention": 1,
+                       "fused_frame_attention_bwd": 1}
+
+
 @pytest.mark.parametrize("act", ["none", "silu"])
 @pytest.mark.parametrize("slab", GROUP_NORM_SLABS,
                          ids=lambda s: "x".join(map(str, s)))
@@ -115,22 +143,37 @@ def test_fused_group_norm_compiles(one_chip, slab, act):
     assert kernels == {"fused_group_norm": 1}
 
 
-@pytest.mark.parametrize("shape", ATTENTION_SHAPES[:2],
-                         ids=lambda s: "x".join(map(str, s)))
-def test_sharded_frame_attention_compiles(mesh4, shape):
+def _sharded_attention(mesh4, shape):
     """The kernel under ``jax.shard_map`` with the specs of
-    ``parallel.mesh.make_sharded_frame_attention_fn``: queries over
-    ``frames``, the frame-0 K/V replicated across it."""
+    ``parallel.mesh.make_sharded_frame_attention_fn`` — queries over
+    ``frames``, the frame-0 K/V replicated across it — and its abstract
+    arguments at ``shape``."""
     qspec = P(AXIS_DATA, AXIS_FRAMES, AXIS_TENSOR, None, None)
     kvspec = P(AXIS_DATA, AXIS_TENSOR, None, None)
     fn = jax.shard_map(
         lambda q, k, v: fused_frame_attention(q, k, v, 256), mesh=mesh4,
         in_specs=(qspec, kvspec, kvspec), out_specs=qspec, check_vma=False,
     )
-    kernels = _kernels(fn, *_attention_shapes(
-        shape, NamedSharding(mesh4, qspec), NamedSharding(mesh4, kvspec)
-    ))
-    assert kernels == {"fused_frame_attention": 1}
+    return fn, _attention_shapes(
+        shape, NamedSharding(mesh4, qspec), NamedSharding(mesh4, kvspec))
+
+
+@pytest.mark.parametrize("shape", ATTENTION_SHAPES[:2],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_sharded_frame_attention_compiles(mesh4, shape):
+    fn, args = _sharded_attention(mesh4, shape)
+    assert _kernels(fn, *args) == {"fused_frame_attention": 1}
+
+
+def test_sharded_frame_attention_grad_compiles(mesh4):
+    """The kernel pair under the same ``shard_map``: each chip differentiates
+    its two frames, and the replicated K / V get their cotangents summed over
+    ``frames`` by the transpose (an all-reduce)."""
+    fn, args = _sharded_attention(mesh4, TUNE_ATTENTION_SHAPES[0])
+    text = jax.jit(_attention_grad(fn)).lower(*args).compile().as_text()
+    assert tpu_custom_call_counts(text) == {
+        "fused_frame_attention": 1, "fused_frame_attention_bwd": 1}
+    assert "all-reduce" in text
 
 
 @pytest.mark.parametrize("slab", GROUP_NORM_SLABS[:2],

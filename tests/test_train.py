@@ -268,6 +268,32 @@ def _tune_cfg(root, name, **over):
     return cfg
 
 
+@pytest.mark.parametrize("backend, impl", [("cpu", "chunked"), ("tpu", "auto")])
+def test_tune_main_takes_its_frame_attention_from_the_backend(
+    tmp_path, monkeypatch, backend, impl
+):
+    """``main`` asks ``build_models`` for the frame attention that
+    ``ops.attention.training_frame_attention`` chooses from the backend: on
+    the CPU "chunked" (today's program — dense at the tiny preset's sites),
+    with the backend reported as TPU "auto", the Pallas kernel pair."""
+    from videop2p_tpu.cli import run_tuning as rt
+
+    class Stop(Exception):
+        pass
+
+    asked = {}
+
+    def build(*args, **kw):
+        asked.update(kw)
+        raise Stop()
+
+    monkeypatch.setattr(rt, "build_models", build)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with pytest.raises(Stop):
+        rt.main(**_tune_cfg(tmp_path, "choice"))
+    assert asked["frame_attention"] == impl
+
+
 @pytest.mark.slow  # ~30 s: three tiny end-to-end tuning runs
 def test_tuning_preemption_checkpoint_and_bit_identical_resume(
     tmp_path, monkeypatch

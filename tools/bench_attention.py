@@ -9,7 +9,14 @@ Measurement per impl: warm on a fresh input, then time a CHAIN of calls
 where each input depends on the previous output (no call can start before
 the one ahead of it finished), ending with a device→host value fetch.
 
-Usage: PYTHONPATH=/root/repo python tools/bench_attention.py [reps]
+``grad`` times the BACKWARD of one site (``jax.grad`` with a cotangent that
+does not depend on the output, so XLA drops the forward, whose result no
+gradient needs) at the two large sites of Stage-1 tuning, batch 1: the
+chunked vjp (its own recompute of each chunk included) against the Pallas
+backward kernel at each block (PERF.md §6, PR 27, has the chip's readings;
+``ops/attention._BWD_BLOCKS`` takes the largest that fits VMEM).
+
+Usage: PYTHONPATH=/root/repo python tools/bench_attention.py [reps | grad]
 """
 
 from __future__ import annotations
@@ -104,10 +111,56 @@ def measure(name, fn, reps: int = 8):
     return dt, out
 
 
+def measure_grad(name, fn, shape, reps: int = 10):
+    b, f, h, n, d = shape
+    ks = jax.random.split(jax.random.key(n), 4)
+    q = jax.random.normal(ks[0], shape, jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, h, n, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, h, n, d), jnp.bfloat16)
+    w = jax.random.normal(ks[3], shape, jnp.float32)
+    # all three gradients feed the next call's operands INSIDE the program:
+    # none can be dropped as unused, and no eager op (whose first use
+    # compiles) sits in the timed loop
+    def step(q, k, v):
+        grads = jax.grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w),
+            argnums=(0, 1, 2))(q, k, v)
+        return tuple(x + 0.001 * g for x, g in zip((q, k, v), grads))
+
+    step = jax.jit(step)
+    try:
+        q, k, v = jax.block_until_ready(step(*step(q, k, v)))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            q, k, v = step(q, k, v)
+        jax.block_until_ready(q)
+        print(f"{name:28s} {(time.perf_counter() - t0) / reps * 1e3:8.2f} ms")
+    except Exception as e:  # noqa: BLE001
+        print(f"{name:28s} FAILED: {type(e).__name__}: {str(e)[-200:]}")
+
+
+def main_grad():
+    import videop2p_tpu.ops.attention as attention
+
+    blocks = attention._BWD_BLOCKS
+    for shape in [(1, 8, 8, 4096, 40), (1, 8, 8, 1024, 80)]:
+        print(f"grad, q={shape}  device={jax.devices()[0].device_kind}")
+        measure_grad("chunked(512) vjp", chunked_frame_attention, shape)
+        for blk in blocks:
+            attention._BWD_BLOCKS = (blk,)
+            # a fresh function per block, so that jit traces it again
+            measure_grad(f"fused bwd kernel, block {blk}",
+                         lambda q, k, v: fused_frame_attention(q, k, v, 256),
+                         shape)
+        attention._BWD_BLOCKS = blocks
+
+
 def main():
     if any(a in ("-h", "--help") for a in sys.argv[1:]):
         print(__doc__.strip())
         return 0
+    if sys.argv[1:] == ["grad"]:
+        return main_grad()
     reps = int(sys.argv[1]) if len(sys.argv) > 1 else 8
     print(f"shape: q=({B},{F},{H},{N},{D})  reps={reps}  "
           f"device={jax.devices()[0].device_kind}")

@@ -42,6 +42,7 @@ from videop2p_tpu.core import DDIMScheduler, DDPMScheduler, DependentNoiseSample
 from videop2p_tpu.data import SingleVideoDataset
 from videop2p_tpu.models import decode_video, encode_video
 from videop2p_tpu.models.pipeline_io import save_pipeline
+from videop2p_tpu.ops.attention import training_frame_attention
 from videop2p_tpu.pipelines import ddim_inversion, edit_sample, make_unet_fn
 from videop2p_tpu.train import (
     TrainState,
@@ -203,7 +204,8 @@ def main(
         dtype = {"fp16": jnp.bfloat16, "bf16": jnp.bfloat16, "no": jnp.float32}[mixed_precision]
         with span("tune.build_models"):
             bundle = build_models(
-                pretrained_model_path, dtype=dtype, frame_attention="chunked",
+                pretrained_model_path, dtype=dtype,
+                frame_attention=training_frame_attention(),
                 gradient_checkpointing=gradient_checkpointing, tiny=tiny,
                 seed=seed or 0,
             )

@@ -83,8 +83,9 @@ class UNet3DConfig:
     # the saved dot outputs push a 16 GB chip into spills — so full
     # recompute is the default; the knob stays for bigger-HBM parts.
     remat_policy: Optional[str] = None
-    # frame-attention kernel: "auto"/"dense" (inference), "chunked"
-    # (training: memory-bounded backward), "flash" (Pallas; see ops/attention.py)
+    # frame-attention kernel: "auto" (the Pallas forward / backward pair on
+    # TPU, dense elsewhere), "dense", "chunked" (memory-bounded backward: what
+    # training takes off the TPU), "flash" (stock Pallas; see ops/attention.py)
     frame_attention: str = "auto"
     # GroupNorm implementation: "auto" = one-pass fused Pallas kernel on TPU
     # at VMEM-fitting sites (ops/groupnorm.py), "xla" = always the two-pass

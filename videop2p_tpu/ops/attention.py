@@ -1,26 +1,30 @@
-"""Frame-attention kernels: Pallas flash attention on TPU, chunked fallback.
+"""Frame-attention kernels: a Pallas forward / backward pair on TPU, chunked fallback.
 
 The spatial frame attention (every frame's queries against frame-0 keys,
 /root/reference/tuneavideo/models/attention.py:296-302) is the framework's
 hw×hw hot op: at 64×64 latents it is a 4096×4096 attention per frame per
 head — materialized, that is ~2 GB of probabilities in bf16 and the single
 reason the reference needs xformers (SURVEY §2.1 #7). Implementations behind
-one dispatch:
+one dispatch (what each costs on the chip is in PERF.md §5–§6, nowhere else):
 
-  * **fused** — custom Pallas kernel for the frame-0-KV structure: K/V sit
+  * **fused** — custom Pallas kernels for the frame-0-KV structure: K/V sit
     resident in VMEM (N·D ≈ 320 KB each) while query blocks stream through
-    with an exact full-row softmax. The TPU inference default ("auto"):
-    measured 19.6 s → 17.0 s fast-edit e2e vs dense (round-3 A/B on v5e).
+    with an exact full-row softmax; no score ever reaches HBM. Forward
+    ``fused_frame_attention``, backward ``fused_frame_attention_bwd`` (dQ, and
+    dK / dV accumulated in float32 in VMEM across query blocks and frames).
+    The TPU default ("auto") for inference AND for programs that
+    differentiate through the UNet (Stage-1 tuning, null-text inversion).
   * **dense** — plain einsum: the CPU path and the small-site (16²/8²)
     fallback, where the score matrix is tiny and XLA fuses it fine.
   * **chunked** — exact attention scanned over query blocks with
-    ``jax.checkpoint``, bounding peak memory on any backend: the TRAINING
-    path (bounded backward) and the sharded-mesh path (pjit cannot
-    partition a Pallas custom call).
+    ``jax.checkpoint``, bounding peak memory on any backend: the training
+    path OFF the TPU, the backward of a shape the kernel's VMEM fit test
+    refuses, and the sharded-mesh path (pjit cannot partition a Pallas
+    custom call). On the chip it writes every score chunk to HBM several
+    times over, which is why the tune left it (PERF.md §6, PR 27).
   * **flash / flash_rect** — the stock Pallas flash-attention kernel
     (``jax.experimental.pallas.ops.tpu.flash_attention``); kept for
-    comparison — loses to ``fused`` at every measured shape (d=40 grid
-    overhead, tools/bench_attention.py).
+    comparison (tools/bench_attention.py).
 
 These kernels are only for the UNCONTROLLED frame attention. The P2P
 controlled sites (text-cross, temporal) must materialize probabilities for
@@ -41,7 +45,9 @@ __all__ = [
     "flash_frame_attention",
     "flash_rect_frame_attention",
     "fused_frame_attention",
+    "fused_bwd_block",
     "make_frame_attention_fn",
+    "training_frame_attention",
 ]
 
 # shapes: q (B, F, H, N, D); k, v (B, H, N, D) — frame-0 KV shared across F
@@ -186,8 +192,10 @@ def fused_frame_attention(
     M = F·N grids that also cover the 24/32-frame long-video shapes without
     the chunked path's lax.map overhead.
 
-    Differentiation recomputes through :func:`chunked_frame_attention` (the
-    memory-bounded exact backward); the kernel itself is inference-path.
+    Differentiation runs the backward kernel of the same structure
+    (:func:`_fused_bwd_kernel`; residuals are just ``(q, k, v)``) where
+    :func:`fused_bwd_block` finds a block that fits VMEM, and the vjp of
+    :func:`chunked_frame_attention` (memory-bounded, exact) otherwise.
     """
     b, f, h, n, d = q.shape
     if (f * n) % q_blk != 0:
@@ -206,13 +214,156 @@ def _fused_fwd(q, k, v, q_blk, interpret):
     return fused_frame_attention(q, k, v, q_blk, interpret), (q, k, v)
 
 
+# The backward kernel's VMEM: one 64²-site block is over the default scoped
+# limit (16 MiB), so the call asks for what the arithmetic below says, and a
+# shape whose smallest block is over the budget goes to the chunked backward.
+# A v5e core has 128 MiB of VMEM; the compiler's own count at the tune's
+# shapes is about 0.7 of this one (tests/test_tpu_compile.py compiles them).
+# The largest block that fits is taken: at N = 4096 the kernel read 13.99 /
+# 8.23 / 7.32 ms at 256 / 512 / 1024 on the v5e (PERF.md §6, PR 27) — the
+# dK / dV read-modify-write is paid once per block — and at N = 1024 0.56 /
+# 0.48 ms at 512 / 1024.
+_BWD_BLOCKS = (1024, 512, 256, 128)
+_BWD_VMEM_BUDGET = 96 * 1024 * 1024
+
+
+def _fused_bwd_vmem_bytes(n: int, d: int, itemsize: int, blk: int) -> int:
+    """VMEM the backward kernel holds for one grid cell, from its shapes."""
+    lanes = -(-d // 128) * 128  # a (rows, d) block pads its last axis to 128 lanes
+    # (N, blk) tiles: Pᵀ, dPᵀ, dSᵀ in float32, Pᵀ and dSᵀ again as operands
+    tiles = n * blk * (3 * 4 + 2 * itemsize)
+    kv = 2 * (2 * n * lanes + max(d, 16) * n) * itemsize  # K, V, Kᵀ, double-buffered
+    acc = 2 * 2 * n * lanes * 4  # dK, dV float32, double-buffered
+    streams = 2 * (2 * blk * lanes * itemsize + -(-d // 8) * 8 * blk * 4)  # q, dO, dQᵀ
+    return tiles + kv + acc + streams
+
+
+def fused_bwd_block(m: int, n: int, d: int, dtype) -> Optional[int]:
+    """Query block of the backward kernel for q (·, m, d) against k/v (·, n, d):
+    the largest that divides ``m`` and whose VMEM fits the budget; None where
+    none does (the caller differentiates the chunked code instead). The one
+    fit test the dispatch and the kernel call share."""
+    if n % 128 != 0 or d > 128:  # the (N, blk) tile's sublanes; one lane tile of D
+        return None
+    itemsize = jnp.dtype(dtype).itemsize
+    for blk in _BWD_BLOCKS:
+        if (m % blk == 0
+                and _fused_bwd_vmem_bytes(n, d, itemsize, blk) <= _BWD_VMEM_BUDGET):
+            return blk
+    return None
+
+
+def _fused_bwd_kernel(q_ref, do_ref, k_ref, kt_ref, v_ref,
+                      dqt_ref, dk_ref, dv_ref, *, scale: float):
+    """One grid cell: a query block's share of dQ, dK and dV against the whole
+    (VMEM-resident) frame-0 K/V. The score tile is held TRANSPOSED, (N, blk):
+    every product is then a plain or a transposed-RHS matmul (no transpose of
+    a tile), the softmax reduces over sublanes, and dQ comes out as dQᵀ from
+    Kᵀ·dSᵀ. The full row is present, so the probabilities are recomputed as
+    the forward computes them and neither O nor a log-sum-exp is needed.
+    dK / dV accumulate in float32 in their output blocks, which stay resident
+    along the inner grid axis — frames are folded into the query length, so
+    this also sums them over frames. ``scale`` on dQ and dK is the caller's."""
+    import jax.lax as lax
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    q = q_ref[0]  # (blk, D)
+    do = do_ref[0]  # (blk, D)
+    k = k_ref[0]  # (N, D)
+    v = v_ref[0]  # (N, D)
+    nt = (((1,), (1,)), ((), ()))
+    nn = (((1,), (0,)), ((), ()))
+    st = lax.dot_general(k, q, nt, preferred_element_type=jnp.float32) * scale
+    m = jnp.max(st, axis=0, keepdims=True)  # (1, blk)
+    e = jnp.exp(st - m)
+    pt = e * (1.0 / jnp.sum(e, axis=0, keepdims=True))  # Pᵀ (N, blk) f32
+    dv_ref[0] += lax.dot_general(
+        pt.astype(do.dtype), do, nn, preferred_element_type=jnp.float32)
+    dpt = lax.dot_general(v, do, nt, preferred_element_type=jnp.float32)
+    delta = jnp.sum(pt * dpt, axis=0, keepdims=True)  # rowsum(P ∘ dP), (1, blk)
+    dst = (pt * (dpt - delta)).astype(q.dtype)  # dSᵀ, rounded as an operand only
+    dk_ref[0] += lax.dot_general(dst, q, nn, preferred_element_type=jnp.float32)
+    dqt_ref[0] = lax.dot_general(
+        kt_ref[0], dst, nn, preferred_element_type=jnp.float32)  # (D, blk)
+
+
+def _fused_rect_bwd(q3: jax.Array, do3: jax.Array, k: jax.Array, v: jax.Array,
+                    blk: int, interpret: bool = False):
+    """q3, do3 (BH, M, D); k, v (BH, N, D) → dQ (BH, M, D), dK, dV (BH, N, D),
+    all float32 (the caller rounds them to the operands' dtype)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, m, d = q3.shape
+    n = k.shape[1]
+    scale = d ** -0.5
+    stream = pl.BlockSpec((1, blk, d), lambda b, i: (b, i, 0))
+    resident = pl.BlockSpec((1, n, d), lambda b, i: (b, 0, 0))
+    dqt, dk, dv = pl.pallas_call(
+        functools.partial(_fused_bwd_kernel, scale=scale),
+        name="fused_frame_attention_bwd",
+        out_shape=(
+            jax.ShapeDtypeStruct((bh, d, m), jnp.float32),
+            jax.ShapeDtypeStruct((bh, n, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, n, d), jnp.float32),
+        ),
+        grid=(bh, m // blk),
+        in_specs=[
+            stream, stream, resident,
+            pl.BlockSpec((1, d, n), lambda b, i: (b, 0, 0)),
+            resident,
+        ],
+        out_specs=(
+            pl.BlockSpec((1, d, blk), lambda b, i: (b, 0, i)),
+            # constant along the inner axis → resident accumulators, written
+            # back once per (b, h); that axis must then run in order
+            resident, resident,
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_fused_bwd_vmem_bytes(
+                n, d, q3.dtype.itemsize, blk),
+        ),
+        interpret=interpret,
+    )(q3, do3, k, k.transpose(0, 2, 1), v)
+    return dqt.transpose(0, 2, 1) * scale, dk * scale, dv
+
+
 def _fused_bwd(q_blk, interpret, res, g):
     q, k, v = res
-    _, vjp = jax.vjp(chunked_frame_attention, q, k, v)
-    return vjp(g)
+    b, f, h, n, d = q.shape
+    blk = fused_bwd_block(f * n, n, d, q.dtype)
+    if blk is None:
+        _, vjp = jax.vjp(chunked_frame_attention, q, k, v)
+        return vjp(g)
+    with jax.named_scope(_SCOPE):
+        def fold(x):
+            return x.transpose(0, 2, 1, 3, 4).reshape(b * h, f * n, d)
+
+        dq, dk, dv = _fused_rect_bwd(
+            fold(q), fold(g), k.reshape(b * h, n, d),
+            v.reshape(b * h, n, d), blk, interpret,
+        )
+        dq = dq.reshape(b, h, f, n, d).transpose(0, 2, 1, 3, 4)
+        return (dq.astype(q.dtype), dk.reshape(k.shape).astype(k.dtype),
+                dv.reshape(v.shape).astype(v.dtype))
 
 
 fused_frame_attention.defvjp(_fused_fwd, _fused_bwd)
+
+
+def training_frame_attention() -> str:
+    """The ``impl`` of a program that differentiates through the large
+    sites, chosen from the backend: ``"auto"`` on the TPU — the kernel pair
+    where :func:`make_frame_attention_fn`'s rules and the backward's VMEM fit
+    test pass — and ``"chunked"`` elsewhere, whose bounded backward memory is
+    the reason it exists (``auto``'s choice off the TPU is ``dense``)."""
+    return "auto" if jax.default_backend() == "tpu" else "chunked"
 
 
 def make_frame_attention_fn(
@@ -225,28 +376,26 @@ def make_frame_attention_fn(
 
     ``impl``:
       * "auto" — ``fused`` on TPU, ``dense`` elsewhere (None → the
-        module-inline einsum). Round-3 shootout on v5e at the 64²-site edit
-        shape (tools/bench_attention.py): the XLA dense path materializes the
-        bf16 score tensor in HBM (~18 ms/instance inside the forward); the
-        stock Pallas flash kernel is worse at d=40 regardless of head-dim
-        padding (118–124 ms standalone vs chunked 51 ms — its block/grid
-        shape, not the 40→128 tile padding, is the loss); the ``fused``
-        kernel below keeps everything in VMEM.
-      * "fused" — custom Pallas kernel for the frame-0-KV structure: K/V
-        resident in VMEM, query blocks stream, exact full-row softmax. The
-        memory-optimal AND compute-optimal inference path. Asked for by
-        name on a backend with no Pallas TPU lowering it is an error, not
-        a quiet drop to ``chunked`` — a run that "works" must not be the
-        XLA fallback (only ``auto`` chooses by backend).
+        module-inline einsum): the XLA dense path materializes the bf16
+        score tensor in HBM, the ``fused`` kernels keep it in VMEM, forward
+        and backward.
+      * "fused" — custom Pallas kernels for the frame-0-KV structure: K/V
+        resident in VMEM, query blocks stream, exact full-row softmax, and a
+        backward of the same structure. Asked for by name on a backend with
+        no Pallas TPU lowering it is an error, not a quiet drop to
+        ``chunked`` — a run that "works" must not be the XLA fallback (only
+        ``auto`` chooses by backend).
       * "dense" — plain einsum; the small-site (16²/8²) and CPU path.
-      * "chunked" — the TRAINING path: exact attention scanned over query
-        blocks with ``jax.checkpoint``; the backward pass never materializes
-        an N×N probability tensor (dense would need ~2 GB per 64²-site and
-        OOMs a 16 GB chip when combined with gradients).
+      * "chunked" — exact attention scanned over query blocks with
+        ``jax.checkpoint``; the backward pass never materializes an N×N
+        probability tensor (dense would need ~2 GB per 64²-site and OOMs a
+        16 GB chip when combined with gradients). What training takes off
+        the TPU (:func:`training_frame_attention`) and what a mesh takes
+        where GSPMD partitions the op.
       * "flash" / "flash_rect" — the stock Pallas TPU kernel, with per-frame
         broadcast KV or frames folded into the query length respectively
         (head dims pad to ≤128; otherwise falls back to chunked). Kept for
-        comparison; loses to ``fused`` at every measured shape.
+        comparison.
     """
     if impl == "auto":
         impl = "fused" if jax.default_backend() == "tpu" else "dense"
