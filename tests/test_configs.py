@@ -47,6 +47,8 @@ def test_config_binds_to_entry_point(path):
     unknown = set(cfg) - _known(fn)
     assert not unknown, f"{path} has keys no parameter consumes: {unknown}"
 
+    if is_tune and cfg.get("model_family", "unet3d") != "unet3d":
+        return  # a token model tunes on a document of ids, not on a clip
     clip = cfg["train_data"]["video_path"] if is_tune else cfg["image_path"]
     name = os.path.basename(clip.rstrip("/"))
     if name in SHIPPED_CLIPS:
@@ -57,3 +59,36 @@ def test_config_binds_to_entry_point(path):
         assert cfg["prompt"] == cfg["prompts"][0], (
             f"{path}: source prompt must open the prompts list"
         )
+
+
+def test_token_model_config_loads_and_builds_the_published_model():
+    """The token model's YAML loads through ``load_config``, names a known
+    family, and its ``model`` dict is the published config.json at this
+    chip's share (ISSUE 28: 16 of 256 experts, 8 of 128 heads, 1/8 of the
+    vocabulary, 1 dense + 4 expert layers; no width cut)."""
+    from videop2p_tpu.cli.common import MODEL_FAMILIES, check_model_family
+    from videop2p_tpu.models.deepseek import DeepSeekV32Config
+
+    cfg = load_config(os.path.join(ROOT, "configs", "deepseek-v32-s16-tune.yaml"))
+    assert check_model_family(cfg["model_family"]) == "deepseek_v32"
+    assert cfg["model_family"] in MODEL_FAMILIES
+    model = DeepSeekV32Config.from_dict(cfg["model"])
+    published = DeepSeekV32Config()
+    cut = {"num_hidden_layers": 5, "first_k_dense_replace": 1,
+           "vocab_size": 16160, "experts_held": (0, 16), "heads_held": (0, 8)}
+    for f in inspect.signature(DeepSeekV32Config).parameters:
+        assert getattr(model, f) == cut.get(f, getattr(published, f)), f
+    assert list(cfg["trainable_modules"]) == ["q_a_proj", "q_b_proj"]
+    assert cfg["train_data"]["n_tokens"] == 16384
+
+
+def test_unknown_model_family_is_rejected_with_the_known_ones():
+    from videop2p_tpu.cli.common import check_model_family
+
+    with pytest.raises(ValueError) as err:
+        check_model_family("sdxl")
+    assert "'sdxl'" in str(err.value)
+    assert "unet3d" in str(err.value) and "deepseek_v32" in str(err.value)
+    with pytest.raises(ValueError, match="known:"):
+        tune_main(pretrained_model_path=None, output_dir="unused",
+                  train_data={}, model_family="nope")

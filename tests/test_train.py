@@ -373,3 +373,55 @@ def test_remat_policy_threads_through_blocks():
     b = jax.tree_util.tree_leaves(grads["dots_saveable"])
     for ga, gb in zip(a, b):
         np.testing.assert_allclose(np.asarray(ga), np.asarray(gb), atol=1e-5)
+
+
+def _train_step_before_the_split(unet_fn, tx, state, scheduler, latents,
+                                 text_embeddings, key):
+    """``train_step`` as it stood before ISSUE 28 split it into
+    ``loss_step`` over a ``StepLoss`` (git 4c6ffb3, verbatim but for the
+    options this test does not use): the diffusion loss hard-wired."""
+    import optax
+
+    from videop2p_tpu.train.masking import merge_params
+
+    with jax.named_scope("train.noise"):
+        noise_key, t_key = jax.random.split(key)
+        noise = jax.random.normal(noise_key, latents.shape, latents.dtype)
+        timesteps = jax.random.randint(
+            t_key, (latents.shape[0],), 0, scheduler.num_train_timesteps
+        )
+        noisy = scheduler.add_noise(latents, noise, timesteps)
+        target = scheduler.training_target(latents, noise, timesteps)
+
+    def loss_fn(trainable):
+        params = merge_params(trainable, state.frozen)
+        pred, _ = unet_fn({"params": params}, noisy, timesteps, text_embeddings, None)
+        return jnp.mean((pred.astype(jnp.float32) - target.astype(jnp.float32)) ** 2)
+
+    with jax.named_scope("train.loss"):
+        loss, grads = jax.value_and_grad(loss_fn)(state.trainable)
+    with jax.named_scope("train.optimizer"):
+        updates, opt_state = tx.update(grads, state.opt_state, state.trainable)
+        trainable = optax.apply_updates(state.trainable, updates)
+    return TrainState(step=state.step + 1, trainable=trainable,
+                      frozen=state.frozen, opt_state=opt_state), loss
+
+
+def test_unet_train_step_is_bit_equal_across_the_loss_closure_split(tiny):
+    """The UNet's step is the same computation after ``train_step`` became
+    ``loss_step(diffusion_loss(...))``: losses and updated leaves bit-equal
+    over three steps from the same state and keys."""
+    fn, variables, latents, text = tiny
+    tx = make_optimizer(TuneConfig(learning_rate=1e-3))
+    sched = DDPMScheduler.create_sd()
+    new = jax.jit(lambda s, k: train_step(fn, tx, s, sched, latents, text, k))
+    old = jax.jit(lambda s, k: _train_step_before_the_split(
+        fn, tx, s, sched, latents, text, k))
+    s_new = s_old = TrainState.create(variables["params"], tx)
+    for i in range(3):
+        key = jax.random.fold_in(jax.random.key(5), i)
+        s_new, l_new = new(s_new, key)
+        s_old, l_old = old(s_old, key)
+        assert np.asarray(l_new).tobytes() == np.asarray(l_old).tobytes(), i
+    for a, b in zip(jax.tree.leaves(s_new.trainable), jax.tree.leaves(s_old.trainable)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))

@@ -10,6 +10,7 @@ stages (run_tuning.py:97-99, run_videop2p.py:74-78).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import warnings
 from dataclasses import dataclass
@@ -28,6 +29,10 @@ __all__ = [
     "dependent_suffix",
     "resolve_pipeline_dir",
     "build_models",
+    "build_token_model",
+    "check_model_family",
+    "MODEL_FAMILIES",
+    "TokenModelBundle",
     "encode_prompts",
     "enable_compile_cache",
     "make_run_ledger",
@@ -571,6 +576,61 @@ def build_models(
         tokenizer=load_tokenizer(None),
         random_init=True,
         source_dir=None,
+    )
+
+
+# the tune config's ``model_family``: which model Stage 1 builds and which
+# loss it steps on (``tiny`` picks a size inside a family, not a family)
+MODEL_FAMILIES = ("unet3d", "deepseek_v32")
+
+
+def check_model_family(name: str) -> str:
+    if name not in MODEL_FAMILIES:
+        raise ValueError(
+            f"unknown model_family {name!r}; known: {list(MODEL_FAMILIES)}"
+        )
+    return name
+
+
+@dataclass
+class TokenModelBundle:
+    """A token model as Stage 1 tunes it: its configuration, its parameter
+    tree (the checkpoint's dtype) and ``loss_fn(params, ids) -> (loss, aux)``
+    for one document."""
+
+    config: Any
+    params: Dict
+    loss_fn: Any
+
+
+def build_token_model(
+    model: Optional[Dict[str, Any]],
+    *,
+    dtype: jnp.dtype = jnp.bfloat16,
+    gradient_checkpointing: bool = True,
+    tiny: bool = False,
+    seed: int = 0,
+) -> TokenModelBundle:
+    """The ``deepseek_v32`` family from the tune config's ``model`` dict
+    (``config.json`` keys plus the chip's share, ``models/deepseek.py``),
+    with seeded random weights in the checkpoint's dtype (bfloat16): no
+    checkpoint of this family ships, and no loader for one is built."""
+    from videop2p_tpu.models import deepseek
+
+    model = dict(model or {})
+    choices = bool(model.pop("hand_out_choices", False))
+    cfg = (deepseek.DeepSeekV32Config.tiny() if tiny
+           else deepseek.DeepSeekV32Config.from_dict(model))
+    cfg = dataclasses.replace(cfg, remat=bool(gradient_checkpointing),
+                              hand_out_choices=choices)
+    with span("models.init_or_load", model="deepseek_v32"):
+        params = jax.jit(
+            lambda key: deepseek.init_params(key, cfg)
+        )(jax.random.key(seed))["params"]
+    return TokenModelBundle(
+        config=cfg,
+        params=params,
+        loss_fn=lambda p, ids: deepseek.forward_loss(p, cfg, ids, dtype),
     )
 
 

@@ -19,7 +19,7 @@ import os
 import signal
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +29,8 @@ from videop2p_tpu.cli.common import (
     add_dependent_args,
     add_obs_args,
     build_models,
+    build_token_model,
+    check_model_family,
     dependent_suffix,
     encode_prompts,
     load_config,
@@ -39,7 +41,7 @@ from videop2p_tpu.cli.common import (
 from videop2p_tpu.obs import instrumented_jit
 from videop2p_tpu.obs.spans import span
 from videop2p_tpu.core import DDIMScheduler, DDPMScheduler, DependentNoiseSampler
-from videop2p_tpu.data import SingleVideoDataset
+from videop2p_tpu.data import SingleVideoDataset, TokenDocument
 from videop2p_tpu.models import decode_video, encode_video
 from videop2p_tpu.models.pipeline_io import save_pipeline
 from videop2p_tpu.ops.attention import training_frame_attention
@@ -48,8 +50,10 @@ from videop2p_tpu.train import (
     TrainState,
     TuneConfig,
     latest_checkpoint,
+    loss_steps,
     make_lr_schedule,
     make_optimizer,
+    next_token_loss,
     restore_checkpoint,
     save_checkpoint,
     train_steps,
@@ -93,11 +97,167 @@ def _install_preempt_handlers():
     return _restore
 
 
+class _Tuned(NamedTuple):
+    """What one model family hands ``main``'s loop: the train state, the
+    step program ``(state, key, n) -> (state, losses, ...)``, what of a
+    state a checkpoint holds, the validation run (None: the family has
+    none), the export of the tuned model (returns the ledger's ``artifacts``
+    fields), and the device mesh (None on one chip)."""
+
+    state: TrainState
+    program: Callable
+    saved: Callable
+    validate: Optional[Callable]
+    export: Callable
+    mesh: Any = None
+
+
+def _setup_unet(
+    pretrained_model_path, train_data, tx, tune_cfg, ek, *, dtype,
+    gradient_checkpointing, tiny, seed, mesh, sampler, prediction_type,
+    telemetry,
+) -> _Tuned:
+    """The inflated video UNet on one clip: models, the clip's latents and
+    the prompt's text states, the train state, ``train_steps``."""
+    n_frames = int(train_data.get("n_sample_frames", 8))
+    with span("tune.build_models"):
+        bundle = build_models(
+            pretrained_model_path, dtype=dtype,
+            frame_attention=training_frame_attention(),
+            gradient_checkpointing=gradient_checkpointing, tiny=tiny,
+            seed=seed or 0,
+        )
+    # data → latents (VAE encode once; the clip is fixed, run_tuning.py:282-287)
+    with span("tune.load_clip", frames=n_frames):
+        ds = SingleVideoDataset(
+            video_path=train_data["video_path"],
+            prompt=train_data["prompt"],
+            width=int(train_data.get("width", 512)),
+            height=int(train_data.get("height", 512)),
+            n_sample_frames=n_frames,
+            sample_start_idx=int(train_data.get("sample_start_idx", 0)),
+            sample_frame_rate=int(train_data.get("sample_frame_rate", 1)),
+        )
+        video = jnp.asarray(ds.load())[None]  # (1, F, H, W, 3)
+    with phase_timer("tune.vae_encode"):
+        # one program, not an op-by-op walk of the encoder (each eager
+        # op is its own compile on a cold start)
+        latents = jax.jit(
+            lambda vp, v, k: encode_video(bundle.vae, vp, v, k)
+        )(bundle.vae_params, video.astype(dtype), ek)
+        latents = jax.block_until_ready(latents.astype(jnp.float32))
+    with span("tune.text_encode"):
+        text_emb = encode_prompts(bundle, [train_data["prompt"]])
+    device_mesh = None
+    if mesh:
+        from videop2p_tpu.parallel import latent_sharding
+
+        # shard the bundle BEFORE TrainState.create so the partitioned
+        # trainable/frozen trees (and the optimizer state initialized from
+        # them) inherit the placements
+        device_mesh = setup_mesh(bundle, mesh, n_frames)
+        latents = jax.device_put(latents, latent_sharding(device_mesh))
+    with span("tune.state_create"):
+        state = TrainState.create(
+            bundle.unet_params["params"], tx, tune_cfg.trainable_modules
+        )
+    noise_sched = DDPMScheduler.create_sd(prediction_type=prediction_type)
+    unet_fn = make_unet_fn(bundle.unet)
+
+    def program(s, k, n):
+        return train_steps(
+            unet_fn, tx, s, noise_sched, latents, text_emb, k,
+            num_steps=n, dependent_sampler=sampler, telemetry=telemetry,
+        )
+
+    def validate(s, validation_data, output_dir, step, *, dependent_weights, key):
+        _validate(
+            bundle, s, latents, validation_data, output_dir, step,
+            dependent_weights=dependent_weights, sampler=sampler,
+            text_emb=text_emb, key=key,
+        )
+
+    def export(s, output_dir, step):
+        save_pipeline(
+            output_dir,
+            bundle.unet.config,
+            {"params": s.params},
+            source_dir=bundle.source_dir,
+            scheduler_config={
+                "_class_name": "DDIMScheduler",
+                "beta_start": 0.00085,
+                "beta_end": 0.012,
+                "beta_schedule": "scaled_linear",
+                "clip_sample": False,
+                "set_alpha_to_one": False,
+                "steps_offset": 1,
+            },
+        )
+        print(f"[tune] saved pipeline to {output_dir}")
+        return {"pipeline_dir": output_dir}
+
+    return _Tuned(state, program, lambda s: s, validate, export, device_mesh)
+
+
+def _setup_token_model(
+    model, train_data, tx, tune_cfg, *, dtype, gradient_checkpointing, tiny,
+    seed, telemetry,
+) -> _Tuned:
+    """A token model on one document of token ids: no VAE, no text encoder,
+    no validation edit; ``loss_steps`` on the next-token loss."""
+    with span("tune.build_models"):
+        bundle = build_token_model(
+            model, dtype=dtype,
+            gradient_checkpointing=gradient_checkpointing, tiny=tiny,
+            seed=seed or 0,
+        )
+    # data → token ids (one document, the same every step)
+    with span("tune.load_document", tokens=int(train_data["n_tokens"])):
+        ids = jnp.asarray(TokenDocument(
+            n_tokens=int(train_data["n_tokens"]),
+            vocab_size=bundle.config.vocab_size,
+            document_path=train_data.get("document_path"),
+            document_seed=int(train_data.get("document_seed", 0)),
+        ).load())[None]  # (1, T)
+    with span("tune.state_create"):
+        # frozen leaves stay in the checkpoint's dtype; the trainable
+        # ones get a float32 master copy (and float32 moments)
+        state = TrainState.create(
+            bundle.params, tx, tune_cfg.trainable_modules,
+            master_dtype=jnp.float32,
+        )
+        bundle.params = None  # the state owns the weights from here
+        if not jax.tree.leaves(state.trainable):
+            raise ValueError(
+                f"trainable_modules {list(tune_cfg.trainable_modules)} match "
+                "no leaf of the model"
+            )
+    step_loss = next_token_loss(bundle.loss_fn, ids)
+
+    def program(s, k, n):
+        return loss_steps(step_loss, tx, s, k, num_steps=n, telemetry=telemetry)
+
+    def saved(s):
+        # a checkpoint holds the tuned leaves and their optimizer state only:
+        # the frozen share is gigabytes of the checkpoint's own weights
+        return s.replace(frozen={})
+
+    def export(s, output_dir, step):
+        # the tuned leaves are the artifact: no pipeline directory exists
+        # for this family
+        with span("tune.checkpoint", step=step):
+            path = save_checkpoint(output_dir, jax.device_get(saved(s)), step)
+        print(f"[tune] saved the tuned leaves to {path}")
+        return {"checkpoint": path}
+
+    return _Tuned(state, program, saved, None, export)
+
+
 def main(
     pretrained_model_path: str,
     output_dir: str,
     train_data: Dict[str, Any],
-    validation_data: Dict[str, Any],
+    validation_data: Optional[Dict[str, Any]] = None,
     learning_rate: float = 3e-5,
     train_batch_size: int = 1,
     max_train_steps: int = 500,
@@ -127,6 +287,12 @@ def main(
     # over sp (ring attention at uncontrolled temporal sites), attention/FF
     # kernels over tp. Single-clip tuning needs dp=1.
     mesh: Optional[str] = None,
+    # which model Stage 1 tunes (cli/common.MODEL_FAMILIES): the inflated
+    # video UNet on a clip, or a token model on a document of token ids —
+    # ``model`` is that family's configuration (models/deepseek.py). The token
+    # model has no VAE, no text encoder and no validation edit.
+    model_family: str = "unet3d",
+    model: Optional[Dict[str, Any]] = None,
     # extras (not in the reference)
     tiny: bool = False,
     log_every: int = 50,
@@ -160,6 +326,13 @@ def main(
     **unused,
 ) -> str:
     del unused
+    unet_family = check_model_family(model_family) == "unet3d"
+    validation_data = validation_data or {}
+    if mesh and not unet_family:
+        raise NotImplementedError(
+            "model_family 'deepseek_v32' runs one chip's share without any "
+            "exchange between chips: no mesh path is built for it yet"
+        )
     n_frames = int(train_data.get("n_sample_frames", 8))
     output_dir = output_dir + dependent_suffix(
         dependent=dependent, decay_rate=decay_rate, window_size=window_size,
@@ -202,38 +375,8 @@ def main(
             )
 
         dtype = {"fp16": jnp.bfloat16, "bf16": jnp.bfloat16, "no": jnp.float32}[mixed_precision]
-        with span("tune.build_models"):
-            bundle = build_models(
-                pretrained_model_path, dtype=dtype,
-                frame_attention=training_frame_attention(),
-                gradient_checkpointing=gradient_checkpointing, tiny=tiny,
-                seed=seed or 0,
-            )
-
-        # data → latents (VAE encode once; the clip is fixed, run_tuning.py:282-287)
-        with span("tune.load_clip", frames=n_frames):
-            ds = SingleVideoDataset(
-                video_path=train_data["video_path"],
-                prompt=train_data["prompt"],
-                width=int(train_data.get("width", 512)),
-                height=int(train_data.get("height", 512)),
-                n_sample_frames=n_frames,
-                sample_start_idx=int(train_data.get("sample_start_idx", 0)),
-                sample_frame_rate=int(train_data.get("sample_frame_rate", 1)),
-            )
-            video = jnp.asarray(ds.load())[None]  # (1, F, H, W, 3)
         key = jax.random.key(seed if seed is not None else 0)
         key, ek = jax.random.split(key)
-        with phase_timer("tune.vae_encode"):
-            # one program, not an op-by-op walk of the encoder (each eager op is
-            # its own compile on a cold start)
-            latents = jax.jit(
-                lambda vp, v, k: encode_video(bundle.vae, vp, v, k)
-            )(bundle.vae_params, video.astype(dtype), ek)
-            latents = jax.block_until_ready(latents.astype(jnp.float32))
-        with span("tune.text_encode"):
-            text_emb = encode_prompts(bundle, [train_data["prompt"]])
-
         tune_cfg = TuneConfig(
             learning_rate=learning_rate,
             scale_lr=scale_lr,
@@ -246,31 +389,37 @@ def main(
             train_batch_size=train_batch_size,
         )
         tx = make_optimizer(tune_cfg)
-        if mesh:
-            from videop2p_tpu.parallel import latent_sharding
-
-            # shard the bundle BEFORE TrainState.create so the partitioned
-            # trainable/frozen trees (and the optimizer state initialized from
-            # them) inherit the placements
-            device_mesh = setup_mesh(bundle, mesh, n_frames)
-            latents = jax.device_put(latents, latent_sharding(device_mesh))
+        # the family's models, data, train state and step program
+        if unet_family:
+            tuned = _setup_unet(
+                pretrained_model_path, train_data, tx, tune_cfg, ek,
+                dtype=dtype, gradient_checkpointing=gradient_checkpointing,
+                tiny=tiny, seed=seed, mesh=mesh, sampler=sampler,
+                prediction_type=prediction_type, telemetry=telemetry,
+            )
+        else:
+            tuned = _setup_token_model(
+                model, train_data, tx, tune_cfg, dtype=dtype,
+                gradient_checkpointing=gradient_checkpointing, tiny=tiny,
+                seed=seed, telemetry=telemetry,
+            )
+        state, program, saved = tuned.state, tuned.program, tuned.saved
+        tuned = tuned._replace(state=None)  # donated at the first call
         first_step = 0
-        with span("tune.state_create"):
-            params = bundle.unet_params["params"]
-            state = TrainState.create(params, tx, tune_cfg.trainable_modules)
-            if resume_from_checkpoint:
-                path = (
-                    latest_checkpoint(output_dir)
-                    if resume_from_checkpoint == "latest"
-                    else resume_from_checkpoint
-                )
-                if path:
-                    state = restore_checkpoint(path, state)
-                    first_step = int(state.step)
-                    print(f"[tune] resumed from {path} at step {first_step}")
-
-        noise_sched = DDPMScheduler.create_sd(prediction_type=prediction_type)
-        unet_fn = make_unet_fn(bundle.unet)
+        if resume_from_checkpoint:
+            path = (
+                latest_checkpoint(output_dir)
+                if resume_from_checkpoint == "latest"
+                else resume_from_checkpoint
+            )
+            if path:
+                restored = restore_checkpoint(path, saved(state))
+                # a checkpoint that holds no frozen leaves (the token
+                # model's) keeps the ones just built
+                state = (restored if jax.tree.leaves(restored.frozen)
+                         else restored.replace(frozen=state.frozen))
+                first_step = int(state.step)
+                print(f"[tune] resumed from {path} at step {first_step}")
         # multiple steps per device call (lax.scan over the per-step keys): one
         # dispatch per program instead of one per step (train/tuner.py
         # train_steps)
@@ -278,10 +427,7 @@ def main(
         # otherwise be held twice (in + out) inside the program and copied —
         # nothing else reads bundle.unet_params after TrainState.create above
         steps_fn = instrumented_jit(
-            lambda s, k, n: train_steps(
-                unet_fn, tx, s, noise_sched, latents, text_emb, k, num_steps=n,
-                dependent_sampler=sampler, telemetry=telemetry,
-            ),
+            program,
             program="train_steps",
             span_attrs=lambda s, k, n: {"steps": n},
             static_argnums=2,
@@ -296,6 +442,7 @@ def main(
             metrics = MetricsLogger(output_dir, ledger=run_ledger)
         losses = []
         grad_norms = []  # telemetry mode only: per-step pre-clip global norm
+        counters = []  # what the loss hands out beside itself, a dict a chunk
 
         def flush_losses(next_step):
             with span("tune.flush_losses") as flush_span:
@@ -304,15 +451,19 @@ def main(
                 flat = np.asarray(jax.block_until_ready(jnp.concatenate(losses)))
                 gflat = (np.asarray(jax.block_until_ready(jnp.concatenate(grad_norms)))
                          if grad_norms else None)
+                cflat = {name: np.concatenate([np.asarray(c[name]) for c in counters])
+                         for name in (counters[0] if counters else {})}
                 flush_span.set(steps=len(flat))
                 start = next_step - len(flat)
                 for j, lv in enumerate(flat):
                     rec = {"train_loss": float(lv), "lr": float(lr_schedule(start + j))}
                     if gflat is not None:
                         rec["grad_norm"] = float(gflat[j])
+                    rec.update({name: float(v[j]) for name, v in cflat.items()})
                     metrics.log(start + j + 1, rec)
                 losses.clear()
                 grad_norms.clear()
+                counters.clear()
                 return float(flat[-1])
 
         # chunks align with the periodic boundaries so per-step losses,
@@ -368,11 +519,15 @@ def main(
                 if do_trace:
                     jax.block_until_ready(out)  # the capture must hold the work
                     traced_chunk = True
+            state, chunk_losses = out[0], out[1]
             if telemetry:
-                state, chunk_losses, chunk_gnorms = out
-                grad_norms.append(chunk_gnorms)
-            else:
-                state, chunk_losses = out
+                grad_norms.append(out[2])
+            if isinstance(out[-1], dict):
+                # the scalars a step are logged; whatever else the loss hands
+                # out (arrays a step) is for whoever wrapped the program
+                counters.append({name: v for name, v in out[-1].items()
+                                 if getattr(v, "ndim", None) == 1})
+            del out  # nothing of a chunk's outputs outlives its bookkeeping
             losses.append(chunk_losses)  # device-side; no per-chunk host sync
             first_chunk = i == first_step
             i = nxt
@@ -389,13 +544,14 @@ def main(
                       f"({rate:.2f} it/s)")
             if checkpointing_steps and i % checkpointing_steps == 0:
                 with span("tune.checkpoint", step=i):
-                    save_checkpoint(output_dir, jax.device_get(state), i)
-            if (validation_steps and i % validation_steps == 0) or i == max_train_steps:
+                    save_checkpoint(output_dir, jax.device_get(saved(state)), i)
+            if tuned.validate and (
+                (validation_steps and i % validation_steps == 0) or i == max_train_steps
+            ):
                 with span("tune.validate", step=i):
-                    _validate(
-                        bundle, state, latents, validation_data, output_dir, i,
-                        dependent_weights=dependent_weights, sampler=sampler,
-                        text_emb=text_emb, key=key,
+                    tuned.validate(
+                        state, validation_data, output_dir, i,
+                        dependent_weights=dependent_weights, key=key,
                     )
             setup_span.end()  # the first chunk's bookkeeping is done (later: no-op)
         restore_signals()
@@ -404,7 +560,8 @@ def main(
                 flush_losses(i)
             metrics.close()
             with span("tune.checkpoint", step=i):
-                ckpt_path = save_checkpoint(output_dir, jax.device_get(state), i)
+                ckpt_path = save_checkpoint(
+                    output_dir, jax.device_get(saved(state)), i)
             print(f"[tune] preempted at step {i} — checkpoint saved to "
                   f"{ckpt_path}; resume with resume_from_checkpoint: latest")
             if run_ledger is not None:
@@ -423,6 +580,7 @@ def main(
             # ledger event joins the zero-noise-floor COMM_RULES gate
             from videop2p_tpu.obs.comm import tree_replica_divergence
 
+            device_mesh = tuned.mesh
             div_axes = tuple(
                 a for a in device_mesh.axis_names if device_mesh.shape[a] > 1
             )
@@ -436,24 +594,9 @@ def main(
                 print(f"[tune] param replica divergence over {div_axes}: {div}"
                       + ("  <-- REPLICAS DIVERGED (must be 0.0)" if div else ""))
 
-        save_pipeline(
-            output_dir,
-            bundle.unet.config,
-            {"params": state.params},
-            source_dir=bundle.source_dir,
-            scheduler_config={
-                "_class_name": "DDIMScheduler",
-                "beta_start": 0.00085,
-                "beta_end": 0.012,
-                "beta_schedule": "scaled_linear",
-                "clip_sample": False,
-                "set_alpha_to_one": False,
-                "steps_offset": 1,
-            },
-        )
-        print(f"[tune] saved pipeline to {output_dir}")
+        artifacts = tuned.export(state, output_dir, i)
         if run_ledger is not None:
-            run_ledger.event("artifacts", pipeline_dir=output_dir)
+            run_ledger.event("artifacts", **artifacts)
             run_ledger.close()
             print(f"[tune] run ledger: {run_ledger.path}")
         return output_dir

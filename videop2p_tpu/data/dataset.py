@@ -21,7 +21,7 @@ from typing import List, Optional
 import numpy as np
 from PIL import Image
 
-__all__ = ["SingleVideoDataset", "load_frame_sequence"]
+__all__ = ["SingleVideoDataset", "TokenDocument", "load_frame_sequence"]
 
 _IMG_EXT = (".jpg", ".jpeg", ".png", ".bmp", ".webp")
 
@@ -110,6 +110,42 @@ class SingleVideoDataset:
         picked = [_resize(frames[i], self.width, self.height) for i in idx]
         arr = np.stack(picked).astype(np.float32)
         return arr / 127.5 - 1.0  # (dataset.py:55)
+
+
+@dataclasses.dataclass
+class TokenDocument:
+    """The one-document training 'dataset' of a token model (``__len__ ==
+    1``): ``n_tokens`` token ids below ``vocab_size``. ``document_path`` is a
+    ``.npy`` of integer ids (read flat, the first ``n_tokens`` taken); with
+    no path the ids are drawn uniformly over the vocabulary from
+    ``document_seed`` (no tokenizer ships)."""
+
+    n_tokens: int
+    vocab_size: int
+    document_path: Optional[str] = None
+    document_seed: int = 0
+
+    def __len__(self) -> int:
+        return 1
+
+    def load(self) -> np.ndarray:
+        """(n_tokens,) int32."""
+        if self.document_path is None:
+            rng = np.random.default_rng(self.document_seed)
+            return rng.integers(0, self.vocab_size, self.n_tokens, dtype=np.int32)
+        ids = np.load(self.document_path).reshape(-1)
+        if not np.issubdtype(ids.dtype, np.integer) or len(ids) < self.n_tokens:
+            raise ValueError(
+                f"{self.document_path!r}: {len(ids)} ids of dtype {ids.dtype}; "
+                f"{self.n_tokens} integer ids needed"
+            )
+        ids = ids[: self.n_tokens]
+        if ids.min() < 0 or ids.max() >= self.vocab_size:
+            raise ValueError(
+                f"{self.document_path!r}: ids span [{ids.min()}, {ids.max()}], "
+                f"outside the vocabulary held here (0..{self.vocab_size - 1})"
+            )
+        return ids.astype(np.int32)
 
 
 def load_frame_sequence(

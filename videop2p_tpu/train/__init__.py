@@ -25,10 +25,15 @@ from videop2p_tpu.train.masking import (
     trainable_mask,
 )
 from videop2p_tpu.train.tuner import (
+    StepLoss,
     TrainState,
     TuneConfig,
+    diffusion_loss,
+    loss_step,
+    loss_steps,
     make_lr_schedule,
     make_optimizer,
+    next_token_loss,
     train_step,
     train_steps,
 )
@@ -51,10 +56,15 @@ __all__ = [
     "merge_params",
     "partition_params",
     "trainable_mask",
+    "StepLoss",
     "TrainState",
     "TuneConfig",
+    "diffusion_loss",
+    "loss_step",
+    "loss_steps",
     "make_lr_schedule",
     "make_optimizer",
+    "next_token_loss",
     "train_step",
     "train_steps",
 ]
