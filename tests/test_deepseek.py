@@ -12,6 +12,7 @@ both sides keep ties (the module's docstring), and a tie is no difference.
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -94,9 +95,13 @@ def test_published_defaults_and_param_count():
     assert round(n / 1e6) == 3829
 
 
-@pytest.mark.parametrize("key", ["n_heads", "q_chunk", "expert_block"])
+@pytest.mark.parametrize("key", ["n_heads", "q_chunk", "expert_block",
+                                 "attention_kernel", "use_pallas",
+                                 "selected_attention_tiles"])
 def test_config_from_dict_rejects_unknown_keys(key):
-    """How the work is cut is not configuration (module constants)."""
+    """How the work is cut is not configuration (module constants), and
+    neither is which code attends: the Pallas pair or the chunked XLA path
+    is chosen from the backend and the shapes (``_kernel_applies``)."""
     with pytest.raises(ValueError, match="unknown DeepSeekV32Config keys"):
         ds.DeepSeekV32Config.from_dict({"hidden_size": 64, key: 4})
 
@@ -182,6 +187,110 @@ def test_reference_faults_change_the_result(model, fault):
         assert not bool(jnp.array_equal(chosen[0]["mask"], chosen_b[0]["mask"]))
     if fault == "four_experts":
         assert chosen_b[-1]["experts"].shape[-1] == cfg.num_experts_per_tok // 2
+
+
+@pytest.fixture
+def lane_wide():
+    """The tiny model with the published head widths (128 / 64 / 128) on two
+    heads and 1 + 1 layers: a shape the kernel pair's fit test takes at 256
+    tokens."""
+    cfg = ds.DeepSeekV32Config.tiny(
+        num_hidden_layers=2, first_k_dense_replace=1, num_attention_heads=2,
+        heads_held=(0, 2), qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, index_head_dim=128)
+    params = jax.jit(lambda k: ds.init_params(k, cfg))(jax.random.key(5))
+    params = jax.tree.map(lambda x: x.astype(jnp.float32), params["params"])
+    ids = jax.random.randint(jax.random.key(2), (256,), 0, cfg.vocab_size)
+    return cfg, params, ids
+
+
+@pytest.fixture
+def as_on_the_tpu(monkeypatch):
+    """What ``attention()`` observes on the chip, with the kernels in
+    interpret mode: steered from the test, not through an option of the
+    program. 128 x 128 tiles, so 256 tokens are two by two."""
+    from videop2p_tpu.ops import selected_attention as sa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(sa, "_TILES", ((128, 128),))
+    monkeypatch.setattr(ds, "selected_key_attention", functools.partial(
+        sa.selected_key_attention, interpret=True))
+
+
+def _has_kernel(fn, *args) -> bool:
+    return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+
+def test_kernel_path_equals_the_xla_path(lane_wide, as_on_the_tpu, monkeypatch):
+    """``attention()`` and the trainable leaves' gradients of ``forward_loss``
+    through the Pallas pair (interpret mode) against the chunked XLA path on
+    the same weights, float32 both: 1e-4 of the output's scale and of each
+    leaf's norm, this file's tolerances for sums in another order."""
+    cfg, params, ids = lane_wide
+    x = jax.random.normal(jax.random.key(7), (256, cfg.hidden_size), jnp.float32)
+    angles = ds.rope_angles(cfg, jnp.arange(256))
+    attn = params["layers_1"]["attn"]
+
+    def fns():
+        # fresh function objects a path: a traced function is cached by identity
+        def attend(p, x):
+            return ds.attention(p, cfg, x, angles)
+
+        def grads(p):
+            got = named(jax.grad(
+                lambda p: ds.forward_loss(p, cfg, ids, jnp.float32)[0])(p))
+            return {k: v for k, v in got.items() if ref.is_trainable(k, TRAINABLE)}
+
+        return attend, grads
+
+    attend, grads = fns()
+    assert _has_kernel(attend, attn, x)
+    out_kernel, mask_kernel = jax.jit(attend)(attn, x)
+    grads_kernel = jax.jit(grads)(params)
+    with monkeypatch.context() as cpu:  # the chunked XLA path
+        cpu.setattr(jax, "default_backend", lambda: "cpu")
+        attend, grads = fns()
+        assert not _has_kernel(attend, attn, x)
+        out_xla, mask_xla = jax.jit(attend)(attn, x)
+        grads_xla = jax.jit(grads)(params)
+    assert bool(jnp.array_equal(mask_kernel, mask_xla))
+    assert float(jnp.max(jnp.abs(out_kernel - out_xla))) < 1e-4 * float(
+        jnp.max(jnp.abs(out_xla)))
+    assert len(grads_xla) == 2 * cfg.num_hidden_layers
+    for k, w in grads_xla.items():
+        assert float(jnp.linalg.norm(grads_kernel[k] - w)) < 1e-4 * float(
+            jnp.linalg.norm(w)), k
+
+
+def test_a_shape_the_fit_test_refuses_keeps_the_xla_path(model, as_on_the_tpu):
+    """The ``tiny`` size's 16 / 8 / 16-wide heads are off the lane tiles: on
+    the TPU too it is the chunked path, and the same numbers."""
+    cfg, params, ids = model
+    assert not _has_kernel(
+        lambda p: ds.forward_loss(p, cfg, ids, jnp.float32)[0], params)
+    odd = ids[:96]  # 96 tokens: no tile divides them, whatever the widths
+    assert not ds._kernel_applies(jnp.zeros((96, 2, 128)), jnp.zeros((96, 2, 64)),
+                                  jnp.zeros((96, 2, 128)))
+    assert ds._kernel_applies(jnp.zeros((256, 2, 128)), jnp.zeros((256, 2, 64)),
+                              jnp.zeros((256, 2, 128)))
+    loss_tpu, _ = jax.jit(lambda p: ds.forward_loss(p, cfg, odd, jnp.float32))(params)
+    assert bool(jnp.isfinite(loss_tpu))
+
+
+def test_on_the_cpu_train_steps_holds_no_kernel(lane_wide):
+    """Off the TPU the program is XLA alone, whatever the shapes: the
+    ledger's ``program_analysis`` counts of ``train_steps`` are empty."""
+    from videop2p_tpu.obs.introspect import tpu_custom_call_counts
+
+    cfg, params, ids = lane_wide
+    tx = make_optimizer(TuneConfig(trainable_modules=TRAINABLE))
+    state = TrainState.create(params, tx, TRAINABLE, master_dtype=jnp.float32)
+    step_loss = next_token_loss(
+        lambda p, doc: ds.forward_loss(p, cfg, doc, jnp.float32), ids[None])
+    program = jax.jit(lambda s, k: loss_steps(step_loss, tx, s, k, num_steps=2))
+    assert not _has_kernel(program, state, jax.random.key(0))
+    text = program.lower(state, jax.random.key(0)).compile().as_text()
+    assert tpu_custom_call_counts(text) == {}
 
 
 def _share(params, cfg, e0, en, h0, hn):

@@ -1,0 +1,154 @@
+"""The token model's selected-key attention as a Pallas pair
+(``ops/selected_attention.py``), in interpret mode on the CPU, against the
+XLA code it stands in for (``models.deepseek._attend``): the output and all
+five gradients, in float32 so that what is compared is the mathematics
+(tiles, the online softmax, the causal walk) and not bfloat16 rounding; one
+bfloat16 case with its own tolerance; and the fit test's arithmetic. What
+the chip's compiler says of the kernels is ``tests/test_tpu_compile.py``'s.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from videop2p_tpu.models import deepseek as ds
+from videop2p_tpu.ops import selected_attention as sa
+
+NOPE, ROPE, VDIM = 128, 64, 128
+SCALE = 192 ** -0.5 * 1.87
+
+
+def operands(t_len, heads, dtype, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    shapes = [(t_len, heads, NOPE), (t_len, heads, ROPE), (t_len, heads, NOPE),
+              (t_len, ROPE), (t_len, heads, VDIM)]
+    ops = [jax.random.normal(k, s, jnp.float32).astype(dtype)
+           for k, s in zip(ks, shapes)]
+    return ops, jax.random.normal(ks[5], shapes[4], jnp.float32)
+
+
+def selection(t_len, keys, seed=1, hole=None):
+    """A causal top-``keys`` selection of random scores, every query keeping
+    itself; ``hole = (rows, upto)``: those queries select no key before
+    ``upto`` — their first key tiles hold none of their keys."""
+    pos = np.arange(t_len)
+    score = np.random.default_rng(seed).normal(size=(t_len, t_len))
+    causal = pos[None, :] <= pos[:, None]
+    score = np.where(causal, score, -np.inf)
+    thr = np.sort(score, axis=-1)[:, -keys]
+    mask = (causal & (score >= thr[:, None])) | np.eye(t_len, dtype=bool)
+    if hole is not None:
+        rows, upto = hole
+        mask[rows, :upto] = False
+    return jnp.asarray(mask)
+
+
+def pair(attend, ops, mask, w):
+    """The output and the five gradients of sum(w * o)."""
+    out, vjp = jax.vjp(lambda *a: attend(*a, mask, SCALE), *ops)
+    return (out,) + vjp(w.astype(out.dtype))
+
+
+def kernel(*a):
+    return sa.selected_key_attention(*a, True)
+
+
+NAMES = ("o", "dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv")
+CASES = {
+    # tokens, heads, tiles, heads a cell, keys a query, the hole
+    "one_tile": (256, 2, (256, 256), (2, 1), 40, None),
+    "first_key_tile_empty": (256, 2, (128, 128), (2, 1), 24,
+                             (slice(130, 256, 2), 128)),
+    "plain_causal": (256, 2, (128, 128), (2, 1), 256, None),
+    "two_query_tiles_three_key_tiles": (768, 1, (384, 256), (1,), 100, None),
+    "three_by_three_two_cells_of_heads": (384, 4, (128, 128), (2, 1), 64,
+                                          (slice(300, 384), 256)),
+}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_pair_equals_attend_in_float32(monkeypatch, case):
+    """Float32 on both sides: sums in another order, 1e-5 of each result's
+    largest entry (measured 1e-6)."""
+    t_len, heads, tiles, cell_heads, keys, hole = CASES[case]
+    monkeypatch.setattr(sa, "_TILES", (tiles,))
+    monkeypatch.setattr(sa, "_HEADS", cell_heads)
+    got_tiles = sa.selected_attention_tiles(t_len, heads, NOPE, ROPE, VDIM,
+                                            jnp.float32)
+    assert got_tiles[:2] == tiles and got_tiles.fwd_heads == cell_heads[0]
+    ops, w = operands(t_len, heads, jnp.float32)
+    mask = selection(t_len, keys, hole=hole)
+    if case == "plain_causal":
+        assert bool(jnp.array_equal(mask, jnp.tril(jnp.ones_like(mask))))
+    want = jax.jit(lambda: pair(ds._attend, ops, mask, w))()
+    got = jax.jit(lambda: pair(kernel, ops, mask, w))()
+    for name, g, x in zip(NAMES, got, want):
+        assert g.shape == x.shape and g.dtype == x.dtype, name
+        assert bool(jnp.isfinite(g).all()), name
+        assert float(jnp.max(jnp.abs(g - x))) < 1e-5 * float(jnp.max(jnp.abs(x))), name
+
+
+def test_bfloat16_pair_stays_within_two_per_cent():
+    """As the cell runs it: bfloat16 operands, float32 statistics. ``_attend``
+    rounds the normalised probabilities, the kernel the unnormalised ones and
+    divides the float32 accumulator: 2 % of each result's largest entry
+    (measured 0.7 %)."""
+    ops, w = operands(256, 2, jnp.bfloat16)
+    mask = selection(256, 40)
+    want = jax.jit(lambda: pair(ds._attend, ops, mask, w))()
+    got = jax.jit(lambda: pair(kernel, ops, mask, w))()
+    for name, g, x in zip(NAMES, got, want):
+        assert g.dtype == jnp.bfloat16, name
+        g, x = g.astype(jnp.float32), x.astype(jnp.float32)
+        assert float(jnp.max(jnp.abs(g - x))) < 2e-2 * float(jnp.max(jnp.abs(x))), name
+
+
+def test_a_query_without_any_key_reads_zero_not_nan():
+    """Not what ``select_keys`` hands over (a query always keeps keys), but
+    the running max must stay finite through it: ``o`` is 0 there and every
+    gradient finite."""
+    ops, w = operands(256, 1, jnp.float32)
+    mask = selection(256, 16).at[200].set(False)
+    got = jax.jit(lambda: pair(kernel, ops, mask, w))()
+    assert all(bool(jnp.isfinite(g).all()) for g in got)
+    assert float(jnp.abs(got[0][200]).max()) == 0.0
+    assert float(jnp.abs(got[0][199]).max()) > 0.0
+
+
+def test_fit_test_at_the_cells_shape():
+    """16384 tokens, 8 heads, 128 / 64 / 128, bfloat16: 512 x 512 tiles, all
+    heads a forward cell, two a backward cell — the resident dQ is
+    2 buffers x 2 heads x 192 x 16384 x 4 B = 50.3 MB of the backward's 64.6 MB,
+    and four heads (122 MB) are over the 96 MiB budget."""
+    args = (16384, 8, NOPE, ROPE, VDIM, jnp.bfloat16)
+    assert sa.selected_attention_tiles(*args) == sa.Tiles(512, 512, 8, 2)
+    assert sa._bwd_vmem_bytes(2, 16384, 512, 512, 192, 128, 2) == 64_618_496
+    assert 2 * 2 * 192 * 16384 * 4 == 50_331_648
+    assert sa._bwd_vmem_bytes(4, 16384, 512, 512, 192, 128, 2) > sa._VMEM_BUDGET
+    assert sa._fwd_vmem_bytes(8, 512, 512, 192, 128, 2) == 20_447_232
+    # the causal walk: 32 x 33 / 2 tile pairs, each query tile's key tiles in a row
+    qi, ki = sa._causal_steps(16384, 512, 512)
+    assert len(qi) == 528 and qi[:3].tolist() == [0, 1, 1] and ki[:3].tolist() == [0, 0, 1]
+    qi, ki = sa._causal_steps(16384, 512, 512, key_major=True)
+    assert ki[:33].tolist() == [0] * 32 + [1] and qi[:33].tolist() == list(range(32)) + [1]
+    assert sa.selected_attention_tiles(4096, 8, NOPE, ROPE, VDIM, jnp.bfloat16) == (
+        sa.Tiles(512, 512, 8, 8))
+
+
+@pytest.mark.parametrize("why,args", {
+    "tiny_heads_off_the_lane_tiles": (128, 4, 16, 8, 16),
+    "rope_off_the_sublane_tiles": (1024, 8, 128, 8, 128),
+    "no_tile_divides_the_tokens": (1000, 8, NOPE, ROPE, VDIM),
+    "backward_over_vmem": (131072, 8, NOPE, ROPE, VDIM),
+}.items())
+def test_fit_test_refuses(why, args):
+    assert sa.selected_attention_tiles(*args, jnp.bfloat16) is None
+    t_len, heads, nope, rope, v_dim = args
+    if t_len <= 1024:
+        z = lambda *s: jnp.zeros(s, jnp.bfloat16)  # noqa: E731
+        with pytest.raises(ValueError, match="selected_attention_tiles first"):
+            sa.selected_key_attention(
+                z(t_len, heads, nope), z(t_len, heads, rope),
+                z(t_len, heads, nope), z(t_len, rope), z(t_len, heads, v_dim),
+                jnp.ones((t_len, t_len), bool), SCALE, True)

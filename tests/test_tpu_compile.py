@@ -5,7 +5,8 @@ described, not attached (``v5e:2x2``): it refuses what the chip's compiler
 would refuse — a slice off the tiling, a kernel over its VMEM budget, a
 kernel that cannot be partitioned — which Pallas interpret mode on the CPU
 never shows. Each case compiles one kernel (or, for Stage-1 tuning, the
-forward / backward pair through ``jax.grad``) at an SD-1.5 shape, bare or
+forward / backward pair through ``jax.grad``) at an SD-1.5 shape — or the
+token model's selected-key attention pair at its cell's shape — bare or
 under ``jax.shard_map`` on a four-device mesh with the specs
 ``parallel/mesh.py`` uses, and asserts the kernel is IN the compiled text
 (``tpu_custom_call``). A compile that passes is not a run: nothing executes,
@@ -30,6 +31,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from videop2p_tpu.obs.introspect import tpu_custom_call_counts
 from videop2p_tpu.ops.attention import fused_bwd_block, fused_frame_attention
 from videop2p_tpu.ops.groupnorm import fits_fused_group_norm, fused_group_norm
+from videop2p_tpu.ops.selected_attention import (
+    selected_attention_tiles,
+    selected_key_attention,
+)
 from videop2p_tpu.parallel.mesh import AXIS_DATA, AXIS_FRAMES, AXIS_TENSOR
 
 # (B, F, H, N, D) of the SD-1.5 frame-attention sites that pass the
@@ -41,6 +46,10 @@ ATTENTION_SHAPES = [(2, 8, 8, 4096, 40), (2, 8, 8, 1024, 80),
 # and a 24-frame clip
 TUNE_ATTENTION_SHAPES = [(1, 8, 8, 4096, 40), (1, 8, 8, 1024, 80),
                          (1, 24, 8, 4096, 40)]
+# tokens of the token model's selected-key attention (8 heads of 128 / 64 /
+# 128, models/deepseek.py): the cell's document, and a quarter of it, where
+# the backward's resident dQ lets all eight heads into one cell
+SELECTED_TOKENS = [16384, 4096]
 # (rows, C) slab classes of the SD-1.5 GroupNorm sites the kernel covers
 GROUP_NORM_SLABS = [(4096, 320), (1024, 640), (256, 1280), (512, 1280)]
 
@@ -126,6 +135,41 @@ def test_fused_frame_attention_grad_compiles(one_chip, shape):
     )
     assert kernels == {"fused_frame_attention": 1,
                        "fused_frame_attention_bwd": 1}
+
+
+def _selected_attention_shapes(t_len, sharding, heads=8, nope=128, rope=64,
+                               v_dim=128):
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return (arg((t_len, heads, nope)), arg((t_len, heads, rope)),
+            arg((t_len, heads, nope)), arg((t_len, rope)),
+            arg((t_len, heads, v_dim)), arg((t_len, t_len), jnp.bool_))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+@pytest.mark.parametrize("t_len", SELECTED_TOKENS)
+def test_selected_key_attention_compiles(one_chip, t_len, grad):
+    """The token model's pair at the cell's shape: the forward alone, and the
+    forward + backward through ``jax.grad`` of all five operands — the VMEM
+    the fit test counts (``ops.selected_attention._bwd_vmem_bytes``: the
+    resident dQ of two heads at 16384 tokens, of all eight at 4096) is one
+    the chip's compiler accepts."""
+    assert selected_attention_tiles(t_len, 8, 128, 64, 128, jnp.bfloat16) is not None
+
+    def fn(*ops):
+        return selected_key_attention(*ops, 192 ** -0.5)
+
+    if grad:
+        kernels = _kernels(
+            jax.grad(lambda *ops: jnp.sum(fn(*ops).astype(jnp.float32) ** 2),
+                     argnums=(0, 1, 2, 3, 4)),
+            *_selected_attention_shapes(t_len, one_chip))
+        assert kernels == {"lm_selected_attention": 1,
+                           "lm_selected_attention_bwd": 1}
+    else:
+        kernels = _kernels(fn, *_selected_attention_shapes(t_len, one_chip))
+        assert kernels == {"lm_selected_attention": 1}
 
 
 @pytest.mark.parametrize("act", ["none", "silu"])

@@ -37,6 +37,15 @@ the selection keeps every key that ties with the k-th largest score.
 Device ops carry the named scopes ``lm.mla_proj``, ``lm.indexer``,
 ``lm.select``, ``lm.sparse_attention``, ``lm.router``, ``lm.experts``,
 ``lm.shared_expert``, ``lm.dense_ffn`` and ``lm.head_loss``.
+
+**Which code attends.** On the TPU, where the shapes pass its fit test (the
+token count a multiple of a tile, head widths on lane tiles), the softmax
+over the selected keys is the Pallas pair of ``ops/selected_attention.py``
+(``lm_selected_attention`` / ``lm_selected_attention_bwd``): the float32
+score tile stays in VMEM, forward and backward. Everywhere else — the CPU,
+the ``tiny`` size, odd lengths — it is ``_chunked_attend``, masked dense XLA
+in chunks, which is also the tests' oracle. ``_kernel_applies`` chooses from
+the backend and the shapes; no option does.
 """
 
 from __future__ import annotations
@@ -49,6 +58,11 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from videop2p_tpu.ops.selected_attention import (
+    selected_attention_tiles,
+    selected_key_attention,
+)
 
 __all__ = [
     "DeepSeekV32Config",
@@ -65,8 +79,10 @@ __all__ = [
 # How the work is cut (no effect on the mathematics; each is clipped to the
 # input's length). Not configuration: one value is in use, tests patch them.
 Q_CHUNK = 2048      # queries per causal chunk: chunk c sees the keys up to
-                    # its own end only
-ATTN_ROWS = 512     # queries attended at once inside a chunk
+                    # its own end only (the scorer, and attention as XLA)
+ATTN_ROWS = 512     # queries attended at once inside a chunk where the
+                    # attention runs as XLA (_chunked_attend); the Pallas
+                    # pair has its own tiles (ops/selected_attention.py)
 INDEX_ROWS = 256    # queries scored at once inside a chunk
 FFN_ROWS = 4096     # tokens per dense feed-forward block
 # Rows of one expert's tokens per matmul. With the published layout at 16384
@@ -417,6 +433,42 @@ def _attend(q_nope, q_rope, k_nope, k_rope, v, mask, scale):
     return jnp.einsum("hqk,khd->qhd", prob, v)
 
 
+def _chunked_attend(q_nope, q_rope, k_nope, k_rope, v, mask, scale):
+    """:func:`_attend` as XLA: chunk ``c`` of ``Q_CHUNK`` queries against the
+    keys up to its own end, ``ATTN_ROWS`` queries at a time, each row block
+    recomputed in the backward pass (its float32 ``(heads, rows, keys)``
+    scores are what the layer could not keep)."""
+    t_len = q_nope.shape[0]
+    qc = min(Q_CHUNK, t_len)
+    ar = min(ATTN_ROWS, qc)
+    assert t_len % qc == 0 and qc % ar == 0, (t_len, qc, ar)
+    attend = jax.checkpoint(functools.partial(_attend, scale=scale))
+    out = []
+    for c in range(t_len // qc):
+        sl, kb = slice(c * qc, (c + 1) * qc), (c + 1) * qc
+        keys = (k_nope[:kb], k_rope[:kb], v[:kb])
+        out.append(lax.map(
+            lambda a, keys=keys: attend(a[0], a[1], *keys, a[2]),
+            (q_nope[sl].reshape((qc // ar, ar) + q_nope.shape[1:]),
+             q_rope[sl].reshape((qc // ar, ar) + q_rope.shape[1:]),
+             mask[sl, :kb].reshape(qc // ar, ar, kb))))
+    return jnp.concatenate(out, axis=0).reshape((t_len,) + v.shape[1:])
+
+
+def _kernel_applies(q_nope, q_rope, v) -> bool:
+    """Whether the selected-key attention runs as the Pallas pair
+    (``ops/selected_attention.py``): on the TPU, where its fit test takes the
+    shape — the token count a multiple of a tile, head widths on lane tiles,
+    the backward within VMEM. Elsewhere (the CPU, the ``tiny`` size, odd
+    lengths) it is :func:`_chunked_attend`. Chosen from what the input is,
+    never by an option."""
+    if jax.default_backend() != "tpu":
+        return False
+    t_len, heads, nope = q_nope.shape
+    return selected_attention_tiles(t_len, heads, nope, q_rope.shape[-1],
+                                    v.shape[-1], q_nope.dtype) is not None
+
+
 def attention(p, cfg: DeepSeekV32Config, x, angles, mask=None):
     """This chip's heads' part of the attention output (before the
     residual), and the selection it used. ``x`` is the normed input."""
@@ -439,23 +491,15 @@ def attention(p, cfg: DeepSeekV32Config, x, angles, mask=None):
         # no gradient through the scorer or into its inputs
         mask = select_keys(p["indexer"], cfg, lax.stop_gradient(x),
                            lax.stop_gradient(c_q), angles)
-    qc = min(Q_CHUNK, t_len)
-    ar = min(ATTN_ROWS, qc)
-    assert t_len % qc == 0 and qc % ar == 0, (t_len, qc, ar)
-    attend = jax.checkpoint(functools.partial(_attend, scale=cfg.softmax_scale))
-    out = []
     with jax.named_scope("lm.sparse_attention"):
-        for c in range(t_len // qc):
-            sl, kb = slice(c * qc, (c + 1) * qc), (c + 1) * qc
-            keys = (k_nope[:kb], k_rope[:kb], v[:kb])
-            out.append(lax.map(
-                lambda a, keys=keys: attend(a[0], a[1], *keys, a[2]),
-                (q_nope[sl].reshape(qc // ar, ar, hn, nd),
-                 q_rope[sl].reshape(qc // ar, ar, hn, rd),
-                 mask[sl, :kb].reshape(qc // ar, ar, kb))))
-        o = jnp.concatenate(out, axis=0).reshape(t_len, hn * vd)
+        if _kernel_applies(q_nope, q_rope, v):
+            o = selected_key_attention(q_nope, q_rope, k_nope, k_rope, v, mask,
+                                       cfg.softmax_scale)
+        else:
+            o = _chunked_attend(q_nope, q_rope, k_nope, k_rope, v, mask,
+                                cfg.softmax_scale)
     with jax.named_scope("lm.mla_proj"):
-        return _dense(o, p["o_proj"]["kernel"]), mask
+        return _dense(o.reshape(t_len, hn * vd), p["o_proj"]["kernel"]), mask
 
 
 # ------------------------------------------------------------- expert layer
