@@ -522,9 +522,7 @@ def test_cached_vs_live_controlled_delta_tracks_source_drift(sched, tiny, ctx5):
     streams' divergence must be DRIVEN BY (and bounded by a small multiple
     of) the live source's reconstruction drift. With random weights that
     drift is large (DDIM inversion's linearization assumes a trained ε-model),
-    which is exactly why the bound is relative, not absolute; bench.py
-    records the same pair of numbers at SD scale
-    (cached_vs_live_edit_max_abs_delta / cached_vs_live_source_max_abs_delta).
+    which is exactly why the bound is relative, not absolute.
     """
     fn, params, cfg = tiny
     x0 = jax.random.normal(jax.random.key(40), SHAPE)

@@ -401,7 +401,7 @@ def test_cost_report_renders_chargeback_and_savings(tmp_path):
 def test_tools_inventory_is_complete():
     """The smoke below covers every entry point: pin the inventory so a
     new tool must join the contract."""
-    assert len(_TOOLS) == 19
+    assert len(_TOOLS) == 12
     assert {"cost_report", "fleet_dash", "incident_report",
             "ledger_summary", "obs_diff", "probe_report",
             "serve_loadgen"} <= set(_TOOLS)
@@ -436,7 +436,6 @@ def test_tool_help_contract(tool, monkeypatch, capsys):
     ("obs_diff", ["nope.jsonl", "nope.jsonl"]),
     ("probe_report", ["nope.jsonl"]),
     ("trace_view", ["nope.jsonl"]),
-    ("xplane_top_ops", ["nope_trace_dir"]),
 ])
 def test_tool_missing_input_exits_2(tool, argv_tail, tmp_path,
                                     monkeypatch, capsys):

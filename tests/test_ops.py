@@ -56,7 +56,7 @@ def test_chunked_falls_back_on_indivisible():
 def test_fused_matches_dense_interpret():
     """The custom Pallas kernel (frame-0-KV resident in VMEM, full-row
     softmax) must equal dense — run in interpret mode so CPU tests cover the
-    kernel math; the real-TPU path is exercised by bench.py."""
+    kernel math; the real-TPU path is the benchmark's sd15 cell."""
     from videop2p_tpu.ops import fused_frame_attention
 
     q, k, v = _rand_qkv(jax.random.key(5), F=2, N=256, D=8)

@@ -201,7 +201,7 @@ def test_controlled_edit_end_to_end(sched, tiny):
 
 def test_long_video_chunked_controlled_edit(sched):
     """The long-video working point at tiny scale (BASELINE configs 3/5 —
-    24 frames; bench.py's long24 phase): invert + controlled edit with the
+    24 frames): invert + controlled edit with the
     query-chunked frame-attention kernel, which is the only memory-feasible
     kernel at 24 frames on one chip (dense 64²-site scores are ~19 GB).
     Chunked must agree with dense at identical params, and the blend carry /

@@ -41,7 +41,7 @@ def test_plan_windows_geometry_and_validation():
         [(0, 4), (3, 7), (6, 10), (9, 13), (10, 14)]
     assert [w.index for w in plan] == [0, 1, 2, 3, 4]
     assert all(w.frames == 4 for w in plan)
-    # the minute-of-footage counts the bench records (window 8, overlap 2)
+    # the minute-of-footage counts (window 8, overlap 2)
     assert len(plan_windows(128, 8, 2)) == 21
     assert len(plan_windows(480, 8, 2)) == 80
     # one-window degenerate case

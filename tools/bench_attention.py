@@ -1,9 +1,7 @@
 """Frame-attention kernel shootout at the SD-1.5 hot shape.
 
-Times every ops/attention.py implementation (plus head-dim-padded Pallas
-variants) at the 64²-site working point of the fast edit — B=3 streams,
-F=8 frames, H=8 heads, N=4096 tokens, d=40 — the op family that pins the
-edit step at 277 ms (MFU 0.36) in round 2.
+Times every ops/attention.py implementation at the 64²-site working point
+of the fast edit — B=3 streams, F=8 frames, H=8 heads, N=4096 tokens, d=40.
 
 Measurement per impl: warm on a fresh input, then time a CHAIN of calls
 where each input depends on the previous output (no call can start before
@@ -44,42 +42,10 @@ sys.path.insert(0, "/root/repo")
 from videop2p_tpu.ops.attention import (  # noqa: E402
     chunked_frame_attention,
     dense_frame_attention,
-    flash_frame_attention,
-    flash_rect_frame_attention,
     fused_frame_attention,
 )
 
 B, F, H, N, D = 3, 8, 8, 4096, 40
-
-
-def padded(fn, d_pad: int):
-    """Zero-pad the head dim before a kernel: scores are unchanged (extra
-    dims contribute 0 to q·k), V's extra columns are zero — slice them off.
-    Tests whether the Pallas kernel's d→128 tile padding is the loss."""
-
-    def wrapped(q, k, v):
-        pad = [(0, 0)] * (q.ndim - 1) + [(0, d_pad - q.shape[-1])]
-        pad_kv = [(0, 0)] * (k.ndim - 1) + [(0, d_pad - k.shape[-1])]
-        out = fn(
-            jnp.pad(q, pad),
-            jnp.pad(k, pad_kv),
-            jnp.pad(v, pad_kv),
-        )
-        return out[..., : q.shape[-1]]
-
-    return wrapped
-
-
-def scaled_pad(fn, d_pad: int):
-    """Pad variant with exact softmax scale: the kernel scales by
-    d_pad**-0.5, so pre-multiplying q by (d_pad/d)**0.5 restores the true
-    d**-0.5 — (d_pad/d)**0.5 · d_pad**-0.5 = d**-0.5."""
-
-    def wrapped(q, k, v):
-        q = q * (d_pad / q.shape[-1]) ** 0.5
-        return padded(fn, d_pad)(q, k, v)
-
-    return wrapped
 
 
 def measure(name, fn, reps: int = 8):
@@ -292,12 +258,6 @@ def main():
     measure("dense", dense_frame_attention, reps)
     measure("chunked(512)", functools.partial(chunked_frame_attention, q_chunk=512), reps)
     measure("chunked(1024)", functools.partial(chunked_frame_attention, q_chunk=1024), reps)
-    measure("flash d40", flash_frame_attention, reps)
-    measure("flash_rect d40", flash_rect_frame_attention, reps)
-    measure("flash pad64", scaled_pad(flash_frame_attention, 64), reps)
-    measure("flash_rect pad64", scaled_pad(flash_rect_frame_attention, 64), reps)
-    measure("flash pad128", scaled_pad(flash_frame_attention, 128), reps)
-    measure("flash_rect pad128", scaled_pad(flash_rect_frame_attention, 128), reps)
 
 
 if __name__ == "__main__":

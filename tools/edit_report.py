@@ -14,7 +14,7 @@ everything base64-embedded in one HTML file. The sidecar ``.npz`` is
 located from the ledger's ``attn_maps``/``quality`` events when not
 given explicitly.
 
-stdlib + numpy only (tests/test_bench_guard.py pins the import closure)
+stdlib + numpy only (tests/test_ledger_schema.py pins the import closure)
 — runs on any box the ledger was copied to, no plotting stack, no
 accelerator, no repo checkout beyond this package.
 """

@@ -118,7 +118,7 @@ def enable_compile_cache() -> str:
     ``<checkout>/.jax_cache`` (git-ignored; derived from this package's own
     location, because the path is part of the cache key and a directory
     that moves never hits). Called at the binary boundary (CLI entry
-    points, tools, bench.py, the test suite's conftest) — a library import
+    points, tools, the test suite's conftest) — a library import
     must not mutate global jax config; a second call in one process changes
     nothing. Entries are per backend: a cache filled by CPU runs is of no
     use on the chip."""

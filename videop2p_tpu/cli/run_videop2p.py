@@ -613,9 +613,8 @@ def main(
     attn_records = {}
     if use_cached:
         # capture + controlled denoise as ONE device program (the shared
-        # pipelines.cached_fast_edit — the same program bench.py measures):
-        # one dispatch instead of two, and the capture trees never surface
-        # as program outputs
+        # pipelines.cached_fast_edit): one dispatch instead of two, and the
+        # capture trees never surface as program outputs
         from videop2p_tpu.pipelines import cached_fast_edit
 
         print("Start Video-P2P!")

@@ -105,7 +105,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "from the SAME inversion products; per-request "
                          "'steps' outside the warmed buckets is a 400")
     # per-UNet-call cost levers (ISSUE 15 — models/quant.py,
-    # pipelines/reuse.py; docs/PERF_ANALYSIS.md "Per-call cost")
+    # pipelines/reuse.py)
     ap.add_argument("--quant_mode", type=str, default="off",
                     choices=["off", "w8", "w8a8"],
                     help="UNet weight quantization at set build: w8 = int8 "
@@ -125,8 +125,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--reuse_buckets", type=str, nargs="*", default=[],
                     help="additional reuse schedules to warm; per-request "
                          "'reuse_schedule' outside the warmed set is a 400")
-    # consistency-distilled few-step student (ISSUE 16 — train/distill.py;
-    # docs/PERF_ANALYSIS.md "Few-step student")
+    # consistency-distilled few-step student (ISSUE 16 — train/distill.py)
     ap.add_argument("--student_ckpt", type=str, default=None,
                     help="consistency-distilled student checkpoint "
                          "(train/distill.py save_student): the distilled "

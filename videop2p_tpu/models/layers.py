@@ -33,8 +33,8 @@ Dtype = jnp.dtype
 class TpuGroupNorm(nn.Module):
     """GroupNorm with an optional fused activation and a one-pass Pallas
     path (ops/groupnorm.py) on TPU where one statistics sample's slab fits
-    VMEM — the stats+apply two-traversal structure XLA lowers GroupNorm to
-    was 21 % of round-4 edit device time (docs/PERF_ANALYSIS.md).
+    VMEM, in place of the stats+apply two-traversal structure XLA lowers
+    GroupNorm to.
 
     Drop-in for ``nn.GroupNorm``: identical parameter tree ('scale'/'bias'
     of shape (C,)), identical statistics semantics (per-sample per-group,

@@ -84,8 +84,8 @@ class UNet3DConfig:
     # recompute is the default; the knob stays for bigger-HBM parts.
     remat_policy: Optional[str] = None
     # frame-attention kernel: "auto" (the Pallas forward / backward pair on
-    # TPU, dense elsewhere), "dense", "chunked" (memory-bounded backward: what
-    # training takes off the TPU), "flash" (stock Pallas; see ops/attention.py)
+    # TPU, dense elsewhere), "fused", "dense", "chunked" (memory-bounded
+    # backward: what training takes off the TPU); see ops/attention.py
     frame_attention: str = "auto"
     # GroupNorm implementation: "auto" = one-pass fused Pallas kernel on TPU
     # at VMEM-fitting sites (ops/groupnorm.py), "xla" = always the two-pass

@@ -53,7 +53,7 @@ __all__ = [
 ATTN_HEAT_RES: Tuple[int, int] = (16, 16)
 
 # keys every summarize_attn_record carries (the ledger `attn_maps` event
-# schema tests/test_bench_guard.py pins); mask keys appear only when the
+# schema tests/test_ledger_schema.py pins); mask keys appear only when the
 # record holds a LocalBlend mask series
 ATTN_SUMMARY_FIELDS = ("steps", "heat_shape", "sites", "entropy_mean")
 

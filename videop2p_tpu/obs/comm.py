@@ -37,7 +37,7 @@ introspection cover (PAPERS.md):
     0.0: the regression rule has a zero noise floor.
 
 Pure stdlib+numpy+jax (the obs import contract, pinned in
-tests/test_bench_guard.py).
+tests/test_ledger_schema.py).
 """
 
 from __future__ import annotations
@@ -76,7 +76,7 @@ COLLECTIVE_KINDS = (
     "collective-broadcast",
 )
 
-# schema-stable field sets (test_bench_guard pins them): every
+# schema-stable field sets (test_ledger_schema pins them): every
 # comm_analysis / device_telemetry ledger event carries at least these
 COMM_ANALYSIS_FIELDS = (
     "num_partitions",

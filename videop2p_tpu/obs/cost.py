@@ -55,7 +55,7 @@ __all__ = [
 ]
 
 # the per-request cost vector every terminal `done` record carries under
-# "cost" (pinned by test_bench_guard)
+# "cost" (pinned by test_ledger_schema)
 REQUEST_COST_FIELDS = (
     "program",
     "device_seconds",
@@ -68,7 +68,7 @@ REQUEST_COST_FIELDS = (
 )
 
 # one `cost_attribution` ledger event per tenant / per program at engine
-# close (pinned by test_bench_guard; obs/history.py's `cost` section and
+# close (pinned by test_ledger_schema; obs/history.py's `cost` section and
 # tools/cost_report.py's chargeback table key on these names)
 COST_ATTRIBUTION_FIELDS = (
     "scope",

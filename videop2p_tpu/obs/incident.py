@@ -70,7 +70,7 @@ __all__ = [
     "IncidentManager",
 ]
 
-# the `incident` ledger event schema (pinned by test_bench_guard):
+# the `incident` ledger event schema (pinned by test_ledger_schema):
 # everything else lives in the bundle's manifest.json
 INCIDENT_FIELDS = (
     "trigger",     # which declarative trigger fired (INCIDENT_TRIGGERS)

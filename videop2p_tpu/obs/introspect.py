@@ -17,9 +17,8 @@ jitted program's lowered/compiled artifact for:
     the same program produce the same fingerprint, and a *changed*
     fingerprint marks "XLA built a different program" across runs;
   * an instruction-category histogram of the optimized HLO (fusion / dot /
-    convolution / custom-call / copy counts — the op-family view
-    docs/PERF_ANALYSIS.md tabulates from device traces, but available
-    without hardware).
+    convolution / custom-call / copy counts — the op-family view of a
+    device trace, but available without hardware).
 
 Everything is emitted as one flat ``program_analysis`` record
 (:func:`analysis_record` keys are schema-stable — ``obs/history.py`` keys
@@ -135,7 +134,7 @@ def analyze_compiled(compiled) -> Dict[str, Any]:
     cannot produce one of them (e.g. no ``as_text`` on some plugin
     runtimes) yields a record missing those keys, not an exception.
 
-    Conventions (disclosed in docs/PERF_ANALYSIS.md): flops/bytes are
+    Conventions: flops/bytes are
     XLA's STATIC per-module counts — ``while``/``scan`` trip counts are
     not multiplied in — and the memory analysis describes the analyzed
     backend's schedule. Both are deterministic for a given program and

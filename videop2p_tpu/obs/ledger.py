@@ -1,8 +1,8 @@
 """RunLedger: one JSONL event stream per run.
 
-Unifies what previously lived in four places (phase_timer prints,
-MetricsLogger's metrics.jsonl, bench_details.json, and nothing at all for
-compiles) into a single machine-readable record of what a run compiled,
+Unifies what previously lived in three places (phase_timer prints,
+MetricsLogger's metrics.jsonl, and nothing at all for compiles) into a
+single machine-readable record of what a run compiled,
 executed, and measured:
 
   * ``run_start`` — run_id, git sha, jax version, backend/device/mesh
@@ -66,7 +66,7 @@ _PROGRAM: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
 
 # set while the AOT introspection compile runs: those backend-compile events
 # describe the ANALYSIS recompile (a persistent-cache hit in practice), not
-# the run's own work — recording them would double bench's compile totals
+# the run's own work — recording them would double a run's compile totals
 _SUPPRESS_COMPILE: contextvars.ContextVar[bool] = contextvars.ContextVar(
     "videop2p_obs_suppress_compile", default=False
 )
@@ -120,7 +120,7 @@ def program_label(name: str) -> Iterator[None]:
 def suppress_compile_events() -> Iterator[None]:
     """Compile events fired inside this block are NOT recorded — for AOT
     introspection recompiles that would otherwise double a run's compile
-    totals (obs.introspect / bench's program analyses)."""
+    totals (obs.introspect's program analyses)."""
     token = _SUPPRESS_COMPILE.set(True)
     try:
         yield

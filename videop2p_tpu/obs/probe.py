@@ -52,7 +52,7 @@ __all__ = [
     "ProbeSuite",
 ]
 
-# ledger-event schema pins (tests/test_bench_guard.py): every `probe`
+# ledger-event schema pins (tests/test_ledger_schema.py): every `probe`
 # event carries exactly these fields — obs/history.py's probe section and
 # tools/probe_report.py key on them. `content_sha256` is "" for probes
 # with no answer to hash (e.g. the 400-contract probe).

@@ -38,7 +38,7 @@ __all__ = [
 ]
 
 # the scalar keys every edit_quality_record summary carries (the ledger
-# `quality` event schema tests/test_bench_guard.py pins); mask-dependent
+# `quality` event schema tests/test_ledger_schema.py pins); mask-dependent
 # keys (background_psnr, mask_coverage) appear only when a mask exists
 QUALITY_SUMMARY_FIELDS = (
     "recon_psnr",

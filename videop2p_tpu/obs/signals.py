@@ -106,7 +106,7 @@ S_PROBE_LATENCY = "probe_latency"           # seconds, labels {target, probe}
 ERROR_STATUSES = ("error", "deadline_exceeded")
 FINISHED_STATUSES = ("done", "error", "deadline_exceeded", "engine_closed")
 
-# the `fleet_signals` ledger event schema (pinned by test_bench_guard)
+# the `fleet_signals` ledger event schema (pinned by test_ledger_schema)
 FLEET_SIGNALS_FIELDS = (
     "label",
     "t",

@@ -58,7 +58,7 @@ _LATENCY_ENV = "VIDEOP2P_OBS_LATENCY"
 RESERVOIR_CAPACITY = 512
 
 # schema-stable field set of the execute_timing ledger event
-# (test_bench_guard pins it; history TIMING_RULES reference these names)
+# (test_ledger_schema pins it; history TIMING_RULES reference these names)
 EXECUTE_TIMING_FIELDS = (
     "count",
     "sampled",

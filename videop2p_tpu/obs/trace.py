@@ -2,11 +2,10 @@
 ``*.xplane.pb`` protos ``jax.profiler`` writes, plus the timeline
 analyses the time-domain obs layer ledgers.
 
-The previous trace tooling (``tools/profile_xplane.py``) parsed the
-xplane proto through the tensorflow protobuf package — an import this
-image only satisfies with ``PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION=
-python`` and a tensorflow install, so trace mining was a standalone
-script feeding nothing into the ledger. This module decodes the
+Reading the xplane proto through the tensorflow protobuf package needs
+an import this image only satisfies with
+``PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION=python`` and a tensorflow
+install. This module decodes the
 protobuf **wire format** directly (varints + length-delimited fields;
 the xplane schema is stable and shallow), so the import closure stays
 stdlib+numpy — the obs import-guard test walks this file, and the HTML
@@ -38,8 +37,8 @@ Analyses (:func:`analyze_trace_dir` → a ``trace_analysis`` ledger event
 
 :func:`trace_window` wraps a region in a ``jax.profiler`` capture and
 emits the analysis into the active ledger — the CLIs' ``--trace_analysis``
-flag and bench.py's live-backend capture both go through it. jax is
-imported lazily there; importing this module never touches it.
+flag goes through it. jax is imported lazily there; importing this module
+never touches it.
 """
 
 from __future__ import annotations
@@ -70,7 +69,7 @@ __all__ = [
 ]
 
 # schema-stable numeric/string field set of the trace_analysis ledger
-# event (test_bench_guard pins it; TIMING_RULES reference these names)
+# event (test_ledger_schema pins it; TIMING_RULES reference these names)
 TRACE_ANALYSIS_FIELDS = (
     "name",
     "trace_dir",
@@ -271,8 +270,7 @@ def iter_line_events(
 
 
 def op_family(name: str) -> str:
-    """Bucket an XLA op name into a coarse family (moved here from
-    tools/profile_xplane.py so the tools and the ledger agree)."""
+    """Bucket an XLA op name into a coarse family."""
     base = name.split(".")[0].split("%")[-1]
     for fam in (
         "convolution", "dot", "fusion", "copy", "transpose", "reshape",
@@ -363,8 +361,7 @@ def analyze_events(
     ``trace_analysis`` record + the ``.npz`` sidecar arrays.
 
     ``device_total_s`` is the plain duration sum (async-overlapping ops
-    can push it past wall-clock — same convention as the bench's
-    ``module_device_seconds``); ``compute_s``/``collective_s`` are union
+    can push it past wall-clock); ``compute_s``/``collective_s`` are union
     lengths (true device-busy seconds per class); idle is measured
     against the union of ALL device events over the span.
     """

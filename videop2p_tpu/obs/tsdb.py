@@ -51,7 +51,7 @@ __all__ = [
     "load_series_sidecar",
 ]
 
-# the `fleet_series` ledger event schema (pinned by test_bench_guard)
+# the `fleet_series` ledger event schema (pinned by test_ledger_schema)
 FLEET_SERIES_FIELDS = (
     "label",
     "series",

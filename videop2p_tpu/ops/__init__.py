@@ -1,4 +1,5 @@
-"""Hot-path kernels: flash/chunked attention for the spatial frame attention."""
+"""Hot-path kernels: the fused Pallas pair and the chunked / dense fallbacks
+for the spatial frame attention."""
 
 from videop2p_tpu.ops.attention import (
     chunked_frame_attention,

@@ -22,9 +22,6 @@ one dispatch (what each costs on the chip is in PERF.md §5–§6, nowhere else)
     refuses, and the sharded-mesh path (pjit cannot partition a Pallas
     custom call). On the chip it writes every score chunk to HBM several
     times over, which is why the tune left it (PERF.md §6, PR 27).
-  * **flash / flash_rect** — the stock Pallas flash-attention kernel
-    (``jax.experimental.pallas.ops.tpu.flash_attention``); kept for
-    comparison (tools/bench_attention.py).
 
 These kernels are only for the UNCONTROLLED frame attention. The P2P
 controlled sites (text-cross, temporal) must materialize probabilities for
@@ -42,8 +39,6 @@ import jax.numpy as jnp
 __all__ = [
     "dense_frame_attention",
     "chunked_frame_attention",
-    "flash_frame_attention",
-    "flash_rect_frame_attention",
     "fused_frame_attention",
     "fused_bwd_block",
     "make_frame_attention_fn",
@@ -90,38 +85,6 @@ def chunked_frame_attention(
         qc = jnp.moveaxis(q.reshape(b, f, h, nc, q_chunk, d), 3, 0)  # (nc,B,F,H,C,D)
         out = jax.lax.map(one_chunk, qc)  # (nc, B, F, H, C, D)
         return jnp.moveaxis(out, 0, 3).reshape(b, f, h, n, d)
-
-
-@jax.named_scope(_SCOPE)
-def flash_frame_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
-    """Pallas TPU flash attention with the frame axis folded into batch and
-    the shared frame-0 KV broadcast per frame."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import flash_attention
-
-    b, f, h, n, d = q.shape
-    qf = q.reshape(b * f, h, n, d)
-    kf = jnp.broadcast_to(k[:, None], (b, f, h, n, d)).reshape(b * f, h, n, d)
-    vf = jnp.broadcast_to(v[:, None], (b, f, h, n, d)).reshape(b * f, h, n, d)
-    out = flash_attention(qf, kf, vf, sm_scale=d ** -0.5)
-    return out.reshape(b, f, h, n, d)
-
-
-@jax.named_scope(_SCOPE)
-def flash_rect_frame_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
-    """Pallas TPU flash attention with frames folded into the QUERY length.
-
-    The frame-0 KV is shared by every frame, so instead of broadcasting KV
-    per frame (``flash_frame_attention`` — the materialized copies eat the
-    kernel's win), queries from all frames form one long rectangular
-    attention: q (B, H, F·N, D) against kv (B, H, N, D). Softmax is per-row,
-    so the fold is exact; no probability tensor or KV copy materializes.
-    """
-    from jax.experimental.pallas.ops.tpu.flash_attention import flash_attention
-
-    b, f, h, n, d = q.shape
-    qf = q.transpose(0, 2, 1, 3, 4).reshape(b, h, f * n, d)
-    out = flash_attention(qf, k, v, sm_scale=d ** -0.5)
-    return out.reshape(b, h, f, n, d).transpose(0, 2, 1, 3, 4)
 
 
 def _fused_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float):
@@ -182,15 +145,14 @@ def fused_frame_attention(
     spatial self-attention shares frame 0's keys/values).
 
     The XLA dense path materializes the (B,F,H,N,N) bf16 score tensor in HBM
-    (3.2 GB per 64²-site instance at the edit batch — measured ~18 ms per
-    instance per step, ~32 % of the round-2 edit scan; tools/xplane_top_ops).
+    (3.2 GB per 64²-site instance at the edit batch).
     Here K/V for one (batch, head) are tiny — N·D ≈ 320 KB each — so they sit
     resident in VMEM while query blocks stream through: one QKᵀ, an exact
     full-row softmax (no online accumulation needed), one PV, nothing but
     q/out ever touching HBM. Frames fold into the query length (softmax is
-    per-row, so the fold is exact; same trick as flash_rect), giving long
-    M = F·N grids that also cover the 24/32-frame long-video shapes without
-    the chunked path's lax.map overhead.
+    per-row, so the fold is exact), giving long M = F·N grids that also cover
+    the 24/32-frame long-video shapes without the chunked path's lax.map
+    overhead.
 
     Differentiation runs the backward kernel of the same structure
     (:func:`_fused_bwd_kernel`; residuals are just ``(q, k, v)``) where
@@ -392,16 +354,12 @@ def make_frame_attention_fn(
         16 GB chip when combined with gradients). What training takes off
         the TPU (:func:`training_frame_attention`) and what a mesh takes
         where GSPMD partitions the op.
-      * "flash" / "flash_rect" — the stock Pallas TPU kernel, with per-frame
-        broadcast KV or frames folded into the query length respectively
-        (head dims pad to ≤128; otherwise falls back to chunked). Kept for
-        comparison.
     """
     if impl == "auto":
         impl = "fused" if jax.default_backend() == "tpu" else "dense"
     if impl == "dense":
         return None
-    if impl not in ("flash", "flash_rect", "chunked", "fused"):
+    if impl not in ("chunked", "fused"):
         raise ValueError(f"unknown frame attention impl: {impl!r}")
 
     def fn(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
@@ -424,11 +382,6 @@ def make_frame_attention_fn(
             if (f * n) % q_blk == 0 and d <= 128:
                 return fused_frame_attention(q, k, v, q_blk)
             return chunked_frame_attention(q, k, v, q_chunk=q_chunk)
-        flash_ok = (d <= 128 or d % 128 == 0) and jax.default_backend() == "tpu"
-        if impl == "flash_rect" and flash_ok:
-            return flash_rect_frame_attention(q, k, v)
-        if impl == "flash" and flash_ok:
-            return flash_frame_attention(q, k, v)
         return chunked_frame_attention(q, k, v, q_chunk=q_chunk)
 
     return fn

@@ -1,8 +1,6 @@
 """One-pass fused GroupNorm(+SiLU) for TPU.
 
-GroupNorm is the UNet's second-largest op family on chip after attention
-(round-4 trace: 2.40 s of ``convert_reduce_fusion`` stats passes, 21 % of
-device time — docs/PERF_ANALYSIS.md). XLA lowers a GroupNorm as two slab
+XLA lowers a GroupNorm as two slab
 traversals plus a write: a stats pass (read x, convert bf16→f32, reduce)
 and an apply pass (read x again, normalize, write y). When one sample's
 (rows, channels) slab fits VMEM, a Pallas kernel can keep the slab
@@ -31,8 +29,7 @@ The big frame-pooled resnet slabs (64²: 21–63 MB, 32²: 10–31 MB) CANNOT be
 single-pass on this hardware: statistics need the full slab before the
 first normalized element can be written, and a slab larger than VMEM
 therefore must be read twice — once for stats, once for apply — which is
-exactly XLA's schedule. Those sites are already at their traversal floor;
-see docs/PERF_ANALYSIS.md for the ceiling arithmetic.
+exactly XLA's schedule. Those sites are already at their traversal floor.
 """
 
 from __future__ import annotations
@@ -177,7 +174,7 @@ def _fused_gn(
         functools.partial(_gn_kernel, eps=eps, rows=rows, act=act),
         # explicit name: trace events otherwise carry only the flax scope
         # (norm1/norm2/…), making the kernel indistinguishable from the
-        # XLA-path ops in an A/B profile (tools/bench_groupnorm.py)
+        # XLA-path ops in a profile
         name="fused_group_norm",
         out_shape=jax.ShapeDtypeStruct((n, rows, c), x.dtype),
         grid=(n,),

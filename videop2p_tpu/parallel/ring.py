@@ -42,8 +42,8 @@ Variants (``variant=`` / ``VIDEOP2P_RING_VARIANT``):
     exact same math (online softmax is order-invariant up to fp rounding).
   * ``"serial"`` — the pre-rewrite schedule (compute-then-permute, ``n``
     rotations including the dead final pair), kept ONLY as the measurable
-    baseline for the comm-accounting A/B in the multichip dryrun and
-    ``tools/cpu_cost_capture.py``; never the default.
+    baseline for the comm-accounting A/B in the multichip dryrun; never
+    the default.
 
 ``ring_attention`` is the shard_map-level primitive; ``ring_attention_sharded``
 wraps it for callers holding globally-sharded arrays.

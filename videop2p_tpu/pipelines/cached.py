@@ -58,8 +58,8 @@ def capture_windows(ctx, num_steps: int) -> Tuple[int, Tuple[int, int]]:
     cross base maps are only read while ANY word's ``cross_replace_alpha`` is
     nonzero (a step prefix — conservative for per-word dict schedules), and
     temporal base maps only inside the self-replace window. Returns
-    ``(cross_len, (self_lo, self_hi))``. Shared by the CLI, the bench and
-    the tests so the rule cannot drift between them."""
+    ``(cross_len, (self_lo, self_hi))``. Shared by the CLI, the serving
+    programs and the tests so the rule cannot drift between them."""
     import numpy as np
 
     cra = np.asarray(jax.device_get(ctx.cross_replace_alpha))[:num_steps]

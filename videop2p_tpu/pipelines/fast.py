@@ -2,9 +2,8 @@
 as one traceable function.
 
 One device program = one host dispatch, and the multi-GiB capture trees
-never surface as program outputs. Shared by the CLI (cli/run_videop2p.py) and the bench
-(bench.py) so the benchmarked program IS the program users run — the two
-cannot drift apart.
+never surface as program outputs. What the CLI (cli/run_videop2p.py) runs,
+so a measurement of this function is a measurement of the users' program.
 """
 
 from __future__ import annotations

@@ -57,7 +57,7 @@ DDIM inversions per edit of the same clip. This package keeps both warm:
     429/503/``Retry-After``.
 
 Import contract: stdlib + numpy + jax (+ the package itself) only — the
-same guard as ``obs/`` (tests/test_bench_guard.py walks this package).
+same guard as ``obs/`` (tests/test_ledger_schema.py walks this package).
 """
 
 from videop2p_tpu.serve.batching import (

@@ -71,7 +71,7 @@ __all__ = [
 
 FAULTS_ENV = "VIDEOP2P_SERVE_FAULTS"
 
-# ledger-event schema pins (tests/test_bench_guard.py): the `fault` and
+# ledger-event schema pins (tests/test_ledger_schema.py): the `fault` and
 # `breaker` events and the end-of-run `serve_health` summary carry these
 # fields — obs/history.py's reliability section and tools/obs_diff.py's
 # reliability table key on the serve_health names.

@@ -20,7 +20,7 @@ key (:func:`videop2p_tpu.serve.batching.compat_key`).
 Every program goes through :func:`~videop2p_tpu.obs.ledger.instrumented_jit`,
 so with an active :class:`~videop2p_tpu.obs.RunLedger` the serving engine
 gets compile attribution, per-program XLA analyses, and the ``--latency``
-reservoirs for free — the same machinery the bench and CLIs use.
+reservoirs for free — the same machinery the CLIs use.
 
 Stdlib+numpy+jax only (model/pipeline code reached through the package) —
 the import-guard test walks this package like ``obs/``.

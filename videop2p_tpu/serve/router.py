@@ -58,7 +58,7 @@ from videop2p_tpu.serve.faults import EngineUnavailable, RetryPolicy
 __all__ = ["Router", "RouterServer", "make_router_server",
            "ROUTER_HEALTH_FIELDS"]
 
-# ledger-event schema pin (tests/test_bench_guard.py): the `router_health`
+# ledger-event schema pin (tests/test_ledger_schema.py): the `router_health`
 # summary's numeric fields — obs/history.py extracts them into the
 # reliability section (label "router") so FAULT_RULES-style gates apply.
 ROUTER_HEALTH_FIELDS = (

@@ -30,7 +30,6 @@ from videop2p_tpu.stream.windows import (
     blend_weights,
     plan_windows,
     seam_spans,
-    streaming_plan_record,
     synthetic_clip,
     window_key,
 )
@@ -50,5 +49,4 @@ __all__ = [
     "seam_spans",
     "window_key",
     "synthetic_clip",
-    "streaming_plan_record",
 ]

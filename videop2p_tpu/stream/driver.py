@@ -76,7 +76,7 @@ __all__ = [
     "STREAM_SEAM_FIELDS",
 ]
 
-# ledger-event schema pins (tests/test_bench_guard.py): the job-level
+# ledger-event schema pins (tests/test_ledger_schema.py): the job-level
 # `stream_health` summary — obs/history.py extracts it into the `stream`
 # section (label "stream") where SEAM_RULES gate seam-quality drops and
 # new window failures/passthroughs with obs_diff exit-1 teeth.

@@ -12,13 +12,10 @@ are C0-continuous instead of hard cuts.
 
 Everything in this module is pure host math (numpy + stdlib — the
 import-guard test walks this package): the window plan, the crossfade
-weights, the assembly, the content-addressed per-window key, and the
-static cost model ``streaming_plan_record`` the bench uses to land
-128f/480f streaming evidence in ``bench_details.json`` even on
-``backend_unavailable`` rounds. Determinism is the point — the SAME plan,
-weights and assembly order on every run is what makes a killed job's
-resume bit-identical to an uninterrupted one (``stream/manifest.py``,
-``stream/driver.py``).
+weights, the assembly and the content-addressed per-window key.
+Determinism is the point — the SAME plan, weights and assembly order on
+every run is what makes a killed job's resume bit-identical to an
+uninterrupted one (``stream/manifest.py``, ``stream/driver.py``).
 """
 
 from __future__ import annotations
@@ -37,7 +34,6 @@ __all__ = [
     "seam_spans",
     "window_key",
     "synthetic_clip",
-    "streaming_plan_record",
 ]
 
 
@@ -206,43 +202,3 @@ def synthetic_clip(
                 freq[c] * (xx + yy) / size * 2 * np.pi + phase[c] + drift
             )
     return (frames * 255).astype(np.uint8)
-
-
-def streaming_plan_record(
-    total_frames: int,
-    window: int,
-    overlap: int,
-    *,
-    steps: int,
-    latent_size: int,
-    latent_channels: int = 4,
-    flops_per_window: Optional[float] = None,
-) -> Dict[str, Any]:
-    """The static cost model of one streaming plan — the bench's
-    ``streaming_scaling`` evidence row (``bench.STREAMING_WINDOW_FIELDS``
-    pins the shape): window count, the overlap-redundancy overhead
-    (frames processed / frames delivered − 1), total flops scaled from
-    one window's measured analysis, and the content-addressed store
-    footprint (one fp32 trajectory of ``steps + 1`` latents per window —
-    the disk entry a killed job rehydrates from). Per-window numbers are
-    the point: streaming holds device memory FLAT per window while total
-    work grows linearly."""
-    plan = plan_windows(total_frames, window, overlap)
-    n = len(plan)
-    processed = n * int(window)
-    store_per = (int(steps) + 1) * int(window) * int(latent_size) ** 2 \
-        * int(latent_channels) * 4
-    return {
-        "total_frames": int(total_frames),
-        "window": int(window),
-        "overlap": int(overlap),
-        "stride": int(window) - int(overlap),
-        "windows": n,
-        "frames_processed": processed,
-        "overlap_overhead": round(processed / int(total_frames) - 1.0, 4),
-        "flops_per_window": flops_per_window,
-        "flops_total": (flops_per_window * n
-                        if flops_per_window else None),
-        "store_bytes_per_window": store_per,
-        "store_bytes_total": store_per * n,
-    }

@@ -5,7 +5,7 @@ The reference tracks training through HF Accelerate —
 ``accelerator.log({"train_loss": ...})`` plus a tqdm postfix with
 ``step_loss``/``lr`` (/root/reference/run_tuning.py:234,337,377-378). Here a
 :class:`MetricsLogger` appends one JSON object per logged step to
-``<run_dir>/metrics.jsonl`` (machine-readable for the bench/driver) and, when
+``<run_dir>/metrics.jsonl`` (machine-readable) and, when
 the ``tensorboard`` package is importable, mirrors scalars into
 ``<run_dir>/tb/`` for the usual dashboard.
 
