@@ -364,8 +364,8 @@ def test_comm_analysis_record_ring_program(mesh8):
 @pytest.mark.slow
 def test_instrumented_jit_sharded_emits_comm_analysis(tmp_path, mesh8):
     """Sharded calls are first-class obs citizens now: a cache miss on a
-    sharded program emits BOTH program_analysis (the re-lowering keeps the
-    shardings, so it describes the partitioned program) and comm_analysis
+    sharded program emits BOTH program_analysis (the signature asked for
+    keeps the shardings, so it is the partitioned program) and comm_analysis
     — where the pre-PR-5 code silently skipped."""
     from videop2p_tpu.obs import instrumented_jit
     from videop2p_tpu.parallel import ring_attention_sharded
@@ -392,6 +392,42 @@ def test_instrumented_jit_sharded_emits_comm_analysis(tmp_path, mesh8):
     assert ca[0]["num_partitions"] == 8
     assert ca[0]["collective_permute_bytes"] > 0
     assert not skipped
+
+
+def test_a_sharded_miss_reads_the_partitioned_program_it_ran(tmp_path, mesh8):
+    """Tier-1's guard on the sharded path of the analysis: the call's
+    signature carries its shardings, so the analysis is handed the
+    partitioned program the call built (``rebuilt`` false: one lowering,
+    one backend compile in all) and ``comm_analysis`` is still emitted —
+    eight partitions, the all-reduce of the sum."""
+    from videop2p_tpu.obs import instrumented_jit
+
+    x = jax.device_put(jnp.arange(64.0).reshape(2, 8, 4),
+                       NamedSharding(mesh8, P(None, "frames", None)))
+    f = instrumented_jit(lambda x, scale: jnp.sum(jnp.tanh(x)) * scale,
+                         program="sharded_sum")
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path, device_info=False):
+        f(x, jnp.asarray(2.0))
+    events = read_ledger(path)
+    spans = [e for e in events if e["event"] == "span"]
+
+    def kids(parent):
+        return [s for s in spans if s["parent_id"] == parent["span_id"]]
+
+    call, = [s for s in spans if s["name"] == "program.call"]
+    analysis, = [s for s in kids(call) if s["name"] == "program.analysis"]
+    assert analysis["rebuilt"] is False
+    assert not {"program.lower", "program.backend_compile"} & {
+        s["name"] for s in kids(analysis)}
+    assert [s["name"] for s in kids(call)].count("program.lower") == 1
+    assert [s["name"] for s in kids(call)].count("program.backend_compile") == 1
+    pa, = [e for e in events if e["event"] == "program_analysis"]
+    ca, = [e for e in events if e["event"] == "comm_analysis"]
+    assert ca["program"] == pa["program"] == "sharded_sum"
+    assert ca["num_partitions"] == 8 and ca["all_reduce_count"] >= 1
+    assert ca["hlo_fingerprint"] == pa["hlo_fingerprint"]
+    assert not [e for e in events if e["event"] == "program_analysis_skipped"]
 
 
 @pytest.mark.slow

@@ -5,6 +5,7 @@ compile-time attribution by the jax.monitoring listener, ``program.analysis``,
 vocabulary the benchmark reads held against a tiny ``run_tuning.main``."""
 
 import contextvars
+import functools
 import importlib.util
 import os
 import re
@@ -235,12 +236,27 @@ def test_listener_turns_durations_into_children_and_sums_unlabelled(tmp_path):
     assert end["unspanned_backend_compiles"] == 1
 
 
+def _kids(spans, parent, name=None):
+    return [s for s in spans if s["parent_id"] == parent["span_id"]
+            and (name is None or s["name"] == name)]
+
+
+def _subtree(spans, root):
+    out, todo = [], [root]
+    while todo:
+        found = _kids(spans, todo.pop())
+        out += found
+        todo += found
+    return out
+
+
 def test_program_analysis_encloses_the_analysis_and_only_it(tmp_path):
     """On a jit-cache miss the introspection pass runs under
-    ``program.analysis``: what it traces, lowers and compiles again are ITS
-    children and stay out of the run's compile totals; the call's own are
-    the call's; ``program.execute`` starts where the compile ended; a hit
-    has neither compile children nor an analysis."""
+    ``program.analysis``: it reads the executable the call built (no
+    lowering and no backend compile of its own: ``rebuilt`` false) in parts
+    that are its children; the call's trace, lowering and compile are the
+    call's; ``program.execute`` starts where the compile ended; a hit has
+    neither compile children nor an analysis."""
     path = str(tmp_path / "ledger.jsonl")
     with RunLedger(path, latency=True) as led:
         f = instrumented_jit(lambda x: jnp.tanh(x) @ x.T,
@@ -257,20 +273,18 @@ def test_program_analysis_encloses_the_analysis_and_only_it(tmp_path):
     assert miss["cache_miss"] is True and hit["cache_miss"] is False
     assert miss["program"] == "toy_analysis" and miss["rows"] == 32
 
-    def kids(parent, name=None):
-        return [s for s in spans if s["parent_id"] == parent["span_id"]
-                and (name is None or s["name"] == name)]
-
+    kids = functools.partial(_kids, spans)
     analysis, = kids(miss, "program.analysis")
     execute, = kids(miss, "program.execute")
     own_compile, = kids(miss, "program.backend_compile")
     assert kids(miss, "program.trace") and kids(miss, "program.lower")
-    # the analysis' own trace / lower / compile hang under it, not the call
-    assert {s["name"] for s in kids(analysis)} <= {
-        "program.trace", "program.lower", "program.backend_compile"}
+    # what is left in the analysis is the reading, part by part
+    assert analysis["rebuilt"] is False
+    assert {s["name"] for s in kids(analysis)} - {"program.trace"} == {
+        "analysis.text", "analysis.cost", "analysis.memory", "analysis.mine",
+        "analysis.comm"}
     assert len(kids(miss, "program.backend_compile")) == 1
-    # exactly one `compile` event for the program: the analysis' recompile
-    # stays out of the totals, as before
+    # exactly one `compile` event for the program: the call's own
     assert len([e for e in events if e["event"] == "compile"
                 and e["program"] == "toy_analysis"]) == 1
     # it encloses the analysis: the program_analysis event is written inside
@@ -291,6 +305,176 @@ def test_program_analysis_encloses_the_analysis_and_only_it(tmp_path):
     assert [s["name"] for s in kids(hit)] == ["program.execute"]
     # the children of the miss make it up (within the wrapper's own overhead)
     assert sum(s["duration_s"] for s in kids(miss)) <= miss["duration_s"] + 1e-3
+
+
+# Each case: (traced) -> (function, jit options, [(args, kwargs) a call]).
+# Every call has a signature of its own, so every call is a miss; `traced`
+# is appended to whenever jax runs the Python body.
+
+
+def _case_weak_scalar(traced):
+    def f(step, x):
+        traced.append(1)
+        return step + 1, jnp.tanh(x) @ x.T * step
+
+    step = jnp.asarray(0)  # weak-typed, as TrainState.create makes it
+    assert step.weak_type
+    return f, {}, [((step, jnp.ones((16, 16))), {})]
+
+
+def _case_train_state(traced):
+    """``run_tuning.main``'s own wrapping of ``loss_steps``: the state
+    donated, the step count static, ``state.step`` weak through the scan."""
+    from videop2p_tpu.train import TrainState, TuneConfig, make_optimizer
+    from videop2p_tpu.train.tuner import StepLoss, loss_steps
+
+    params = {"blk": {"attn1": {"to_q": {"kernel": jnp.full((8, 8), 0.1)}},
+                      "proj": {"kernel": jnp.full((8, 8), 0.2, jnp.bfloat16)}}}
+    tx = make_optimizer(TuneConfig(learning_rate=1e-3))
+    state = TrainState.create(params, tx)
+    assert state.step.weak_type and jax.tree.leaves(state.frozen)
+
+    def loss(p, drawn):
+        y = drawn @ p["blk"]["attn1"]["to_q"]["kernel"]
+        y = y @ p["blk"]["proj"]["kernel"].astype(jnp.float32)
+        return jnp.mean(y ** 2), {}
+
+    step_loss = StepLoss(draw=lambda key: jax.random.normal(key, (4, 8)),
+                         loss=loss)
+
+    def program(s, k, n):
+        traced.append(1)
+        return loss_steps(step_loss, tx, s, k, num_steps=n)
+
+    return (program, {"static_argnums": 2, "donate_argnums": (0,)},
+            [((state, jax.random.key(0), 3), {})])
+
+
+def _case_typed_key(traced):
+    def f(key, x):
+        traced.append(1)
+        return x + jax.random.normal(key, x.shape)
+
+    return f, {}, [((jax.random.key(7), jnp.zeros((8, 4))), {})]
+
+
+def _case_bfloat16(traced):
+    def f(w, x):
+        traced.append(1)
+        return (x.astype(jnp.bfloat16) @ w).astype(jnp.float32)
+
+    return f, {}, [((jnp.ones((8, 8), jnp.bfloat16), jnp.ones((4, 8))), {})]
+
+
+def _case_keywords(traced):
+    def f(x, *, scale, shift):
+        traced.append(1)
+        return x * scale + shift
+
+    return f, {}, [((jnp.ones((4, 4)),),
+                    {"scale": jnp.asarray(2.0), "shift": jnp.ones((4,))})]
+
+
+def _case_committed_leaf(traced):
+    """A leaf put on a device by name is COMMITTED: the call lowers with its
+    sharding as the argument's, and so must the analysis."""
+    def f(x, y):
+        traced.append(1)
+        return jnp.tanh(x) @ y
+
+    x = jax.device_put(jnp.ones((8, 8)), jax.devices()[0])
+    assert x._committed
+    return f, {}, [((x, jnp.ones((8, 8))), {})]
+
+
+def _case_second_shape(traced):
+    def f(x):
+        traced.append(1)
+        return jnp.tanh(x) @ x.T
+
+    return f, {}, [((jnp.ones((8, 8)),), {}), ((jnp.ones((16, 8)),), {})]
+
+
+@pytest.mark.parametrize("case", [
+    _case_weak_scalar, _case_train_state, _case_typed_key, _case_bfloat16,
+    _case_keywords, _case_committed_leaf, _case_second_shape,
+], ids=lambda c: c.__name__[len("_case_"):])
+def test_a_miss_builds_its_program_once(tmp_path, case):
+    """A miss through ``instrumented_jit`` traces the Python body once and
+    fires one lowering and one backend compile IN ALL: the analysis is
+    handed the call's own build (``rebuilt`` false, no ``program.lower`` /
+    ``program.backend_compile`` under it) and still writes its record."""
+    traced = []
+    fun, jit_kwargs, calls = case(traced)
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path):
+        f = instrumented_jit(fun, program="once", **jit_kwargs)
+        for args, kwargs in calls:
+            jax.block_until_ready(f(*args, **kwargs))
+    events = read_ledger(path)
+    spans = [e for e in events if e["event"] == "span"]
+    misses = [s for s in spans if s["name"] == "program.call"]
+    assert len(misses) == len(calls) == len(traced)
+    for miss in misses:
+        assert miss["cache_miss"] is True
+        names = [s["name"] for s in _subtree(spans, miss)]
+        assert names.count("program.lower") == 1, names
+        assert names.count("program.backend_compile") == 1, names
+        analysis, = _kids(spans, miss, "program.analysis")
+        assert analysis["rebuilt"] is False
+        under = {s["name"] for s in _subtree(spans, analysis)}
+        assert not under & {"program.lower", "program.backend_compile"}, under
+        assert len(_kids(spans, miss, "program.lower")) == 1
+        assert len(_kids(spans, miss, "program.backend_compile")) == 1
+    assert not [e for e in events if e["event"] == "program_analysis_skipped"]
+    records = [e for e in events if e["event"] == "program_analysis"]
+    assert len(records) == len(calls)
+    for rec in records:
+        assert rec["program"] == "once"
+        assert rec["flops"] >= 0 and rec["peak_hbm_bytes"] > 0
+        assert len(rec["hlo_fingerprint"]) == 16
+    # the run's compile totals hold each build once
+    assert len([e for e in events if e["event"] == "compile"
+                and e["program"] == "once"]) == len(calls)
+
+
+def test_an_analysis_that_builds_again_says_rebuilt(tmp_path, monkeypatch):
+    """The abstraction as it was before (shape and dtype alone) asks jax for
+    another signature than the call's wherever a leaf is weak-typed: the
+    analysis then traces, lowers and compiles the program a second time,
+    and ``rebuilt`` says so — a JAX upgrade that stops sharing the call's
+    build fails HERE instead of costing every run its compile again."""
+    from videop2p_tpu.obs import introspect
+
+    def shape_and_dtype_only(args, kwargs):
+        def to_abstract(leaf):
+            if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
+                return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+            return leaf
+
+        return (jax.tree.map(to_abstract, args),
+                jax.tree.map(to_abstract, kwargs))
+
+    monkeypatch.setattr(introspect, "abstractify_args", shape_and_dtype_only)
+    traced = []
+    fun, jit_kwargs, calls = _case_weak_scalar(traced)
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path):
+        f = instrumented_jit(fun, program="twice", **jit_kwargs)
+        for args, kwargs in calls:
+            f(*args, **kwargs)
+    events = read_ledger(path)
+    spans = [e for e in events if e["event"] == "span"]
+    miss, = [s for s in spans if s["name"] == "program.call"]
+    analysis, = _kids(spans, miss, "program.analysis")
+    assert analysis["rebuilt"] is True and len(traced) == 2
+    assert {"program.lower", "program.backend_compile"} <= {
+        s["name"] for s in _kids(spans, analysis)}
+    # the second build is the analysis': the run's totals hold the call's
+    assert len([e for e in events if e["event"] == "compile"
+                and e["program"] == "twice"]) == 1
+    assert [e["program"] for e in events
+            if e["event"] == "program_analysis"] == ["twice"]
 
 
 def test_execute_span_never_adds_a_sync(tmp_path, monkeypatch):
@@ -491,6 +675,20 @@ def test_the_benchmarks_readers_make_up_the_setup_on_a_tiny_main(
     named = sum(parts[k] for k in ("models", "clip", "trace_lower", "load",
                                    "analysis", "execute", "unattributed"))
     assert named == pytest.approx(parts["setup"])
-    # the analysis pass traced and compiled again, under its own span
+    # the analysis pass read the program the first call built: one lowering
+    # and one backend compile in the whole call, neither under the analysis
     assert parts["analysis"] > 0 and parts["trace_lower"] > 0
+    spans = [e for e in tiny_tune_ledger if e["event"] == "span"]
+    first = min((s for s in spans if s["name"] == "program.call"
+                 and s["program"] == "train_steps"),
+                key=lambda s: s["wall_ns"])
+    names = [s["name"] for s in _subtree(spans, first)]
+    assert names.count("program.lower") == 1, names
+    assert names.count("program.backend_compile") == 1, names
+    analysis, = _kids(spans, first, "program.analysis")
+    assert analysis["rebuilt"] is False
+    assert not {"program.lower", "program.backend_compile"} & {
+        s["name"] for s in _subtree(spans, analysis)}
+    assert [e["program"] for e in tiny_tune_ledger
+            if e["event"] == "program_analysis"] == ["train_steps"]
     assert bench.host_between_calls_ms(ctx) > 0

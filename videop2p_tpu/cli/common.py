@@ -304,8 +304,8 @@ def add_obs_args(parser: argparse.ArgumentParser) -> None:
         "--no_program_analysis", action="store_true",
         help="skip the automatic compiled-program introspection "
              "(cost/memory analysis + HLO fingerprint per instrumented "
-             "program on each compile) — it re-lowers each program "
-             "ahead-of-time, which is persistent-cache-cheap but not free",
+             "program on each compile) — it reads the executable the call "
+             "built: printing a UNet-scale module's text takes seconds",
     )
     parser.add_argument(
         "--device_telemetry", action="store_true",

@@ -171,18 +171,22 @@ def sharding_strs(shardings) -> List[str]:
     return out
 
 
-def comm_analysis_record(compiled) -> Optional[Dict[str, Any]]:
+def comm_analysis_record(compiled, hlo_text: Optional[str] = None
+                         ) -> Optional[Dict[str, Any]]:
     """Mine one ``jax.stages.Compiled`` executable into a flat
     ``comm_analysis`` record: partition count, per-kind collective
     counts/bytes (plus flattened ``<kind>_count``/``<kind>_bytes`` keys
     the regression rules can target), and the per-arg/out sharding specs.
+    ``hlo_text`` is ``compiled.as_text()`` where the caller already holds it.
     Returns None when the module text is unavailable."""
     from videop2p_tpu.obs.introspect import hlo_fingerprint
 
-    try:
-        text = compiled.as_text()
-    except Exception:  # noqa: BLE001 — introspection is best-effort
-        return None
+    text = hlo_text
+    if text is None:
+        try:
+            text = compiled.as_text()
+        except Exception:  # noqa: BLE001 — introspection is best-effort
+            return None
     rec: Dict[str, Any] = dict(collective_summary(text))
     # the HloModule header (first line) carries num_partitions; its
     # entry_computation_layout can run to tens of KBs for a UNet-sized

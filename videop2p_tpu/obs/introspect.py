@@ -34,6 +34,8 @@ from typing import Any, Dict, Optional
 
 import jax
 
+from videop2p_tpu.obs.spans import span
+
 __all__ = [
     "analyze_compiled",
     "analyze_jitted",
@@ -127,12 +129,21 @@ def _num(v) -> float:
     return int(f) if f == int(f) else f
 
 
-def analyze_compiled(compiled) -> Dict[str, Any]:
+def analyze_compiled(compiled, hlo_text: Optional[str] = None
+                     ) -> Dict[str, Any]:
     """Mine one ``jax.stages.Compiled`` executable into a flat record.
 
     Each constituent analysis is independently guarded: a backend that
     cannot produce one of them (e.g. no ``as_text`` on some plugin
     runtimes) yields a record missing those keys, not an exception.
+    ``hlo_text`` is ``compiled.as_text()`` where the caller already holds
+    it: printing a UNet-scale module takes seconds, so the ledger's
+    analysis prints it once for this record and the ``comm_analysis`` one.
+
+    Under an active ledger each part is a span (``analysis.cost``,
+    ``analysis.memory``, ``analysis.mine``: the regular expressions and the
+    hash), children of the open ``program.analysis`` beside the ledger's
+    own ``analysis.text`` and ``analysis.comm``.
 
     Conventions: flops/bytes are
     XLA's STATIC per-module counts — ``while``/``scan`` trip counts are
@@ -143,7 +154,8 @@ def analyze_compiled(compiled) -> Dict[str, Any]:
     """
     rec: Dict[str, Any] = {}
     try:
-        cost = compiled.cost_analysis()
+        with span("analysis.cost"):
+            cost = compiled.cost_analysis()
         if isinstance(cost, (list, tuple)):
             cost = cost[0] if cost else {}
         rec["flops"] = _num(cost.get("flops", 0.0))
@@ -152,7 +164,8 @@ def analyze_compiled(compiled) -> Dict[str, Any]:
     except Exception:  # noqa: BLE001 — introspection is best-effort
         pass
     try:
-        mem = compiled.memory_analysis()
+        with span("analysis.memory"):
+            mem = compiled.memory_analysis()
         arg = int(mem.argument_size_in_bytes)
         out = int(mem.output_size_in_bytes)
         tmp = int(mem.temp_size_in_bytes)
@@ -171,14 +184,15 @@ def analyze_compiled(compiled) -> Dict[str, Any]:
     except Exception:  # noqa: BLE001
         pass
     try:
-        text = compiled.as_text()
-        hist = instruction_histogram(text)
-        rec["hlo_fingerprint"] = hlo_fingerprint(text)
-        rec["hlo_instructions"] = sum(hist.values())
-        rec["hlo_histogram"] = hist
-        kernels = tpu_custom_call_counts(text)
-        if kernels:  # CPU records keep their pinned schema
-            rec["tpu_custom_calls"] = kernels
+        text = compiled.as_text() if hlo_text is None else hlo_text
+        with span("analysis.mine"):
+            hist = instruction_histogram(text)
+            rec["hlo_fingerprint"] = hlo_fingerprint(text)
+            rec["hlo_instructions"] = sum(hist.values())
+            rec["hlo_histogram"] = hist
+            kernels = tpu_custom_call_counts(text)
+            if kernels:  # CPU records keep their pinned schema
+                rec["tpu_custom_calls"] = kernels
     except Exception:  # noqa: BLE001
         pass
     return rec
@@ -188,25 +202,36 @@ def abstractify_args(args, kwargs):
     """Array leaves → ShapeDtypeStructs (so a later ``.lower()`` never
     touches possibly-donated/deleted buffers); everything else unchanged.
 
-    Multi-device leaves keep their sharding on the ShapeDtypeStruct, so
-    re-lowering builds the SAME partitioned SPMD program the call executed
-    — the property that makes the sharded ``program_analysis`` and
-    ``comm_analysis`` events honest. Single-device leaves stay
-    sharding-free (attaching a SingleDeviceSharding would churn the HLO
-    fingerprints every PR-3 baseline already pinned)."""
+    The abstraction must be EXACT: it carries everything of a leaf that jit
+    keys a trace and a lowering on, so that ``lower(...).compile()`` at the
+    abstract arguments is the signature of the call they were taken from
+    and is served by jax's own trace, lowering and executable caches — the
+    program the call built, not a second trace, lowering and load of it:
+
+      * ``weak_type`` (``jnp.asarray(0)``, the ``TrainState.step`` of every
+        tune, stays weak through a scan carry): part of the aval the trace
+        is keyed on;
+      * the sharding of a COMMITTED leaf, on one device or many: the call
+        lowers with it as the argument's ``in_sharding``, and a
+        ShapeDtypeStruct that has a sharding counts as committed. So a
+        sharded call analyses the partitioned SPMD program it executed
+        (the sharded ``program_analysis`` and ``comm_analysis`` events). An
+        uncommitted leaf lowers with no sharding, and gets none here.
+
+    Layouts are not carried: nothing in this package sets one."""
 
     def to_abstract(leaf):
+        if isinstance(leaf, jax.ShapeDtypeStruct):
+            return leaf
         if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
-            sharding = getattr(leaf, "sharding", None)
-            try:
-                multi = sharding is not None and len(sharding.device_set) > 1
-            except Exception:  # noqa: BLE001
-                multi = False
-            if multi:
-                return jax.ShapeDtypeStruct(
-                    leaf.shape, leaf.dtype, sharding=sharding
-                )
-            return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+            sharding = None
+            if (getattr(leaf, "_committed", True)
+                    and not isinstance(leaf, jax.core.Tracer)):
+                sharding = getattr(leaf, "sharding", None)
+            return jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=sharding,
+                weak_type=bool(getattr(leaf, "weak_type", False)),
+            )
         return leaf
 
     return (jax.tree.map(to_abstract, args),
@@ -219,9 +244,13 @@ def compile_abstract(jitted, *args, **kwargs):
 
     This is the ahead-of-time path (``jit(f).lower(...).compile()``) — the
     executable is built but NEVER executed, which is what makes the whole
-    analysis CPU-runnable while the accelerator is down. With a persistent
-    compilation cache active (both CLIs and bench enable one) the backend
-    compile behind an already-executed program is a cache hit.
+    analysis CPU-runnable while the accelerator is down. At the exact
+    signature of a call ``jitted`` has already served
+    (:func:`abstractify_args` of its arguments) nothing is built: jax
+    returns the trace, the lowering and the executable that call made, from
+    its own in-memory caches. At any other signature — one weak type or one
+    commitment off — it is a full trace, lowering and backend compile (a
+    load from the persistent cache where one is configured).
     """
     try:
         return jitted.lower(*args, **kwargs).compile()

@@ -64,9 +64,10 @@ _PROGRAM: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "videop2p_obs_program", default=None
 )
 
-# set while the AOT introspection compile runs: those backend-compile events
-# describe the ANALYSIS recompile (a persistent-cache hit in practice), not
-# the run's own work — recording them would double a run's compile totals
+# set while the analysis asks for the call's executable: a backend compile
+# fired there means jax did NOT hand back the call's build (`rebuilt`), and
+# that second compile is the analysis', not the run's own work — recording
+# it would double a run's compile totals
 _SUPPRESS_COMPILE: contextvars.ContextVar[bool] = contextvars.ContextVar(
     "videop2p_obs_suppress_compile", default=False
 )
@@ -87,9 +88,9 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_SEEN = threading.local()
 _LISTENER_INSTALLED = False
 
-# kill-switch for the automatic compiled-program introspection (the AOT
-# lower+compile behind every instrumented cache miss); the CLIs expose it
-# as --no_program_analysis
+# kill-switch for the automatic compiled-program introspection (the reading
+# of the executable behind every instrumented cache miss); the CLIs expose
+# it as --no_program_analysis
 _ANALYSIS_ENV = "VIDEOP2P_OBS_NO_ANALYSIS"
 
 
@@ -118,9 +119,9 @@ def program_label(name: str) -> Iterator[None]:
 
 @contextlib.contextmanager
 def suppress_compile_events() -> Iterator[None]:
-    """Compile events fired inside this block are NOT recorded — for AOT
-    introspection recompiles that would otherwise double a run's compile
-    totals (obs.introspect's program analyses)."""
+    """Compile events fired inside this block are NOT recorded — for an
+    analysis that had to build the program again (``rebuilt``), which would
+    otherwise double a run's compile totals."""
     token = _SUPPRESS_COMPILE.set(True)
     try:
         yield
@@ -149,7 +150,9 @@ def _install_compile_listener() -> None:
     ``cache_hit`` and ``cache_retrieval_s`` from the persistent cache's own
     events); fired with no label — every eager ``jnp`` op does — they are
     summed into ``unspanned_*`` counters of the open span, or of the ledger
-    where none is open (``run_end`` carries those), never one line each.
+    where none is open (``run_end`` carries those), never one line each. A
+    lowering or a backend compile fired under ``program.analysis`` sets its
+    ``rebuilt``: the analysis did not get the call's own build.
     jax has no per-listener unregister, so the listeners are permanent
     no-ops when no ledger is active rather than something we add/remove per
     run."""
@@ -180,6 +183,9 @@ def _install_compile_listener() -> None:
         if not led.tracer.enabled:
             return
         sink = _compile_sink()
+        if (sink is not None and sink.name == "program.analysis"
+                and name != "program.trace"):
+            sink.set(rebuilt=True)
         if program is not None and sink is not None:
             sink.child(name, time.time_ns() - int(duration * 1e9), duration,
                        **attrs)
@@ -595,20 +601,22 @@ class RunLedger:
 
 def _analyze_into_ledger(led: "RunLedger", jitted, program: str,
                          abstract_args, abstract_kwargs) -> None:
-    """Mine the program XLA just built into ``program_analysis`` (cost/
+    """Read the program the call just built into ``program_analysis`` (cost/
     memory analysis, HLO fingerprint, instruction histogram) and — for
     sharded programs — ``comm_analysis`` (collective counts/bytes and
     sharding specs, obs/comm.py) events.
 
-    Runs the AOT ``lower(...).compile()`` path on ABSTRACT arguments — the
-    executed call may have donated its buffers; sharded leaves keep their
-    shardings so the re-lowered module IS the partitioned SPMD program —
-    with compile-event recording suppressed (the recompile is a
-    persistent-cache hit wherever a cache is configured; either way it is
-    not the run's own compile work). A failed lower/compile emits a
-    ``program_analysis_skipped`` event with the reason instead of dropping
-    the record on the floor; nothing here ever breaks the call that
-    triggered it.
+    ``lower(...).compile()`` on ABSTRACT arguments (the executed call may
+    have donated its buffers) that are the call's exact signature
+    (``introspect.abstractify_args``: weak types, committed shardings), so
+    jax hands back the lowering and the executable the call made — the
+    program that RAN, sharded or not — and nothing is traced, lowered or
+    loaded a second time. Should it build all the same (the span's
+    ``rebuilt``), that compile stays out of the run's compile totals. The
+    module's text is printed once, for both records. A failed lower/compile
+    emits a ``program_analysis_skipped`` event with the reason instead of
+    dropping the record on the floor; nothing here ever breaks the call
+    that triggered it.
     """
     from videop2p_tpu.obs import comm, introspect
 
@@ -620,10 +628,16 @@ def _analyze_into_ledger(led: "RunLedger", jitted, program: str,
         led.event("program_analysis_skipped", program=program,
                   reason="lower_or_compile_failed")
         return
-    rec = introspect.analyze_compiled(compiled)
+    try:
+        with span("analysis.text"):
+            text = compiled.as_text()
+    except Exception:  # noqa: BLE001 — each reader then tries for itself
+        text = None
+    rec = introspect.analyze_compiled(compiled, hlo_text=text)
     if rec:
         led.program_analysis(program, rec)
-    comm_rec = comm.comm_analysis_record(compiled)
+    with span("analysis.comm"):
+        comm_rec = comm.comm_analysis_record(compiled, hlo_text=text)
     if comm_rec is not None and (
         comm_rec.get("num_partitions", 1) > 1
         or comm_rec.get("collective_count", 0)
@@ -644,14 +658,19 @@ def instrumented_jit(fun, *, program: str, analyze: bool = True,
     program label, whether the call MISSED the jit cache (compiled), and
     the dispatch wall-clock; compile events fired inside the call are
     attributed to the label. On a cache miss (with ``analyze=True``, the
-    default) the freshly-built executable is additionally mined into a
+    default) the executable that call built is read into a
     ``program_analysis`` event — XLA's cost/memory analysis, a stable
     optimized-HLO fingerprint, and an instruction histogram
     (obs/introspect.py) — which is what ``obs/history.py`` and
-    ``tools/obs_diff.py`` diff across runs. Sharded calls re-lower with
-    their shardings preserved, so the analysis describes the partitioned
-    SPMD program and additionally emits a ``comm_analysis`` event with
-    per-kind collective counts/bytes (obs/comm.py). When the analysis is
+    ``tools/obs_diff.py`` diff across runs. The program is built ONCE: the
+    analysis asks jax for the call's exact signature (the arguments'
+    abstract values, taken before the call deletes what it donates) and is
+    handed the call's own lowering and executable; ``program.analysis``
+    says ``rebuilt: false``, and ``true`` where a lowering or a backend
+    compile fired under it after all. A sharded call's signature carries
+    its shardings, so the analysis describes the partitioned SPMD program
+    and additionally emits a ``comm_analysis`` event with per-kind
+    collective counts/bytes (obs/comm.py). When the analysis is
     disabled or cannot run, a ``program_analysis_skipped`` event records
     the reason — a missing record is a statement, never silence. Disable
     process-wide with ``VIDEOP2P_OBS_NO_ANALYSIS=1`` (the CLIs'
@@ -727,7 +746,7 @@ def instrumented_jit(fun, *, program: str, analyze: bool = True,
             if miss:
                 if skip_reason is None:
                     try:
-                        with span("program.analysis"):
+                        with span("program.analysis", rebuilt=False):
                             _analyze_into_ledger(
                                 led, jitted, program, abs_args, abs_kwargs
                             )
