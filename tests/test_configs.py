@@ -82,6 +82,33 @@ def test_token_model_config_loads_and_builds_the_published_model():
     assert cfg["train_data"]["n_tokens"] == 16384
 
 
+def test_hybrid_token_model_config_loads_and_builds_the_published_model():
+    """The hybrid token model's YAML loads through ``load_config``, names a
+    known family, and its ``model`` dict is the published config.json at
+    this chip's share (ISSUE 32: 18 of 72 experts, 8 of 32 query heads, 32
+    of 128 Mamba heads, 1/4 of the vocabulary, one period of ``layer_types``;
+    no width cut)."""
+    from videop2p_tpu.cli.common import MODEL_FAMILIES, check_model_family
+    from videop2p_tpu.models.granite_hybrid import GraniteHybridConfig
+
+    cfg = load_config(os.path.join(
+        ROOT, "configs", "granite-4.0-h-small-s4-tune.yaml"))
+    assert check_model_family(cfg["model_family"]) == "granitemoehybrid"
+    assert cfg["model_family"] in MODEL_FAMILIES
+    model = GraniteHybridConfig.from_dict(cfg["model"])
+    model.check()
+    published = GraniteHybridConfig()
+    cut = {"num_hidden_layers": 10, "layer_types": published.layer_types[:10],
+           "vocab_size": 25088, "experts_held": (0, 18), "heads_held": (0, 8),
+           "mamba_heads_held": (0, 32)}
+    for f in inspect.signature(GraniteHybridConfig).parameters:
+        assert getattr(model, f) == cut.get(f, getattr(published, f)), f
+    assert list(cfg["trainable_modules"]) == ["q_proj", "in_proj_c"]
+    assert cfg["train_data"]["n_tokens"] == 32768
+    with pytest.raises(ValueError, match="unknown GraniteHybridConfig keys"):
+        GraniteHybridConfig.from_dict({**cfg["model"], "n_group": 8})
+
+
 def test_unknown_model_family_is_rejected_with_the_known_ones():
     from videop2p_tpu.cli.common import check_model_family
 
@@ -89,6 +116,7 @@ def test_unknown_model_family_is_rejected_with_the_known_ones():
         check_model_family("sdxl")
     assert "'sdxl'" in str(err.value)
     assert "unet3d" in str(err.value) and "deepseek_v32" in str(err.value)
+    assert "granitemoehybrid" in str(err.value)
     with pytest.raises(ValueError, match="known:"):
         tune_main(pretrained_model_path=None, output_dir="unused",
                   train_data={}, model_family="nope")

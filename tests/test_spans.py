@@ -14,6 +14,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from videop2p_tpu.obs import RunLedger, instrumented_jit, read_ledger
@@ -692,3 +693,81 @@ def test_the_benchmarks_readers_make_up_the_setup_on_a_tiny_main(
     assert [e["program"] for e in tiny_tune_ledger
             if e["event"] == "program_analysis"] == ["train_steps"]
     assert bench.host_between_calls_ms(ctx) > 0
+
+
+# --------------------------- the hybrid token model's scopes and counters ---
+
+
+def test_hybrid_token_model_main_emits_the_scopes_and_counters_read():
+    """What the hybrid cell's per-layer metrics read by NAME is one pair of
+    tuples (``models/granite_hybrid.SCOPES`` / ``COUNTERS``): every scope is
+    in the lowered loss, forward and backward, and a
+    tiny ``run_tuning.main`` logs every counter a step beside the loss and
+    emits ``tune.load_document`` under the root."""
+    import tempfile
+
+    from videop2p_tpu.cli import run_tuning
+    from videop2p_tpu.cli.common import load_config
+    from videop2p_tpu.models import granite_hybrid as gh
+
+    assert gh.SCOPES == ("lm.mamba_proj", "lm.ssd", "lm.attention",
+                         "lm.router", "lm.experts", "lm.shared_expert",
+                         "lm.head_loss")
+    assert gh.COUNTERS == ("expert_load_max_over_mean", "held_pair_share",
+                           "routed_over_shared", "ssd_state_rms")
+    cfg = gh.GraniteHybridConfig.tiny()
+    params = gh.abstract_params(cfg, jnp.float32)["params"]
+    ids = jax.ShapeDtypeStruct((32,), jnp.int32)
+    text = jax.jit(jax.grad(
+        lambda p, i: gh.forward_loss(p, cfg, i, jnp.float32)[0])).lower(
+            params, ids).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]*)"', text))
+    for scope in gh.SCOPES:
+        mine = [n for n in names if scope in n]
+        assert [n for n in mine if f"jvp({scope})" in n], scope   # forward
+        assert [n for n in mine if "transpose(" in n], scope      # backward
+
+    class WindowClosed(Exception):
+        pass
+
+    real_jit, calls = run_tuning.instrumented_jit, []
+
+    def counting_jit(fn, **kw):
+        prog = real_jit(fn, **kw)
+
+        def steps_fn(*args):
+            if len(calls) == 2:
+                raise WindowClosed()
+            calls.append(1)
+            return prog(*args)
+
+        return steps_fn
+
+    config = load_config(os.path.join(
+        _REPO, "configs", "granite-4.0-h-small-s4-tune.yaml"))
+    with tempfile.TemporaryDirectory() as out:
+        path = os.path.join(out, "ledger.jsonl")
+        config.update(output_dir=os.path.join(out, "run"),
+                      train_data={"n_tokens": 32, "document_seed": 1},
+                      max_train_steps=10 ** 6, steps_per_call=2, log_every=2,
+                      checkpointing_steps=0, validation_steps=0)
+        run_tuning.instrumented_jit = counting_jit
+        try:
+            with pytest.raises(WindowClosed):
+                run_tuning.main(**config, tiny=True, ledger=path)
+        finally:
+            run_tuning.instrumented_jit = real_jit
+            led = obs_ledger.current_ledger()
+            events = read_ledger(path)
+            if led is not None and led.path == path:
+                led.close()
+    spans = [e for e in events if e["event"] == "span"]
+    root = _one(spans, "tune.setup")
+    document = _one(spans, "tune.load_document")
+    assert document["parent_id"] == root["span_id"] and document["tokens"] == 32
+    metrics = [e for e in events if e["event"] == "metric"]
+    assert len(metrics) == 4  # two calls of two steps
+    for rec in metrics:
+        for name in gh.COUNTERS + ("train_loss",):
+            assert np.isfinite(rec[name]), (name, rec)
+    assert metrics[0]["ssd_state_rms"] > 0

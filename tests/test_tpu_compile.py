@@ -6,7 +6,8 @@ would refuse — a slice off the tiling, a kernel over its VMEM budget, a
 kernel that cannot be partitioned — which Pallas interpret mode on the CPU
 never shows. Each case compiles one kernel (or, for Stage-1 tuning, the
 forward / backward pair through ``jax.grad``) at an SD-1.5 shape — or the
-token model's selected-key attention pair at its cell's shape — bare or
+token models' attention pair at their cells' shapes, with its selection
+operand and without — bare or
 under ``jax.shard_map`` on a four-device mesh with the specs
 ``parallel/mesh.py`` uses, and asserts the kernel is IN the compiled text
 (``tpu_custom_call``). A compile that passes is not a run: nothing executes,
@@ -32,6 +33,7 @@ from videop2p_tpu.obs.introspect import tpu_custom_call_counts
 from videop2p_tpu.ops.attention import fused_bwd_block, fused_frame_attention
 from videop2p_tpu.ops.groupnorm import fits_fused_group_norm, fused_group_norm
 from videop2p_tpu.ops.selected_attention import (
+    causal_attention,
     selected_attention_tiles,
     selected_key_attention,
 )
@@ -50,6 +52,10 @@ TUNE_ATTENTION_SHAPES = [(1, 8, 8, 4096, 40), (1, 8, 8, 1024, 80),
 # 128, models/deepseek.py): the cell's document, and a quarter of it, where
 # the backward's resident dQ lets all eight heads into one cell
 SELECTED_TOKENS = [16384, 4096]
+# tokens of the hybrid token model's attention layer (8 query heads on 2 key /
+# value heads of 128, models/granite_hybrid.py): the same pair with no
+# selection operand, at the cell's document and at an eighth of it
+CAUSAL_TOKENS = [32768, 4096]
 # (rows, C) slab classes of the SD-1.5 GroupNorm sites the kernel covers
 GROUP_NORM_SLABS = [(4096, 320), (1024, 640), (256, 1280), (512, 1280)]
 
@@ -170,6 +176,33 @@ def test_selected_key_attention_compiles(one_chip, t_len, grad):
     else:
         kernels = _kernels(fn, *_selected_attention_shapes(t_len, one_chip))
         assert kernels == {"lm_selected_attention": 1}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+@pytest.mark.parametrize("t_len", CAUSAL_TOKENS)
+def test_causal_attention_compiles(one_chip, t_len, grad):
+    """The pair WITHOUT its selection operand (the causal rule from the
+    tile's position) at the hybrid cell's shape — 32768 tokens, where the
+    backward's resident dQ lets two heads into a cell — forward alone and
+    through ``jax.grad`` of q, k and v."""
+    assert selected_attention_tiles(t_len, 8, 128, 0, 128, jnp.bfloat16) is not None
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((t_len, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def fn(q, k, v):
+        return causal_attention(q, k, v, 0.0078125)
+
+    if grad:
+        kernels = _kernels(
+            jax.grad(lambda *ops: jnp.sum(fn(*ops).astype(jnp.float32) ** 2),
+                     argnums=(0, 1, 2)), arg(8), arg(2), arg(2))
+        assert kernels == {"lm_selected_attention": 1,
+                           "lm_selected_attention_bwd": 1}
+    else:
+        assert _kernels(fn, arg(8), arg(2), arg(2)) == {
+            "lm_selected_attention": 1}
 
 
 @pytest.mark.parametrize("act", ["none", "silu"])

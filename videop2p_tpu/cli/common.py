@@ -581,7 +581,7 @@ def build_models(
 
 # the tune config's ``model_family``: which model Stage 1 builds and which
 # loss it steps on (``tiny`` picks a size inside a family, not a family)
-MODEL_FAMILIES = ("unet3d", "deepseek_v32")
+MODEL_FAMILIES = ("unet3d", "deepseek_v32", "granitemoehybrid")
 
 
 def check_model_family(name: str) -> str:
@@ -603,34 +603,43 @@ class TokenModelBundle:
     loss_fn: Any
 
 
+def _token_families() -> Dict[str, Any]:
+    """``model_family`` → (module, configuration class) of the token models."""
+    from videop2p_tpu.models import deepseek, granite_hybrid
+
+    return {"deepseek_v32": (deepseek, deepseek.DeepSeekV32Config),
+            "granitemoehybrid": (granite_hybrid,
+                                 granite_hybrid.GraniteHybridConfig)}
+
+
 def build_token_model(
     model: Optional[Dict[str, Any]],
     *,
+    model_family: str,
     dtype: jnp.dtype = jnp.bfloat16,
     gradient_checkpointing: bool = True,
     tiny: bool = False,
     seed: int = 0,
 ) -> TokenModelBundle:
-    """The ``deepseek_v32`` family from the tune config's ``model`` dict
-    (``config.json`` keys plus the chip's share, ``models/deepseek.py``),
-    with seeded random weights in the checkpoint's dtype (bfloat16): no
-    checkpoint of this family ships, and no loader for one is built."""
-    from videop2p_tpu.models import deepseek
-
+    """A token family (``models/deepseek.py``, ``models/granite_hybrid.py``)
+    from the tune config's ``model`` dict — ``config.json`` keys plus the
+    chip's share, unknown keys an error — with seeded random weights in the
+    checkpoint's dtype (bfloat16): no checkpoint of these families ships,
+    and no loader for one is built."""
+    module, config_cls = _token_families()[model_family]
     model = dict(model or {})
     choices = bool(model.pop("hand_out_choices", False))
-    cfg = (deepseek.DeepSeekV32Config.tiny() if tiny
-           else deepseek.DeepSeekV32Config.from_dict(model))
+    cfg = config_cls.tiny() if tiny else config_cls.from_dict(model)
     cfg = dataclasses.replace(cfg, remat=bool(gradient_checkpointing),
                               hand_out_choices=choices)
-    with span("models.init_or_load", model="deepseek_v32"):
+    with span("models.init_or_load", model=model_family):
         params = jax.jit(
-            lambda key: deepseek.init_params(key, cfg)
+            lambda key: module.init_params(key, cfg)
         )(jax.random.key(seed))["params"]
     return TokenModelBundle(
         config=cfg,
         params=params,
-        loss_fn=lambda p, ids: deepseek.forward_loss(p, cfg, ids, dtype),
+        loss_fn=lambda p, ids: module.forward_loss(p, cfg, ids, dtype),
     )
 
 

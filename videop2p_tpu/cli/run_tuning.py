@@ -200,14 +200,14 @@ def _setup_unet(
 
 
 def _setup_token_model(
-    model, train_data, tx, tune_cfg, *, dtype, gradient_checkpointing, tiny,
-    seed, telemetry,
+    model_family, model, train_data, tx, tune_cfg, *, dtype,
+    gradient_checkpointing, tiny, seed, telemetry,
 ) -> _Tuned:
     """A token model on one document of token ids: no VAE, no text encoder,
     no validation edit; ``loss_steps`` on the next-token loss."""
     with span("tune.build_models"):
         bundle = build_token_model(
-            model, dtype=dtype,
+            model, model_family=model_family, dtype=dtype,
             gradient_checkpointing=gradient_checkpointing, tiny=tiny,
             seed=seed or 0,
         )
@@ -289,7 +289,8 @@ def main(
     mesh: Optional[str] = None,
     # which model Stage 1 tunes (cli/common.MODEL_FAMILIES): the inflated
     # video UNet on a clip, or a token model on a document of token ids —
-    # ``model`` is that family's configuration (models/deepseek.py). The token
+    # ``model`` is that family's configuration (models/deepseek.py,
+    # models/granite_hybrid.py). The token
     # model has no VAE, no text encoder and no validation edit.
     model_family: str = "unet3d",
     model: Optional[Dict[str, Any]] = None,
@@ -330,8 +331,8 @@ def main(
     validation_data = validation_data or {}
     if mesh and not unet_family:
         raise NotImplementedError(
-            "model_family 'deepseek_v32' runs one chip's share without any "
-            "exchange between chips: no mesh path is built for it yet"
+            f"model_family {model_family!r} runs one chip's share without "
+            "any exchange between chips: no mesh path is built for it yet"
         )
     n_frames = int(train_data.get("n_sample_frames", 8))
     output_dir = output_dir + dependent_suffix(
@@ -399,7 +400,7 @@ def main(
             )
         else:
             tuned = _setup_token_model(
-                model, train_data, tx, tune_cfg, dtype=dtype,
+                model_family, model, train_data, tx, tune_cfg, dtype=dtype,
                 gradient_checkpointing=gradient_checkpointing, tiny=tiny,
                 seed=seed, telemetry=telemetry,
             )

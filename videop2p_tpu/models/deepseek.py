@@ -71,7 +71,9 @@ __all__ = [
     "select_keys",
     "route",
     "select_experts",
+    "held_expert_ffn",
     "expert_ffn",
+    "head_loss",
     "forward_loss",
     "forward_logits",
 ]
@@ -255,24 +257,34 @@ def abstract_params(cfg: DeepSeekV32Config, dtype=jnp.bfloat16):
                         param_shapes(cfg), is_leaf=_is_spec)
 
 
-def init_params(key: jax.Array, cfg: DeepSeekV32Config, dtype=jnp.bfloat16):
-    """Seeded random weights in the checkpoint's dtype (every leaf bfloat16
-    as published): kernels N(0, 1/fan_in), norm scales 1 + N(0, 0.05^2),
-    embedding and the rest N(0, 0.02^2). Meant to run under one ``jax.jit``."""
-    specs = param_shapes(cfg)
+def seeded_leaf(name: str, z, fan_in):
+    """A leaf's seeded value from standard normal draws ``z`` of its shape,
+    by its last name: kernels N(0, 1/fan_in), norm scales 1 + N(0, 0.05^2),
+    embedding and the rest N(0, 0.02^2)."""
+    if name == "kernel":
+        return z * (1.0 / math.sqrt(fan_in))
+    if name == "scale":
+        return 1.0 + 0.05 * z
+    return 0.02 * z
+
+
+def seeded_params(key: jax.Array, specs, dtype, leaf=seeded_leaf):
+    """``specs`` (a tree of ``(shape, fan_in)``) filled leaf by leaf with
+    ``leaf(last name, normal draws, fan_in)`` in ``dtype``, leaf ``i`` from
+    ``fold_in(key, i)``. Meant to run under one ``jax.jit``."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(specs, is_leaf=_is_spec)
     leaves = []
     for i, (path, (shape, fan_in)) in enumerate(flat):
         z = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
         last = str(getattr(path[-1], "key", path[-1]))
-        if last == "kernel":
-            v = z * (1.0 / math.sqrt(fan_in))
-        elif last == "scale":
-            v = 1.0 + 0.05 * z
-        else:
-            v = 0.02 * z
-        leaves.append(v.astype(dtype))
+        leaves.append(leaf(last, z, fan_in).astype(dtype))
     return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def init_params(key: jax.Array, cfg: DeepSeekV32Config, dtype=jnp.bfloat16):
+    """Seeded random weights in the checkpoint's dtype (every leaf bfloat16
+    as published), :func:`seeded_leaf` leaf by leaf."""
+    return seeded_params(key, param_shapes(cfg), dtype)
 
 
 # ------------------------------------------------------------ small pieces
@@ -602,15 +614,19 @@ def _grouped_bwd(block, res, dy):
 _grouped_swiglu.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def expert_ffn(p, cfg: DeepSeekV32Config, x):
-    """``(routed part, shared expert, counters, experts chosen)`` of one
-    expert layer on the normed input ``x`` (T, h): routing over all experts,
-    the held experts' part of the sum, and the shared expert."""
-    t_len = x.shape[0]
-    e0, en = cfg.experts_held
-    k, block = cfg.num_experts_per_tok, EXPERT_BLOCK
+def held_expert_ffn(p, x, experts, gates, held: Tuple[int, int]):
+    """``(routed part, shared expert, counters)`` of one expert layer on the
+    normed input ``x`` (T, h), GIVEN the routing: ``experts`` (T, K) int32
+    over ALL routed experts and their ``gates`` (T, K) float32. ``held`` is
+    the ``(first, count)`` range of experts whose stacked matrices
+    ``p["experts"]`` holds: the rows routed to them are sorted by expert and
+    cut into ``EXPERT_BLOCK``-row blocks of one expert each. ``p["shared"]``
+    runs on every token. Every token family's expert layer calls this with
+    its own router's ``(experts, gates)``."""
+    t_len, k = experts.shape
+    e0, en = held
+    block = EXPERT_BLOCK
     with jax.named_scope("lm.router"):
-        experts, gates = route(p["router"], cfg, x)
         local = experts.reshape(-1) - e0
         key = jnp.where((local >= 0) & (local < en), local, en)
         order = jnp.argsort(key, stable=True)
@@ -634,17 +650,29 @@ def expert_ffn(p, cfg: DeepSeekV32Config, x):
                                  ex["down_proj"]["kernel"], block)
     with jax.named_scope("lm.shared_expert"):
         shared = _swiglu(p["shared"], x)
-    held = jnp.sum(count).astype(jnp.float32)
+    held_pairs = jnp.sum(count).astype(jnp.float32)
     square = lambda a: jnp.sum(jnp.square(a.astype(jnp.float32)))  # noqa: E731
     counters = {
         # tokens to the busiest held expert over the mean over held experts
-        "expert_load_max_over_mean": jnp.max(count) * en / jnp.maximum(held, 1.0),
+        "expert_load_max_over_mean":
+            jnp.max(count) * en / jnp.maximum(held_pairs, 1.0),
         # share of routed (token, expert) pairs that land on held experts
-        "held_pair_share": held / (t_len * k),
+        "held_pair_share": held_pairs / (t_len * k),
         # the held experts' part over the shared expert's, root mean square:
         # it carries the gates' scale whatever tokens were chosen
         "routed_over_shared": jnp.sqrt(square(routed) / square(shared)),
     }
+    return routed, shared, counters
+
+
+def expert_ffn(p, cfg: DeepSeekV32Config, x):
+    """``(routed part, shared expert, counters, experts chosen)`` of one
+    expert layer on the normed input ``x`` (T, h): routing over all experts,
+    the held experts' part of the sum, and the shared expert."""
+    with jax.named_scope("lm.router"):
+        experts, gates = route(p["router"], cfg, x)
+    routed, shared, counters = held_expert_ffn(p, x, experts, gates,
+                                               cfg.experts_held)
     return routed, shared, counters, experts
 
 
@@ -676,27 +704,32 @@ def _layer_mask(p, cfg, x, angles):
     return select_keys(a["indexer"], cfg, x, c_q, angles)
 
 
-def _head_loss(params, cfg, x, ids):
-    """Mean next-token cross-entropy in float32, in chunks of tokens."""
+def head_loss(norm_scale, matrix, x, ids, eps, *, tied: bool = False,
+              logit_scale: float = 1.0):
+    """Mean next-token cross-entropy in float32, in chunks of tokens:
+    logits = RMSNorm(x) W * ``logit_scale``, W = ``matrix`` (h, V), or its
+    transpose where the head is ``tied`` to the embedding (V, h)."""
     t_len = x.shape[0]
     lc = min(LOSS_CHUNK, t_len)
     assert t_len % lc == 0, (t_len, lc)
     target = jnp.concatenate([ids[1:], ids[:1]])
     weight = (jnp.arange(t_len) < t_len - 1).astype(jnp.float32)
+    contract = (((1,), (1 if tied else 0,)), ((), ()))
 
     @jax.checkpoint
     def chunk(scale, kernel, xs, ts, ws):
-        y = _rms_norm(xs, scale, cfg.rms_norm_eps)
-        logits = jnp.matmul(y, kernel.astype(y.dtype),
-                            preferred_element_type=jnp.float32)
+        y = _rms_norm(xs, scale, eps)
+        logits = lax.dot_general(y, kernel.astype(y.dtype), contract,
+                                 preferred_element_type=jnp.float32)
+        if logit_scale != 1.0:
+            logits = logits * logit_scale
         nll = (jax.nn.logsumexp(logits, axis=-1)
                - jnp.take_along_axis(logits, ts[:, None], axis=-1)[:, 0])
         return jnp.sum(nll * ws)
 
     with jax.named_scope("lm.head_loss"):
-        total = sum(chunk(params["final_norm"]["scale"],
-                          params["head"]["kernel"], x[i:i + lc],
-                          target[i:i + lc], weight[i:i + lc])
+        total = sum(chunk(norm_scale, matrix, x[i:i + lc], target[i:i + lc],
+                          weight[i:i + lc])
                     for i in range(0, t_len, lc))
         return total / (t_len - 1)
 
@@ -739,7 +772,8 @@ def forward_loss(params, cfg: DeepSeekV32Config, ids, dtype=jnp.bfloat16):
     eight keys a byte; ``experts`` (T, K) and ``routed_over_shared`` (both
     None in a dense layer)."""
     x, aux = _forward(params, cfg, ids, dtype)
-    return _head_loss(params, cfg, x, ids), aux
+    return head_loss(params["final_norm"]["scale"], params["head"]["kernel"],
+                     x, ids, cfg.rms_norm_eps), aux
 
 
 def forward_logits(params, cfg: DeepSeekV32Config, ids, dtype=jnp.bfloat16):

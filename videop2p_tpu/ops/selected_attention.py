@@ -23,6 +23,11 @@ statistics are (1, queries) rows. The grid's second axis walks only the
 scalar-prefetched tables), so no step is spent on a skipped tile, and the
 selection tile — one byte a pair — is read once for all the heads of a cell.
 
+The selection is optional: ``causal_attention`` (grouped-query heads, no
+mask operand) runs the same two kernels with the causal rule taken from the
+tile's own position — the other token family's attention layer, under the
+scope ``lm.attention``.
+
 Precision as ``models.deepseek._attend``: float32 scores, statistics, dS and
 accumulators; the operands' dtype only as matmul operands. No key is
 dropped: the mask is consumed as handed over, but it must be causal
@@ -40,7 +45,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-__all__ = ["selected_key_attention", "selected_attention_tiles", "Tiles"]
+__all__ = ["selected_key_attention", "causal_attention",
+           "selected_attention_tiles", "Tiles"]
 
 _SCOPE = "lm.sparse_attention"
 
@@ -137,14 +143,27 @@ def _causal_steps(t_len: int, bq: int, bk: int, key_major: bool = False):
     return qi, ki
 
 
-def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, vt_ref, mask_ref, ot_ref, lse_ref,
-                m_ref, l_ref, acc_ref, *, scale: float, bq: int, bk: int):
+def _keep(mask_ref, qi, ki, bq: int, bk: int):
+    """(bk, bq) bool: the keys of tile ``ki`` each query of tile ``qi``
+    attends — the selection tile as handed over, or, with no selection, the
+    causal rule from the tile's position."""
+    if mask_ref is not None:
+        return mask_ref[...].astype(jnp.int32) != 0
+    keys = ki * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+    queries = qi * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+    return keys <= queries
+
+
+def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, vt_ref, *refs, scale: float,
+                bq: int, bk: int, masked: bool):
     """One (query tile, key tile) of every head of the cell: the online
     softmax's update of the running max ``m``, sum ``l`` and output
     ``acc`` (transposed, (v_dim, queries)); the last key tile of a query
     tile writes ``o`` and the log-sum-exp."""
     from jax.experimental import pallas as pl
 
+    mask_ref = refs[0] if masked else None
+    ot_ref, lse_ref, m_ref, l_ref, acc_ref = refs[int(masked):]
     step = pl.program_id(1)
     qi, ki = qi_ref[step], ki_ref[step]
 
@@ -154,7 +173,7 @@ def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, vt_ref, mask_ref, ot_ref, lse_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    keep = mask_ref[...].astype(jnp.int32) != 0  # (bk, bq)
+    keep = _keep(mask_ref, qi, ki, bq, bk)  # (bk, bq)
     for h in range(q_ref.shape[0]):
         st = lax.dot_general(k_ref[h], q_ref[h], _NT,
                              preferred_element_type=jnp.float32) * scale
@@ -178,8 +197,9 @@ def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, vt_ref, mask_ref, ot_ref, lse_ref,
 
 
 def _forward_call(q, k, v, mask_t, tiles: Tiles, scale: float, interpret: bool):
-    """q, k (H, T, qk); v (H, T, v_dim); mask_t (T keys, T queries) int8 →
-    oᵀ (H, v_dim, T) in ``v``'s dtype, log-sum-exp (H, 1, T) float32."""
+    """q, k (H, T, qk); v (H, T, v_dim); mask_t (T keys, T queries) int8, or
+    None (causal) → oᵀ (H, v_dim, T) in ``v``'s dtype, log-sum-exp (H, 1, T)
+    float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -187,8 +207,14 @@ def _forward_call(q, k, v, mask_t, tiles: Tiles, scale: float, interpret: bool):
     vd = v.shape[2]
     bq, bk, hb, _ = tiles
     qi, ki = _causal_steps(t_len, bq, bk)
+    masked = mask_t is not None
+    # the selection tile and its operand: there, or left out of the call
+    mask_spec = [pl.BlockSpec((bk, bq), lambda g, s, qi, ki: (ki[s], qi[s]))
+                 ] * masked
+    mask_operand = [mask_t] * masked
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk),
+        functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk,
+                          masked=masked),
         # explicit name: the compiled program's custom call and the trace
         # events carry it (obs/introspect.tpu_custom_call_counts)
         name="lm_selected_attention",
@@ -201,7 +227,7 @@ def _forward_call(q, k, v, mask_t, tiles: Tiles, scale: float, interpret: bool):
                 pl.BlockSpec((hb, bq, qk), lambda g, s, qi, ki: (g, qi[s], 0)),
                 pl.BlockSpec((hb, bk, qk), lambda g, s, qi, ki: (g, ki[s], 0)),
                 pl.BlockSpec((hb, vd, bk), lambda g, s, qi, ki: (g, 0, ki[s])),
-                pl.BlockSpec((bk, bq), lambda g, s, qi, ki: (ki[s], qi[s])),
+                *mask_spec,
             ],
             # constant over a query tile's key tiles: written back once
             out_specs=(
@@ -217,12 +243,13 @@ def _forward_call(q, k, v, mask_t, tiles: Tiles, scale: float, interpret: bool):
             vmem_limit_bytes=_fwd_vmem_bytes(hb, bq, bk, qk, vd, q.dtype.itemsize),
         ),
         interpret=interpret,  # CPU-testable (tests/test_selected_attention.py)
-    )(jnp.asarray(qi), jnp.asarray(ki), q, k, v.transpose(0, 2, 1), mask_t)
+    )(jnp.asarray(qi), jnp.asarray(ki), q, k, v.transpose(0, 2, 1),
+      *mask_operand)
 
 
 def _bwd_kernel(qi_ref, ki_ref, q_ref, do_ref, k_ref, kt_ref, v_ref, lse_ref,
-                delta_ref, mask_ref, dqt_ref, dk_ref, dv_ref, *,
-                scale: float, bq: int, bk: int):
+                delta_ref, *refs, scale: float, bq: int, bk: int,
+                masked: bool):
     """One (key tile, query tile) of every head of the cell: Pᵀ from the saved
     log-sum-exp, dSᵀ = Pᵀ ∘ (dPᵀ − delta), and its share of dV, dK (their
     blocks stay resident over the key tile's query tiles) and dQᵀ (its block
@@ -230,6 +257,8 @@ def _bwd_kernel(qi_ref, ki_ref, q_ref, do_ref, k_ref, kt_ref, v_ref, lse_ref,
     the caller's."""
     from jax.experimental import pallas as pl
 
+    mask_ref = refs[0] if masked else None
+    dqt_ref, dk_ref, dv_ref = refs[int(masked):]
     step = pl.program_id(1)
     qi, ki = qi_ref[step], ki_ref[step]
 
@@ -242,7 +271,7 @@ def _bwd_kernel(qi_ref, ki_ref, q_ref, do_ref, k_ref, kt_ref, v_ref, lse_ref,
         dk_ref[...] = jnp.zeros_like(dk_ref)
         dv_ref[...] = jnp.zeros_like(dv_ref)
 
-    keep = mask_ref[...].astype(jnp.int32) != 0  # (bk, bq)
+    keep = _keep(mask_ref, qi, ki, bq, bk)  # (bk, bq)
     for h in range(q_ref.shape[0]):
         q, do = q_ref[h], do_ref[h]  # (bq, qk), (bq, v_dim)
         st = lax.dot_general(k_ref[h], q, _NT,
@@ -262,7 +291,8 @@ def _bwd_kernel(qi_ref, ki_ref, q_ref, do_ref, k_ref, kt_ref, v_ref, lse_ref,
 
 def _backward_call(q, do, k, v, lse, delta, mask_t, tiles: Tiles,
                    scale: float, interpret: bool):
-    """q, k (H, T, qk); do, v (H, T, v_dim); lse, delta (H, 1, T) →
+    """q, k (H, T, qk); do, v (H, T, v_dim); lse, delta (H, 1, T); mask_t as
+    the forward's →
     dQᵀ (H, T / bq, qk, bq), dK (H, T, qk), dV (H, T, v_dim), float32 and
     unscaled (the caller scales and rounds them)."""
     from jax.experimental import pallas as pl
@@ -280,8 +310,14 @@ def _backward_call(q, do, k, v, lse, delta, mask_t, tiles: Tiles,
         return pl.BlockSpec((hb, bk, width), lambda g, s, qi, ki: (g, ki[s], 0))
 
     stat = pl.BlockSpec((hb, 1, bq), lambda g, s, qi, ki: (g, 0, qi[s]))
+    masked = mask_t is not None
+    # the selection tile and its operand: there, or left out of the call
+    mask_spec = [pl.BlockSpec((bk, bq), lambda g, s, qi, ki: (ki[s], qi[s]))
+                 ] * masked
+    mask_operand = [mask_t] * masked
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=scale, bq=bq, bk=bk),
+        functools.partial(_bwd_kernel, scale=scale, bq=bq, bk=bk,
+                          masked=masked),
         name="lm_selected_attention_bwd",
         out_shape=(
             jax.ShapeDtypeStruct((heads, t_len // bq, qk, bq), jnp.float32),
@@ -294,8 +330,7 @@ def _backward_call(q, do, k, v, lse, delta, mask_t, tiles: Tiles,
             in_specs=[
                 rows_q(qk), rows_q(vd), rows_k(qk),
                 pl.BlockSpec((hb, qk, bk), lambda g, s, qi, ki: (g, 0, ki[s])),
-                rows_k(vd), stat, stat,
-                pl.BlockSpec((bk, bq), lambda g, s, qi, ki: (ki[s], qi[s])),
+                rows_k(vd), stat, stat, *mask_spec,
             ],
             out_specs=(
                 # constant along the step axis: one resident accumulator of
@@ -313,7 +348,7 @@ def _backward_call(q, do, k, v, lse, delta, mask_t, tiles: Tiles,
         ),
         interpret=interpret,
     )(jnp.asarray(qi), jnp.asarray(ki), q, do, k, k.transpose(0, 2, 1), v,
-      lse, delta, mask_t)
+      lse, delta, *mask_operand)
 
 
 def _tiles_of(q_nope, q_rope, v) -> Tiles:
@@ -383,3 +418,71 @@ def _attention_bwd(scale, interpret, res, g):
 
 
 selected_key_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+# ------------------------------------------------- no selection: causal, GQA
+
+_CAUSAL_SCOPE = "lm.attention"
+
+
+def _causal_tiles(q) -> Tiles:
+    t_len, heads, width = q.shape
+    tiles = selected_attention_tiles(t_len, heads, width, 0, width, q.dtype)
+    if tiles is None:
+        raise ValueError(
+            f"causal_attention does not apply to q {q.shape}: ask "
+            "selected_attention_tiles first and keep the XLA path where it "
+            "returns None")
+    return tiles
+
+
+def _grouped_heads_first(q, k, v):
+    """(T, H, D) queries and (T, H_kv, D) keys / values → (H, T, D) each,
+    every key / value head handed to the H / H_kv query heads it serves."""
+    group = q.shape[1] // k.shape[1]
+    spread = lambda a: jnp.repeat(a.transpose(1, 0, 2), group, axis=0)  # noqa: E731
+    return q.transpose(1, 0, 2), spread(k), spread(v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def causal_attention(q, k, v, scale: float, interpret: bool = False):
+    """Causal softmax attention with grouped-query heads and NO selection:
+    ``q`` (T, H, D), ``k``, ``v`` (T, H_kv, D) with H a multiple of H_kv
+    (query head ``i`` reads key / value head ``i // (H / H_kv)``) → (T, H,
+    D). The kernel pair above without its mask operand; differentiable in
+    all three. Raises where :func:`selected_attention_tiles` refuses the
+    shape (asked as ``(T, H, D, 0, D)``)."""
+    return _causal_fwd(q, k, v, scale, interpret)[0]
+
+
+def _causal_fwd(q, k, v, scale, interpret):
+    tiles = _causal_tiles(q)
+    with jax.named_scope(_CAUSAL_SCOPE):
+        qh, kh, vh = _grouped_heads_first(q, k, v)
+        ot, lse = _forward_call(qh, kh, vh, None, tiles, scale, interpret)
+        o = ot.transpose(2, 0, 1)
+    return o, (q, k, v, o, lse)
+
+
+def _causal_bwd(scale, interpret, res, g):
+    q, k, v, o, lse = res
+    tiles = _causal_tiles(q)
+    t_len, heads, width = q.shape
+    kv_heads = k.shape[1]
+    with jax.named_scope(_CAUSAL_SCOPE):
+        qh, kh, vh = _grouped_heads_first(q, k, v)
+        delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
+        dqt, dk, dv = _backward_call(
+            qh, g.transpose(1, 0, 2), kh, vh, lse, delta.T[:, None, :], None,
+            tiles, scale, interpret)
+        dq = dqt.transpose(1, 3, 0, 2).reshape(t_len, heads, width) * scale
+
+        def gathered(d):  # (H, T, D) → (T, H_kv, D): a group's heads summed
+            return jnp.sum(d.reshape(kv_heads, heads // kv_heads, t_len, width),
+                           axis=1).transpose(1, 0, 2)
+
+        return (dq.astype(q.dtype), (gathered(dk) * scale).astype(k.dtype),
+                gathered(dv).astype(v.dtype))
+
+
+causal_attention.defvjp(_causal_fwd, _causal_bwd)
