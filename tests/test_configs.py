@@ -109,6 +109,33 @@ def test_hybrid_token_model_config_loads_and_builds_the_published_model():
         GraniteHybridConfig.from_dict({**cfg["model"], "n_group": 8})
 
 
+def test_third_token_family_config_loads_and_builds_the_published_model():
+    """Command A+'s YAML loads through ``load_config``, names a known family,
+    and its ``model`` dict is the published config.json at this chip's share
+    (ISSUE 34: 16 of 128 experts, 16 of 128 query heads on one key / value
+    head, 2048 of the 16384 shared-expert columns, 1/8 of the vocabulary, one
+    period of ``layer_types``; no width cut)."""
+    from videop2p_tpu.cli.common import MODEL_FAMILIES, check_model_family
+    from videop2p_tpu.models.cohere2_moe import Cohere2MoeConfig
+
+    cfg = load_config(os.path.join(ROOT, "configs", "command-a-plus-s8-tune.yaml"))
+    assert check_model_family(cfg["model_family"]) == "cohere2_moe"
+    assert cfg["model_family"] in MODEL_FAMILIES
+    model = Cohere2MoeConfig.from_dict(cfg["model"])
+    model.check()
+    published = Cohere2MoeConfig()
+    cut = {"num_hidden_layers": 4, "layer_types": published.layer_types[:4],
+           "vocab_size": 32768, "experts_held": (0, 16), "heads_held": (0, 16),
+           "shared_columns_held": (0, 2048)}
+    for f in inspect.signature(Cohere2MoeConfig).parameters:
+        assert getattr(model, f) == cut.get(f, getattr(published, f)), f
+    assert model.kv_heads_held == (0, 1)
+    assert list(cfg["trainable_modules"]) == ["q_proj"]
+    assert cfg["train_data"]["n_tokens"] == 32768
+    with pytest.raises(ValueError, match="unknown Cohere2MoeConfig keys"):
+        Cohere2MoeConfig.from_dict({**cfg["model"], "n_group": 8})
+
+
 def test_unknown_model_family_is_rejected_with_the_known_ones():
     from videop2p_tpu.cli.common import check_model_family
 
@@ -117,6 +144,7 @@ def test_unknown_model_family_is_rejected_with_the_known_ones():
     assert "'sdxl'" in str(err.value)
     assert "unet3d" in str(err.value) and "deepseek_v32" in str(err.value)
     assert "granitemoehybrid" in str(err.value)
+    assert "cohere2_moe" in str(err.value)
     with pytest.raises(ValueError, match="known:"):
         tune_main(pretrained_model_path=None, output_dir="unused",
                   train_data={}, model_family="nope")

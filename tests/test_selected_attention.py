@@ -152,3 +152,104 @@ def test_fit_test_refuses(why, args):
                 z(t_len, heads, nope), z(t_len, heads, rope),
                 z(t_len, heads, nope), z(t_len, rope), z(t_len, heads, v_dim),
                 jnp.ones((t_len, t_len), bool), SCALE, True)
+
+
+# ------------------------------------------------ no selection: causal, a band
+
+WINDOW_CASES = {
+    # tokens, (query heads, key / value heads), tiles, heads a cell, window
+    "window_under_a_tile": (384, (2, 2), (128, 128), (2, 1), 50),
+    "window_a_multiple_of_the_tile": (512, (2, 1), (128, 128), (2, 1), 256),
+    "window_not_a_multiple": (512, (4, 2), (128, 128), (2, 1), 200),
+    "query_tile_wider_than_key_tile": (768, (2, 1), (384, 128), (1,), 300),
+    "window_reaches_every_key": (384, (2, 1), (128, 128), (2, 1), 384),
+    "grouped_heads_16_to_1": (256, (16, 1), (128, 128), (8, 4, 2, 1), 130),
+}
+
+
+def _banded_dense(q, k, v, scale, window):
+    """Masked dense XLA, the whole (T, T) score matrix: the oracle."""
+    t_len, group = q.shape[0], q.shape[1] // k.shape[1]
+    pos = jnp.arange(t_len)
+    seen = (pos[None, :] <= pos[:, None]) & (pos[None, :] > pos[:, None] - window)
+    s = jnp.einsum("qhd,khd->hqk", q, jnp.repeat(k, group, axis=1),
+                   preferred_element_type=jnp.float32)
+    prob = jax.nn.softmax(jnp.where(seen[None], s * scale, -jnp.inf), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", prob, jnp.repeat(v, group, axis=1))
+
+
+@pytest.mark.parametrize("case", WINDOW_CASES)
+def test_windowed_pair_equals_the_xla_path(monkeypatch, case):
+    """``causal_attention`` with a window, in interpret mode, against masked
+    dense XLA and against the row-blocked XLA path that reads only the keys
+    a block can see (``granite_hybrid._chunked_causal_attend``): the values
+    and dq, dk, dv, float32 on both sides; the step tables hold the band's
+    tile pairs and no others; a window that reaches every key is the causal
+    call to the bit."""
+    from videop2p_tpu.models import granite_hybrid as gh
+
+    t_len, (hq, hkv), tiles, cell_heads, window = WINDOW_CASES[case]
+    monkeypatch.setattr(sa, "_TILES", (tiles,))
+    monkeypatch.setattr(sa, "_HEADS", cell_heads)
+    monkeypatch.setattr(gh, "ATTN_ROWS", 128)
+    got_tiles = sa.selected_attention_tiles(t_len, hq, 128, 0, 128, jnp.float32)
+    assert got_tiles[:2] == tiles
+    bq, bk = tiles
+    qi, ki = sa._causal_steps(t_len, bq, bk, False, sa._band(t_len, window))
+    band = {(a, b) for a in range(t_len // bq) for b in range(t_len // bk)
+            if any(0 <= t - s < window for t in (a * bq, (a + 1) * bq - 1)
+                   for s in (b * bk, (b + 1) * bk - 1))
+            or (b * bk <= a * bq and (a + 1) * bq - 1 <= (b + 1) * bk - 1)
+            or (a * bq <= b * bk and (b + 1) * bk - 1 <= (a + 1) * bq - 1
+                and (b + 1) * bk - 1 > a * bq - window)}
+    assert set(zip(qi.tolist(), ki.tolist())) == band
+    assert sa.causal_tile_pairs(t_len, got_tiles, window) == len(band)
+    kq, kk = sa._causal_steps(t_len, bq, bk, True, sa._band(t_len, window))
+    assert sorted(zip(kq.tolist(), kk.tolist())) == sorted(band)
+    assert kk.tolist() == sorted(kk.tolist())
+    ks = jax.random.split(jax.random.key(7), 4)
+    q = jax.random.normal(ks[0], (t_len, hq, 128), jnp.float32)
+    k = jax.random.normal(ks[1], (t_len, hkv, 128), jnp.float32)
+    v = jax.random.normal(ks[2], (t_len, hkv, 128), jnp.float32)
+    w = jax.random.normal(ks[3], (t_len, hq, 128), jnp.float32)
+    scale = 128 ** -0.5
+
+    def pair(attend):
+        out, vjp = jax.vjp(attend, q, k, v)
+        return (out,) + vjp(w)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda: pair(lambda q, k, v: sa.causal_attention(
+            q, k, v, scale, True, window)))()
+        dense = jax.jit(lambda: pair(lambda q, k, v: _banded_dense(
+            q, k, v, scale, window)))()
+        blocked = jax.jit(lambda: pair(lambda q, k, v: gh._chunked_causal_attend(
+            q, k, v, scale, window)))()
+        causal = jax.jit(lambda: pair(lambda q, k, v: sa.causal_attention(
+            q, k, v, scale, True)))()
+    for name, g, x, b, c in zip(("o", "dq", "dk", "dv"), got, dense, blocked,
+                                causal):
+        assert g.shape == x.shape and bool(jnp.isfinite(g).all()), name
+        top = float(jnp.max(jnp.abs(x)))
+        assert float(jnp.max(jnp.abs(g - x))) < 1e-5 * top, name
+        assert float(jnp.max(jnp.abs(b - x))) < 1e-5 * top, name
+        if window >= t_len:
+            assert bool(jnp.array_equal(g, c)), name
+        else:
+            assert float(jnp.max(jnp.abs(c - x))) > 1e-3 * top, name
+
+
+def test_the_band_at_the_cells_shape():
+    """32768 tokens, 16 query heads on one key / value head, a window of
+    4096, 512 x 512 tiles: nine key tiles a query tile once the band is
+    full, 540 of the 2080 causal pairs; the forward's first key tile of a
+    query tile is the one its first query's first key lies in."""
+    tiles = sa.selected_attention_tiles(32768, 16, 128, 0, 128, jnp.bfloat16)
+    assert tiles == sa.Tiles(512, 512, 8, 2)
+    assert sa.causal_tile_pairs(32768, tiles) == 64 * 65 // 2 == 2080
+    assert sa.causal_tile_pairs(32768, tiles, 4096) == 36 + 56 * 9 == 540
+    assert sa.causal_tile_pairs(32768, tiles, 32768) == 2080
+    qi, ki = sa._causal_steps(32768, 512, 512, False, 4096)
+    for a in (0, 7, 8, 9, 63):
+        mine = ki[qi == a]
+        assert mine.min() == max(a * 512 - 4095, 0) // 512 and mine.max() == a

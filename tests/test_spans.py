@@ -177,16 +177,24 @@ _BACKEND = "/jax/core/compile/backend_compile_duration"
 _RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
-def test_listener_turns_durations_into_children_and_sums_unlabelled(tmp_path):
+def test_listener_turns_durations_into_children_and_sums_unlabelled(
+        tmp_path, monkeypatch):
     """jax's trace / lower / backend-compile durations fired under a program
     label become children of the open span (nested ones collapse into the
     outermost); fired with no label they are summed on the open span, or on
-    the ledger where none is open — never one line each."""
+    the ledger where none is open — never one line each. The wall clock the
+    listener and the spans read is the test's own: a child's start is "now
+    less its duration", and a worker under load sleeps longer than asked."""
     fire = jax.monitoring.record_event_duration_secs
+    clock = [time.time_ns()]
+    monkeypatch.setattr(time, "time_ns", lambda: clock[0])
+
+    def spend(seconds):
+        clock[0] += int(seconds * 1e9)
 
     def took(event, seconds, slept=None):
         # jax reports a duration when the work ends: spend it, then report
-        time.sleep(seconds if slept is None else slept)
+        spend(seconds if slept is None else slept)
         fire(event, seconds)
 
     path = str(tmp_path / "ledger.jsonl")
@@ -201,7 +209,7 @@ def test_listener_turns_durations_into_children_and_sums_unlabelled(tmp_path):
                     "/jax/compilation_cache/compile_requests_use_cache")
                 jax.monitoring.record_event(
                     "/jax/compilation_cache/cache_hits")
-                time.sleep(0.005)
+                spend(0.005)
                 fire(_RETRIEVAL, 0.004)
                 fire(_BACKEND, 0.005)
         assert len(led.compile_seconds) == n_compile_events + 1
@@ -698,31 +706,22 @@ def test_the_benchmarks_readers_make_up_the_setup_on_a_tiny_main(
 # --------------------------- the hybrid token model's scopes and counters ---
 
 
-def test_hybrid_token_model_main_emits_the_scopes_and_counters_read():
-    """What the hybrid cell's per-layer metrics read by NAME is one pair of
-    tuples (``models/granite_hybrid.SCOPES`` / ``COUNTERS``): every scope is
-    in the lowered loss, forward and backward, and a
-    tiny ``run_tuning.main`` logs every counter a step beside the loss and
-    emits ``tune.load_document`` under the root."""
+def _token_family_main_emits(module, cfg, yaml_name):
+    """The named scopes in the family's lowered loss, forward and backward,
+    and the ``metric`` events of a tiny ``run_tuning.main`` on its YAML (two
+    calls of two steps) with ``tune.load_document`` under the root."""
     import tempfile
 
     from videop2p_tpu.cli import run_tuning
     from videop2p_tpu.cli.common import load_config
-    from videop2p_tpu.models import granite_hybrid as gh
 
-    assert gh.SCOPES == ("lm.mamba_proj", "lm.ssd", "lm.attention",
-                         "lm.router", "lm.experts", "lm.shared_expert",
-                         "lm.head_loss")
-    assert gh.COUNTERS == ("expert_load_max_over_mean", "held_pair_share",
-                           "routed_over_shared", "ssd_state_rms")
-    cfg = gh.GraniteHybridConfig.tiny()
-    params = gh.abstract_params(cfg, jnp.float32)["params"]
+    params = module.abstract_params(cfg, jnp.float32)["params"]
     ids = jax.ShapeDtypeStruct((32,), jnp.int32)
     text = jax.jit(jax.grad(
-        lambda p, i: gh.forward_loss(p, cfg, i, jnp.float32)[0])).lower(
+        lambda p, i: module.forward_loss(p, cfg, i, jnp.float32)[0])).lower(
             params, ids).as_text(debug_info=True)
     names = set(re.findall(r'loc\("([^"]*)"', text))
-    for scope in gh.SCOPES:
+    for scope in module.SCOPES:
         mine = [n for n in names if scope in n]
         assert [n for n in mine if f"jvp({scope})" in n], scope   # forward
         assert [n for n in mine if "transpose(" in n], scope      # backward
@@ -743,8 +742,7 @@ def test_hybrid_token_model_main_emits_the_scopes_and_counters_read():
 
         return steps_fn
 
-    config = load_config(os.path.join(
-        _REPO, "configs", "granite-4.0-h-small-s4-tune.yaml"))
+    config = load_config(os.path.join(_REPO, "configs", yaml_name))
     with tempfile.TemporaryDirectory() as out:
         path = os.path.join(out, "ledger.jsonl")
         config.update(output_dir=os.path.join(out, "run"),
@@ -768,6 +766,41 @@ def test_hybrid_token_model_main_emits_the_scopes_and_counters_read():
     metrics = [e for e in events if e["event"] == "metric"]
     assert len(metrics) == 4  # two calls of two steps
     for rec in metrics:
-        for name in gh.COUNTERS + ("train_loss",):
+        for name in module.COUNTERS + ("train_loss",):
             assert np.isfinite(rec[name]), (name, rec)
+    return metrics
+
+
+def test_hybrid_token_model_main_emits_the_scopes_and_counters_read():
+    """What the hybrid cell's per-layer metrics read by NAME is one pair of
+    tuples (``models/granite_hybrid.SCOPES`` / ``COUNTERS``): every scope is
+    in the lowered loss, forward and backward, and a
+    tiny ``run_tuning.main`` logs every counter a step beside the loss and
+    emits ``tune.load_document`` under the root."""
+    from videop2p_tpu.models import granite_hybrid as gh
+
+    assert gh.SCOPES == ("lm.mamba_proj", "lm.ssd", "lm.attention",
+                         "lm.router", "lm.experts", "lm.shared_expert",
+                         "lm.head_loss")
+    assert gh.COUNTERS == ("expert_load_max_over_mean", "held_pair_share",
+                           "routed_over_shared", "ssd_state_rms")
+    metrics = _token_family_main_emits(gh, gh.GraniteHybridConfig.tiny(),
+                                       "granite-4.0-h-small-s4-tune.yaml")
     assert metrics[0]["ssd_state_rms"] > 0
+
+
+def test_third_token_family_main_emits_the_scopes_and_counters_read():
+    """The same for ``models/cohere2_moe.SCOPES`` / ``COUNTERS``: the sliding
+    layers under ``lm.window_attention``, the full layer under
+    ``lm.attention`` (the name ``attention_ms.tune`` reads), and the counter
+    ``window_tile_share`` a step beside the loss — 1.0 at 32 tokens, where a
+    window of 8 keys still reads every key a row block of 32 has."""
+    from videop2p_tpu.models import cohere2_moe as cm
+
+    assert cm.SCOPES == ("lm.window_attention", "lm.attention", "lm.router",
+                         "lm.experts", "lm.shared_expert", "lm.head_loss")
+    assert cm.COUNTERS == ("expert_load_max_over_mean", "held_pair_share",
+                           "routed_over_shared", "window_tile_share")
+    metrics = _token_family_main_emits(cm, cm.Cohere2MoeConfig.tiny(),
+                                       "command-a-plus-s8-tune.yaml")
+    assert metrics[0]["window_tile_share"] == 1.0

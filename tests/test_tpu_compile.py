@@ -62,7 +62,15 @@ CAUSAL_TOKENS = [32768, 4096]
 # held of all experts, inner width, experts a token: the hybrid cell's (an
 # expert's three matrices stay in VMEM) and the other's (tiles of the 2048)
 EXPERT_LAYERS = {"granite_18x768": (32768, 4096, (18, 72), 768, 10),
-                 "deepseek_16x2048": (16384, 7168, (16, 256), 2048, 8)}
+                 "deepseek_16x2048": (16384, 7168, (16, 256), 2048, 8),
+                 # the third family's: 4096 x 4096 experts in inner tiles of
+                 # 1024, 16 held of 128, beside 2048 held columns of its four
+                 # shared experts
+                 "command_16x4096": (32768, 4096, (16, 128), 4096, 8, 2048)}
+# the third family's sliding layers (models/cohere2_moe.py): 16 query heads on
+# ONE key / value head of 128, a window of 4096 keys, at the cell's document
+# and at an eighth of it (one window: the causal walk)
+WINDOW_TOKENS = [32768, 4096]
 # (rows, C) slab classes of the SD-1.5 GroupNorm sites the kernel covers
 GROUP_NORM_SLABS = [(4096, 320), (1024, 640), (256, 1280), (512, 1280)]
 
@@ -212,6 +220,36 @@ def test_causal_attention_compiles(one_chip, t_len, grad):
             "lm_selected_attention": 1}
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+@pytest.mark.parametrize("t_len", WINDOW_TOKENS)
+def test_windowed_attention_compiles(one_chip, t_len, grad):
+    """The pair with a WINDOW (the band's step tables, the band's in-tile
+    rule) at the third family's shape — 16 query heads on one key / value
+    head — forward alone and through ``jax.grad`` of q, k and v; the kernels
+    lie under ``lm.window_attention``, what ``window_attention_ms.tune`` and
+    the roofline read, and not under the full layers' ``lm.attention``."""
+    assert selected_attention_tiles(t_len, 16, 128, 0, 128, jnp.bfloat16) is not None
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((t_len, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def fn(q, k, v):
+        return causal_attention(q, k, v, 128 ** -0.5, window=4096)
+
+    want = {"lm_selected_attention": 1}
+    if grad:
+        want["lm_selected_attention_bwd"] = 1
+        fn = jax.grad(lambda *ops, fn=fn: jnp.sum(
+            fn(*ops).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(arg(16), arg(1), arg(1)).compile().as_text()
+    assert tpu_custom_call_counts(text) == want
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = line.split('op_name="')[1].split('"')[0]
+            assert "lm.window_attention" in name and "lm.attention" not in name
+
+
 @pytest.mark.parametrize("act", ["none", "silu"])
 @pytest.mark.parametrize("slab", GROUP_NORM_SLABS,
                          ids=lambda s: "x".join(map(str, s)))
@@ -227,10 +265,11 @@ def test_fused_group_norm_compiles(one_chip, slab, act):
     assert kernels == {"fused_group_norm": 1}
 
 
-def _expert_layer(one_chip, t_len, hidden, held, inner, k):
-    """``held_expert_ffn`` GIVEN a routing, as both token families call it,
+def _expert_layer(one_chip, t_len, hidden, held, inner, k, shared=128):
+    """``held_expert_ffn`` GIVEN a routing, as every token family calls it,
     and its abstract arguments: the held experts' stacked matrices, a shared
-    expert, ``x``, the experts a token and their gates."""
+    expert (``shared``: the columns held), ``x``, the experts a token and
+    their gates."""
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -239,7 +278,7 @@ def _expert_layer(one_chip, t_len, hidden, held, inner, k):
                 "up_proj": {"kernel": arg(lead + (hidden, width))},
                 "down_proj": {"kernel": arg(lead + (width, hidden))}}
 
-    p = {"experts": swiglu((held[0],), inner), "shared": swiglu((), 128)}
+    p = {"experts": swiglu((held[0],), inner), "shared": swiglu((), shared)}
 
     def routed(p, x, experts, gates):
         return ds.held_expert_ffn(p, x, experts, gates, (0, held[0]))[0]
@@ -258,13 +297,13 @@ def test_grouped_experts_compile(one_chip, monkeypatch, layer, grad):
     the fit test counts is one the chip's compiler accepts, both kernels are
     in the text, and their ``op_name`` lies under the scope ``lm.experts`` —
     what ``experts_ms.tune`` reads — while the table's sort does not."""
-    t_len, hidden, held, inner, k = EXPERT_LAYERS[layer]
+    t_len, hidden, held, inner, k = EXPERT_LAYERS[layer][:5]
     tiles = grouped_expert_tiles(t_len * k, hidden, inner, ds.EXPERT_BLOCK,
                                  jnp.bfloat16)
     assert tiles is not None and inner % tiles.inner == 0
     assert max(tiles.fwd_vmem, tiles.bwd_vmem) <= 100 * 2 ** 20  # of 128 MiB
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    routed, args = _expert_layer(one_chip, t_len, hidden, held, inner, k)
+    routed, args = _expert_layer(one_chip, *EXPERT_LAYERS[layer])
     fn = routed
     if grad:
         fn = jax.grad(lambda *a: jnp.sum(routed(*a).astype(jnp.float32) ** 2),

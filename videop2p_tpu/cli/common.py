@@ -581,7 +581,7 @@ def build_models(
 
 # the tune config's ``model_family``: which model Stage 1 builds and which
 # loss it steps on (``tiny`` picks a size inside a family, not a family)
-MODEL_FAMILIES = ("unet3d", "deepseek_v32", "granitemoehybrid")
+MODEL_FAMILIES = ("unet3d", "deepseek_v32", "granitemoehybrid", "cohere2_moe")
 
 
 def check_model_family(name: str) -> str:
@@ -605,11 +605,12 @@ class TokenModelBundle:
 
 def _token_families() -> Dict[str, Any]:
     """``model_family`` → (module, configuration class) of the token models."""
-    from videop2p_tpu.models import deepseek, granite_hybrid
+    from videop2p_tpu.models import cohere2_moe, deepseek, granite_hybrid
 
     return {"deepseek_v32": (deepseek, deepseek.DeepSeekV32Config),
             "granitemoehybrid": (granite_hybrid,
-                                 granite_hybrid.GraniteHybridConfig)}
+                                 granite_hybrid.GraniteHybridConfig),
+            "cohere2_moe": (cohere2_moe, cohere2_moe.Cohere2MoeConfig)}
 
 
 def build_token_model(
@@ -621,8 +622,8 @@ def build_token_model(
     tiny: bool = False,
     seed: int = 0,
 ) -> TokenModelBundle:
-    """A token family (``models/deepseek.py``, ``models/granite_hybrid.py``)
-    from the tune config's ``model`` dict — ``config.json`` keys plus the
+    """A token family (``models/deepseek.py``, ``models/granite_hybrid.py``,
+    ``models/cohere2_moe.py``) from the tune config's ``model`` dict — ``config.json`` keys plus the
     chip's share, unknown keys an error — with seeded random weights in the
     checkpoint's dtype (bfloat16): no checkpoint of these families ships,
     and no loader for one is built."""

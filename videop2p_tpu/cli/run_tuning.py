@@ -290,7 +290,7 @@ def main(
     # which model Stage 1 tunes (cli/common.MODEL_FAMILIES): the inflated
     # video UNet on a clip, or a token model on a document of token ids —
     # ``model`` is that family's configuration (models/deepseek.py,
-    # models/granite_hybrid.py). The token
+    # models/granite_hybrid.py, models/cohere2_moe.py). The token
     # model has no VAE, no text encoder and no validation edit.
     model_family: str = "unet3d",
     model: Optional[Dict[str, Any]] = None,

@@ -784,10 +784,11 @@ def _layer_mask(p, cfg, x, angles):
 
 
 def head_loss(norm_scale, matrix, x, ids, eps, *, tied: bool = False,
-              logit_scale: float = 1.0):
+              logit_scale: float = 1.0, norm=_rms_norm):
     """Mean next-token cross-entropy in float32, in chunks of tokens:
-    logits = RMSNorm(x) W * ``logit_scale``, W = ``matrix`` (h, V), or its
-    transpose where the head is ``tied`` to the embedding (V, h)."""
+    logits = Norm(x) W * ``logit_scale``, W = ``matrix`` (h, V), or its
+    transpose where the head is ``tied`` to the embedding (V, h); ``norm(x,
+    scale, eps)`` is the family's final norm (RMSNorm unless it says)."""
     t_len = x.shape[0]
     lc = min(LOSS_CHUNK, t_len)
     assert t_len % lc == 0, (t_len, lc)
@@ -797,7 +798,7 @@ def head_loss(norm_scale, matrix, x, ids, eps, *, tied: bool = False,
 
     @jax.checkpoint
     def chunk(scale, kernel, xs, ts, ws):
-        y = _rms_norm(xs, scale, eps)
+        y = norm(xs, scale, eps)
         logits = lax.dot_general(y, kernel.astype(y.dtype), contract,
                                  preferred_element_type=jnp.float32)
         if logit_scale != 1.0:

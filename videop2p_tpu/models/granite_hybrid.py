@@ -426,24 +426,44 @@ def mamba_mixer(p, cfg: GraniteHybridConfig, u):
 # ---------------------------------------------------------------- attention
 
 
-def _causal_attend(q, k, v, first, scale):
-    """``q`` (R, H_kv, G, D) rows from position ``first`` against all keys
-    ``k``, ``v`` (T, H_kv, D)."""
+def _causal_attend(q, k, v, first, scale, window=None, key_first=0):
+    """``q`` (R, H_kv, G, D) rows from position ``first`` against the keys
+    ``k``, ``v`` (S, H_kv, D) that sit at positions ``key_first`` onwards
+    (all of them, from 0, unless a caller cut them); inside a ``window`` key
+    s is seen by query t iff 0 <= t - s < window."""
     s = jnp.einsum("rkgd,tkd->kgrt", q, k, preferred_element_type=jnp.float32)
-    causal = (jnp.arange(k.shape[0])[None, :]
-              <= (first + jnp.arange(q.shape[0]))[:, None])
+    keys = jnp.arange(k.shape[0])[None, :]
+    rows = (first + jnp.arange(q.shape[0]))[:, None]
+    if window is None:
+        causal = keys <= rows
+    else:
+        keys = key_first + keys
+        causal = (keys <= rows) & (keys > rows - window)
     prob = jax.nn.softmax(jnp.where(causal, s * scale, -jnp.inf), axis=-1)
     return jnp.einsum("kgrt,tkd->rkgd", prob.astype(v.dtype), v)
 
 
-def _chunked_causal_attend(q, k, v, scale):
+def _chunked_causal_attend(q, k, v, scale, window=None):
     """Causal grouped-query attention as XLA: ``ATTN_ROWS`` queries at a
-    time against all keys, each row block recomputed in the backward pass.
-    ``q`` (T, H, D); ``k``, ``v`` (T, H_kv, D) → (T, H, D)."""
+    time against all keys — with a ``window``, against the
+    ``ATTN_ROWS + window - 1`` keys a row block can see and no others —
+    each row block recomputed in the backward pass. ``q`` (T, H, D); ``k``,
+    ``v`` (T, H_kv, D) → (T, H, D)."""
     t_len, heads, width = q.shape
     kv_heads = k.shape[1]
     rows = math.gcd(ATTN_ROWS, t_len)
-    attend = jax.checkpoint(functools.partial(_causal_attend, scale=scale))
+    span = t_len if window is None else min(t_len, rows + window - 1)
+    if span == t_len:
+        attend = functools.partial(_causal_attend, scale=scale, window=window)
+    else:
+        def attend(q_rows, k, v, first):
+            # the last key a row block sees is its own last row's
+            at = jnp.clip(first + rows - span, 0, t_len - span)
+            return _causal_attend(
+                q_rows, lax.dynamic_slice_in_dim(k, at, span),
+                lax.dynamic_slice_in_dim(v, at, span), first, scale, window, at)
+
+    attend = jax.checkpoint(attend)
     out = lax.map(
         lambda a: attend(a[0], k, v, a[1]),
         (q.reshape(t_len // rows, rows, kv_heads, heads // kv_heads, width),

@@ -25,8 +25,12 @@ selection tile — one byte a pair — is read once for all the heads of a cell.
 
 The selection is optional: ``causal_attention`` (grouped-query heads, no
 mask operand) runs the same two kernels with the causal rule taken from the
-tile's own position — the other token family's attention layer, under the
-scope ``lm.attention``.
+tile's own position — the other token families' attention layers, under the
+scope ``lm.attention``. With a ``window`` (key s is seen by query t iff
+0 <= t - s < window) the step tables hold only the tile pairs that
+intersect that band, the in-tile rule is the band's, and the scope is
+``lm.window_attention``; without one the program is the causal one, as it
+was before the window existed.
 
 Precision as ``models.deepseek._attend``: float32 scores, statistics, dS and
 accumulators; the operands' dtype only as matmul operands. No key is
@@ -46,7 +50,7 @@ import numpy as np
 from jax import lax
 
 __all__ = ["selected_key_attention", "causal_attention",
-           "selected_attention_tiles", "Tiles"]
+           "selected_attention_tiles", "causal_tile_pairs", "Tiles"]
 
 _SCOPE = "lm.sparse_attention"
 
@@ -132,48 +136,72 @@ def selected_attention_tiles(t_len: int, heads: int, nope: int, rope: int,
 
 
 @functools.lru_cache(maxsize=None)
-def _causal_steps(t_len: int, bq: int, bk: int, key_major: bool = False):
-    """(query tile, key tile) of every pair on or under the causal diagonal:
+def _causal_steps(t_len: int, bq: int, bk: int, key_major: bool = False,
+                  window: Optional[int] = None):
+    """(query tile, key tile) of every pair on or under the causal diagonal
+    — with a ``window``, of those that hold a pair 0 <= t - s < window:
     query-major (a query tile's key tiles in a row) or key-major."""
+    reach = t_len if window is None else window
     pairs = [(qi, ki) for qi in range(t_len // bq) for ki in range(t_len // bk)
-             if ki * bk < (qi + 1) * bq]
+             if ki * bk < (qi + 1) * bq and (ki + 1) * bk - 1 > qi * bq - reach]
     if key_major:
         pairs.sort(key=lambda p: (p[1], p[0]))
     qi, ki = np.asarray(pairs, np.int32).T
     return qi, ki
 
 
-def _keep(mask_ref, qi, ki, bq: int, bk: int):
+def causal_tile_pairs(t_len: int, tiles: "Tiles",
+                      window: Optional[int] = None) -> int:
+    """How many (query tile, key tile) steps a call of the pair walks a cell
+    of heads: the causal pairs, or with a ``window`` those in its band."""
+    return len(_causal_steps(t_len, tiles.q, tiles.k, False,
+                             _band(t_len, window))[0])
+
+
+def _band(t_len: int, window: Optional[int]) -> Optional[int]:
+    """A window that reaches every earlier key is no window: the causal
+    program, to the bit."""
+    return None if window is None or window >= t_len else int(window)
+
+
+def _keep(mask_ref, qi, ki, bq: int, bk: int, window: Optional[int] = None):
     """(bk, bq) bool: the keys of tile ``ki`` each query of tile ``qi``
     attends — the selection tile as handed over, or, with no selection, the
-    causal rule from the tile's position."""
+    causal rule from the tile's position (inside a ``window``:
+    queries - window < keys <= queries)."""
     if mask_ref is not None:
         return mask_ref[...].astype(jnp.int32) != 0
     keys = ki * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
     queries = qi * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
-    return keys <= queries
+    if window is None:
+        return keys <= queries
+    return (keys <= queries) & (keys > queries - window)
 
 
 def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, vt_ref, *refs, scale: float,
-                bq: int, bk: int, masked: bool):
+                bq: int, bk: int, masked: bool, window: Optional[int] = None):
     """One (query tile, key tile) of every head of the cell: the online
     softmax's update of the running max ``m``, sum ``l`` and output
     ``acc`` (transposed, (v_dim, queries)); the last key tile of a query
-    tile writes ``o`` and the log-sum-exp."""
+    tile writes ``o`` and the log-sum-exp. A query tile's first key tile is
+    tile 0, or with a ``window`` the one that holds the first key its first
+    query sees; its last is the diagonal's either way."""
     from jax.experimental import pallas as pl
 
     mask_ref = refs[0] if masked else None
     ot_ref, lse_ref, m_ref, l_ref, acc_ref = refs[int(masked):]
     step = pl.program_id(1)
     qi, ki = qi_ref[step], ki_ref[step]
+    first = 0 if window is None else lax.div(
+        jnp.maximum(qi * bq - (window - 1), 0), bk)
 
-    @pl.when(ki == 0)
+    @pl.when(ki == first)
     def _():
         m_ref[...] = jnp.full_like(m_ref, _FLOOR)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    keep = _keep(mask_ref, qi, ki, bq, bk)  # (bk, bq)
+    keep = _keep(mask_ref, qi, ki, bq, bk, window)  # (bk, bq)
     for h in range(q_ref.shape[0]):
         st = lax.dot_general(k_ref[h], q_ref[h], _NT,
                              preferred_element_type=jnp.float32) * scale
@@ -196,17 +224,18 @@ def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, vt_ref, *refs, scale: float,
         lse_ref[...] = m_ref[...] + jnp.log(l)
 
 
-def _forward_call(q, k, v, mask_t, tiles: Tiles, scale: float, interpret: bool):
+def _forward_call(q, k, v, mask_t, tiles: Tiles, scale: float, interpret: bool,
+                  window: Optional[int] = None):
     """q, k (H, T, qk); v (H, T, v_dim); mask_t (T keys, T queries) int8, or
-    None (causal) → oᵀ (H, v_dim, T) in ``v``'s dtype, log-sum-exp (H, 1, T)
-    float32."""
+    None (causal, inside ``window`` where one is given) → oᵀ (H, v_dim, T) in
+    ``v``'s dtype, log-sum-exp (H, 1, T) float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     heads, t_len, qk = q.shape
     vd = v.shape[2]
     bq, bk, hb, _ = tiles
-    qi, ki = _causal_steps(t_len, bq, bk)
+    qi, ki = _causal_steps(t_len, bq, bk, False, window)
     masked = mask_t is not None
     # the selection tile and its operand: there, or left out of the call
     mask_spec = [pl.BlockSpec((bk, bq), lambda g, s, qi, ki: (ki[s], qi[s]))
@@ -214,7 +243,7 @@ def _forward_call(q, k, v, mask_t, tiles: Tiles, scale: float, interpret: bool):
     mask_operand = [mask_t] * masked
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk,
-                          masked=masked),
+                          masked=masked, window=window),
         # explicit name: the compiled program's custom call and the trace
         # events carry it (obs/introspect.tpu_custom_call_counts)
         name="lm_selected_attention",
@@ -249,12 +278,13 @@ def _forward_call(q, k, v, mask_t, tiles: Tiles, scale: float, interpret: bool):
 
 def _bwd_kernel(qi_ref, ki_ref, q_ref, do_ref, k_ref, kt_ref, v_ref, lse_ref,
                 delta_ref, *refs, scale: float, bq: int, bk: int,
-                masked: bool):
+                masked: bool, window: Optional[int] = None):
     """One (key tile, query tile) of every head of the cell: Pᵀ from the saved
     log-sum-exp, dSᵀ = Pᵀ ∘ (dPᵀ − delta), and its share of dV, dK (their
     blocks stay resident over the key tile's query tiles) and dQᵀ (its block
     holds every query tile for the whole call). ``scale`` on dQ and dK is
-    the caller's."""
+    the caller's. A key tile's first query tile is the diagonal's, with a
+    ``window`` or without; the window only ends its walk sooner."""
     from jax.experimental import pallas as pl
 
     mask_ref = refs[0] if masked else None
@@ -271,7 +301,7 @@ def _bwd_kernel(qi_ref, ki_ref, q_ref, do_ref, k_ref, kt_ref, v_ref, lse_ref,
         dk_ref[...] = jnp.zeros_like(dk_ref)
         dv_ref[...] = jnp.zeros_like(dv_ref)
 
-    keep = _keep(mask_ref, qi, ki, bq, bk)  # (bk, bq)
+    keep = _keep(mask_ref, qi, ki, bq, bk, window)  # (bk, bq)
     for h in range(q_ref.shape[0]):
         q, do = q_ref[h], do_ref[h]  # (bq, qk), (bq, v_dim)
         st = lax.dot_general(k_ref[h], q, _NT,
@@ -290,7 +320,8 @@ def _bwd_kernel(qi_ref, ki_ref, q_ref, do_ref, k_ref, kt_ref, v_ref, lse_ref,
 
 
 def _backward_call(q, do, k, v, lse, delta, mask_t, tiles: Tiles,
-                   scale: float, interpret: bool):
+                   scale: float, interpret: bool,
+                   window: Optional[int] = None):
     """q, k (H, T, qk); do, v (H, T, v_dim); lse, delta (H, 1, T); mask_t as
     the forward's →
     dQᵀ (H, T / bq, qk, bq), dK (H, T, qk), dV (H, T, v_dim), float32 and
@@ -301,7 +332,7 @@ def _backward_call(q, do, k, v, lse, delta, mask_t, tiles: Tiles,
     heads, t_len, qk = q.shape
     vd = v.shape[2]
     bq, bk, _, hb = tiles
-    qi, ki = _causal_steps(t_len, bq, bk, key_major=True)
+    qi, ki = _causal_steps(t_len, bq, bk, True, window)
 
     def rows_q(width):
         return pl.BlockSpec((hb, bq, width), lambda g, s, qi, ki: (g, qi[s], 0))
@@ -317,7 +348,7 @@ def _backward_call(q, do, k, v, lse, delta, mask_t, tiles: Tiles,
     mask_operand = [mask_t] * masked
     return pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, bq=bq, bk=bk,
-                          masked=masked),
+                          masked=masked, window=window),
         name="lm_selected_attention_bwd",
         out_shape=(
             jax.ShapeDtypeStruct((heads, t_len // bq, qk, bq), jnp.float32),
@@ -423,6 +454,7 @@ selected_key_attention.defvjp(_attention_fwd, _attention_bwd)
 # ------------------------------------------------- no selection: causal, GQA
 
 _CAUSAL_SCOPE = "lm.attention"
+_WINDOW_SCOPE = "lm.window_attention"
 
 
 def _causal_tiles(q) -> Tiles:
@@ -444,37 +476,44 @@ def _grouped_heads_first(q, k, v):
     return q.transpose(1, 0, 2), spread(k), spread(v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def causal_attention(q, k, v, scale: float, interpret: bool = False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def causal_attention(q, k, v, scale: float, interpret: bool = False,
+                     window: Optional[int] = None):
     """Causal softmax attention with grouped-query heads and NO selection:
     ``q`` (T, H, D), ``k``, ``v`` (T, H_kv, D) with H a multiple of H_kv
     (query head ``i`` reads key / value head ``i // (H / H_kv)``) → (T, H,
     D). The kernel pair above without its mask operand; differentiable in
-    all three. Raises where :func:`selected_attention_tiles` refuses the
-    shape (asked as ``(T, H, D, 0, D)``)."""
-    return _causal_fwd(q, k, v, scale, interpret)[0]
+    all three. With a ``window`` key s is seen by query t iff
+    0 <= t - s < window: only the tile pairs that intersect that band are
+    walked, forward and backward, and the ops carry the scope
+    ``lm.window_attention``; a window that reaches every earlier key gives
+    the causal result to the bit. Raises where
+    :func:`selected_attention_tiles` refuses the shape (asked as ``(T, H, D,
+    0, D)``)."""
+    return _causal_fwd(q, k, v, scale, interpret, window)[0]
 
 
-def _causal_fwd(q, k, v, scale, interpret):
+def _causal_fwd(q, k, v, scale, interpret, window):
     tiles = _causal_tiles(q)
-    with jax.named_scope(_CAUSAL_SCOPE):
+    with jax.named_scope(_CAUSAL_SCOPE if window is None else _WINDOW_SCOPE):
         qh, kh, vh = _grouped_heads_first(q, k, v)
-        ot, lse = _forward_call(qh, kh, vh, None, tiles, scale, interpret)
+        ot, lse = _forward_call(qh, kh, vh, None, tiles, scale, interpret,
+                                _band(q.shape[0], window))
         o = ot.transpose(2, 0, 1)
     return o, (q, k, v, o, lse)
 
 
-def _causal_bwd(scale, interpret, res, g):
+def _causal_bwd(scale, interpret, window, res, g):
     q, k, v, o, lse = res
     tiles = _causal_tiles(q)
     t_len, heads, width = q.shape
     kv_heads = k.shape[1]
-    with jax.named_scope(_CAUSAL_SCOPE):
+    with jax.named_scope(_CAUSAL_SCOPE if window is None else _WINDOW_SCOPE):
         qh, kh, vh = _grouped_heads_first(q, k, v)
         delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
         dqt, dk, dv = _backward_call(
             qh, g.transpose(1, 0, 2), kh, vh, lse, delta.T[:, None, :], None,
-            tiles, scale, interpret)
+            tiles, scale, interpret, _band(t_len, window))
         dq = dqt.transpose(1, 3, 0, 2).reshape(t_len, heads, width) * scale
 
         def gathered(d):  # (H, T, D) → (T, H_kv, D): a group's heads summed
