@@ -7,6 +7,8 @@ bfloat16 case with its own tolerance; and the fit test's arithmetic. What
 the chip's compiler says of the kernels is ``tests/test_tpu_compile.py``'s.
 """
 
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -253,3 +255,103 @@ def test_the_band_at_the_cells_shape():
     for a in (0, 7, 8, 9, 63):
         mine = ki[qi == a]
         assert mine.min() == max(a * 512 - 4095, 0) // 512 and mine.max() == a
+
+
+# ------------------------------- what a layer's recompute keeps of the pair
+
+def _pallas_calls(jaxpr) -> dict:
+    """Pallas calls of a jaxpr by kernel name, sub-jaxprs included."""
+    counts = collections.Counter(
+        eqn.params["name"] for eqn in jaxpr.eqns
+        if eqn.primitive.name == "pallas_call")
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            counts.update(_pallas_calls(sub))
+    return dict(counts)
+
+
+def _causal_layer(window):
+    def layer(x):  # (T, 2, 128): two query heads on one key / value head
+        kv = x[:, :1]
+        return x + jnp.tanh(sa.causal_attention(x, kv, kv, 128 ** -0.5, True,
+                                                window))
+    return layer
+
+
+def _selected_layer(x):  # (T, 2, 128) on a fixed selection
+    return x + jnp.tanh(sa.selected_key_attention(
+        x, x[..., :ROPE], x, x[:, 0, :ROPE], x, selection(256, 40), SCALE, True))
+
+
+@pytest.mark.parametrize("layer", {
+    "causal": _causal_layer(None), "window": _causal_layer(100),
+    "selected": _selected_layer}.items(), ids=lambda c: c[0])
+def test_the_policy_keeps_the_forward_kernel_out_of_the_recompute(monkeypatch,
+                                                                  layer):
+    """Two checkpointed layers over the pair, in interpret mode: with
+    ``keep_attention_outputs`` the gradient holds each layer's forward kernel
+    ONCE (a bare ``jax.checkpoint`` runs it again in the recompute: the names
+    sit inside the forward rule or this fails), the backward kernel once
+    either way, and the gradient is the bare checkpoint's and the
+    uncheckpointed stack's to the bit."""
+    monkeypatch.setattr(sa, "_TILES", ((128, 128),))
+    layer = layer[1]
+    x = jax.random.normal(jax.random.key(11), (256, 2, 128), jnp.float32)
+
+    def grad(wrap):
+        return jax.grad(lambda x: jnp.sum(wrap(layer)(wrap(layer)(x)) ** 2))
+
+    kept = grad(lambda f: jax.checkpoint(f, policy=sa.keep_attention_outputs))
+    bare = grad(jax.checkpoint)
+    assert _pallas_calls(jax.make_jaxpr(kept)(x).jaxpr) == {
+        "lm_selected_attention": 2, "lm_selected_attention_bwd": 2}
+    assert _pallas_calls(jax.make_jaxpr(bare)(x).jaxpr) == {
+        "lm_selected_attention": 4, "lm_selected_attention_bwd": 2}
+    want = jax.jit(grad(lambda f: f))(x)
+    assert float(jnp.abs(want).max()) > 0
+    assert bool(jnp.array_equal(jax.jit(kept)(x), want))
+    assert bool(jnp.array_equal(jax.jit(bare)(x), want))
+
+
+def test_a_name_outside_a_checkpoint_is_an_identity():
+    a, b = jnp.arange(3.0), jnp.ones(2)
+    got = sa._kept(a, b)
+    assert bool(jnp.array_equal(got[0], a)) and bool(jnp.array_equal(got[1], b))
+    names = [e.params["name"] for e in jax.make_jaxpr(sa._kept)(a, b).jaxpr.eqns]
+    assert tuple(names) == sa.KEPT_NAMES
+
+
+@pytest.mark.parametrize("family", ["deepseek_v32", "granitemoehybrid",
+                                    "cohere2_moe"])
+def test_on_the_cpu_path_the_policy_keeps_nothing(monkeypatch, family):
+    """Each family's ``forward_loss`` off the TPU: the XLA attention carries
+    no name, so under the policy the layers' recompute is the bare
+    checkpoint's — no ``name`` equation in the loss, and the gradient of the
+    trainable-sized leaves equal to the bit with the policy taken away."""
+    from videop2p_tpu.cli.common import _token_families
+
+    module, config_cls = _token_families()[family]
+    cfg = config_cls.tiny()
+    params = jax.jit(lambda k: module.init_params(k, cfg))(
+        jax.random.key(5))["params"]
+    params = jax.tree.map(lambda x: x.astype(jnp.float32), params)
+    ids = jax.random.randint(jax.random.key(1), (64,), 0, cfg.vocab_size)
+
+    def loss(p):
+        return module.forward_loss(p, cfg, ids, jnp.float32)[0]
+
+    def names(jaxpr):
+        return sum((e.primitive.name == "name")
+                   + sum(names(s) for s in jax.core.jaxprs_in_params(e.params))
+                   for e in jaxpr.eqns)
+
+    assert module.keep_attention_outputs is sa.keep_attention_outputs
+    assert names(jax.make_jaxpr(loss)(params).jaxpr) == 0
+    got = jax.jit(jax.grad(loss))(params)
+    monkeypatch.setattr(module, "keep_attention_outputs", None)
+    want = jax.jit(jax.grad(loss))(params)
+    moved = 0
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.array_equal(g, w))
+        moved += float(jnp.abs(w).max()) > 0
+    assert moved > 4

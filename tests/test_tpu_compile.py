@@ -36,6 +36,7 @@ from videop2p_tpu.ops.grouped_experts import grouped_expert_tiles
 from videop2p_tpu.ops.groupnorm import fits_fused_group_norm, fused_group_norm
 from videop2p_tpu.ops.selected_attention import (
     causal_attention,
+    keep_attention_outputs,
     selected_attention_tiles,
     selected_key_attention,
 )
@@ -248,6 +249,73 @@ def test_windowed_attention_compiles(one_chip, t_len, grad):
         if 'custom_call_target="tpu_custom_call"' in line:
             name = line.split('op_name="')[1].split('"')[0]
             assert "lm.window_attention" in name and "lm.attention" not in name
+
+
+def _family_attention(family):
+    """``(module, cfg, tokens, layer)`` of a token family at its cell: the
+    configuration the tuner's YAML states, and in ``layer(p, x, *given)`` the
+    family's own ``attention`` on its residual — a sliding layer where the
+    family has them and ``family`` says so."""
+    from videop2p_tpu.cli.common import _token_families, load_config
+
+    name, _, kind = family.partition(":")
+    tune = load_config({"deepseek_v32": "configs/deepseek-v32-s16-tune.yaml",
+                        "granitemoehybrid": "configs/granite-4.0-h-small-s4-tune.yaml",
+                        "cohere2_moe": "configs/command-a-plus-s8-tune.yaml"}[name])
+    module, config_cls = _token_families()[name]
+    cfg = config_cls.from_dict(tune["model"])
+    t_len = tune["train_data"]["n_tokens"]
+    if name == "deepseek_v32":
+        def layer(p, x, mask):
+            return x + module.attention(
+                p, cfg, x, module.rope_angles(cfg, jnp.arange(t_len)), mask)[0]
+    elif name == "granitemoehybrid":
+        def layer(p, x):
+            return x + module.attention(p, cfg, x)
+    else:
+        def layer(p, x):
+            angles = (module.rope_angles(cfg, jnp.arange(t_len))
+                      if kind == "sliding" else None)
+            return x + module.attention(p, cfg, x, angles)[0]
+    return module, cfg, t_len, layer
+
+
+@pytest.mark.parametrize("family", ["deepseek_v32", "granitemoehybrid",
+                                    "cohere2_moe:sliding", "cohere2_moe:full"])
+def test_a_layers_recompute_keeps_the_attention_pairs_outputs(one_chip,
+                                                              monkeypatch,
+                                                              family):
+    """Each token family's ``attention`` at its cell's shape, as the model
+    dispatches it on the chip, differentiated through the checkpoint its
+    ``_forward`` wraps a layer in: with ``keep_attention_outputs`` the
+    compiled gradient holds the forward kernel ONCE a layer (``oᵀ`` and the
+    log-sum-exp are kept across the recompute), under a bare
+    ``jax.checkpoint`` twice — so this fails if the names ever slip outside
+    the forward rule, or a family's layer stops carrying them."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    module, cfg, t_len, layer = _family_attention(family)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    attn = next(layer_p["attn"] for layer_p in
+                module.param_shapes(cfg)["params"].values() if "attn" in layer_p)
+    args = [jax.tree.map(lambda spec: arg(spec[0]), attn,
+                         is_leaf=ds._is_spec),
+            arg((t_len, cfg.hidden_size))]
+    if family == "deepseek_v32":
+        args.append(arg((t_len, t_len), jnp.bool_))
+
+    def kernels(policy):
+        wrapped = jax.checkpoint(layer, policy=policy)
+        return _kernels(jax.grad(
+            lambda *a: jnp.sum(wrapped(*a).astype(jnp.float32) ** 2),
+            argnums=(0, 1)), *args)
+
+    assert kernels(keep_attention_outputs) == {
+        "lm_selected_attention": 1, "lm_selected_attention_bwd": 1}
+    assert kernels(None) == {
+        "lm_selected_attention": 2, "lm_selected_attention_bwd": 1}
 
 
 @pytest.mark.parametrize("act", ["none", "silu"])

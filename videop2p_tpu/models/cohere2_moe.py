@@ -89,6 +89,7 @@ from videop2p_tpu.models.granite_hybrid import (
 from videop2p_tpu.ops.selected_attention import (
     causal_attention,
     causal_tile_pairs,
+    keep_attention_outputs,
     selected_attention_tiles,
 )
 
@@ -150,7 +151,10 @@ class Cohere2MoeConfig:
     experts_held: Tuple[int, int] = (0, 128)
     heads_held: Tuple[int, int] = (0, 128)
     shared_columns_held: Tuple[int, int] = (0, 16384)
-    remat: bool = True         # recompute each layer in the backward pass
+    # recompute each layer in the backward pass; kept across it: the output
+    # and log-sum-exp of the attention kernel pair where it ran, nothing else
+    # (``ops.selected_attention.keep_attention_outputs``)
+    remat: bool = True
     # the loss hands out, beside its scalars, the experts every layer CHOSE
     # for every token: what a check against a reference takes as data
     hand_out_choices: bool = False
@@ -360,7 +364,7 @@ def _forward(params, cfg: Cohere2MoeConfig, ids, dtype):
     angles = rope_angles(cfg, jnp.arange(t_len))
     layer = functools.partial(_layer, cfg)
     if cfg.remat:
-        layer = jax.checkpoint(layer)
+        layer = jax.checkpoint(layer, policy=keep_attention_outputs)
     counters, choices = [], []
     for i, kind in enumerate(cfg.layer_types):
         x, c, experts = layer(params[f"layers_{i}"], x,

@@ -78,6 +78,7 @@ from videop2p_tpu.ops.grouped_experts import (
     grouped_experts_forward,
 )
 from videop2p_tpu.ops.selected_attention import (
+    keep_attention_outputs,
     selected_attention_tiles,
     selected_key_attention,
 )
@@ -153,7 +154,10 @@ class DeepSeekV32Config:
     # the chip's share: (first, count) of the routed experts and of the heads
     experts_held: Tuple[int, int] = (0, 256)
     heads_held: Tuple[int, int] = (0, 128)
-    remat: bool = True         # recompute each layer in the backward pass
+    # recompute each layer in the backward pass; kept across it: the output
+    # and log-sum-exp of the attention kernel pair where it ran, nothing else
+    # (``ops.selected_attention.keep_attention_outputs``)
+    remat: bool = True
     # the loss hands out, beside its scalars, what every layer CHOSE (the
     # selection eight keys a byte, the experts a token): what a check against
     # a reference takes as data. 168 MB a step at 16384 tokens and 5 layers,
@@ -821,7 +825,7 @@ def _forward(params, cfg: DeepSeekV32Config, ids, dtype):
     x = params["embed"]["embedding"].astype(dtype)[ids]
     layer = functools.partial(_layer, cfg)
     if cfg.remat:
-        layer = jax.checkpoint(layer)
+        layer = jax.checkpoint(layer, policy=keep_attention_outputs)
     counters, selected, choices = [], [], []
     for i in range(cfg.num_hidden_layers):
         p = params[f"layers_{i}"]

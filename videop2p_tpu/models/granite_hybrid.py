@@ -97,6 +97,7 @@ from videop2p_tpu.models.deepseek import (
 )
 from videop2p_tpu.ops.selected_attention import (
     causal_attention,
+    keep_attention_outputs,
     selected_attention_tiles,
 )
 
@@ -166,7 +167,10 @@ class GraniteHybridConfig:
     experts_held: Tuple[int, int] = (0, 72)
     heads_held: Tuple[int, int] = (0, 32)
     mamba_heads_held: Tuple[int, int] = (0, 128)
-    remat: bool = True         # recompute each layer in the backward pass
+    # recompute each layer in the backward pass; kept across it: the output
+    # and log-sum-exp of the attention kernel pair where it ran, nothing else
+    # (``ops.selected_attention.keep_attention_outputs``)
+    remat: bool = True
     # the loss hands out, beside its scalars, the experts every layer CHOSE
     # for every token: what a check against a reference takes as data
     hand_out_choices: bool = False
@@ -535,7 +539,7 @@ def _forward(params, cfg: GraniteHybridConfig, ids, dtype):
          * params["embed"]["embedding"].astype(dtype)[ids]).astype(dtype)
     layer = functools.partial(_layer, cfg)
     if cfg.remat:
-        layer = jax.checkpoint(layer)
+        layer = jax.checkpoint(layer, policy=keep_attention_outputs)
     counters, states, choices = [], [], []
     for i in range(cfg.num_hidden_layers):
         x, c, experts, state_sq = layer(params[f"layers_{i}"], x)

@@ -48,9 +48,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["selected_key_attention", "causal_attention",
-           "selected_attention_tiles", "causal_tile_pairs", "Tiles"]
+           "selected_attention_tiles", "causal_tile_pairs", "Tiles",
+           "KEPT_NAMES", "keep_attention_outputs"]
 
 _SCOPE = "lm.sparse_attention"
 
@@ -69,6 +71,21 @@ _FLOOR = -1e30
 
 _NT = (((1,), (1,)), ((), ()))
 _NN = (((1,), (0,)), ((), ()))
+
+# What the forward kernel hands the backward one, by name: oᵀ and the row
+# log-sum-exp, tagged INSIDE the forward rules (outside them the residual
+# the backward reads is the untagged value). A layer wrapped in
+# ``jax.checkpoint(layer, policy=keep_attention_outputs)`` keeps the two
+# across its recompute and nothing else, so the forward kernel runs once a
+# layer and step; where the pair did not run no value carries the names and
+# the policy keeps nothing.
+KEPT_NAMES = ("lm_selected_attention_ot", "lm_selected_attention_lse")
+keep_attention_outputs = jax.checkpoint_policies.save_only_these_names(
+    *KEPT_NAMES)
+
+
+def _kept(ot, lse):
+    return tuple(map(checkpoint_name, (ot, lse), KEPT_NAMES))
 
 
 class Tiles(NamedTuple):
@@ -422,7 +439,8 @@ def _attention_fwd(q_nope, q_rope, k_nope, k_rope, v, mask, scale, interpret):
     with jax.named_scope(_SCOPE):
         q, k, vh = _heads_first(q_nope, q_rope, k_nope, k_rope, v)
         mask_t = mask.T.astype(jnp.int8)
-        ot, lse = _forward_call(q, k, vh, mask_t, tiles, scale, interpret)
+        ot, lse = _kept(*_forward_call(q, k, vh, mask_t, tiles, scale,
+                                       interpret))
         o = ot.transpose(2, 0, 1)
     return o, (q_nope, q_rope, k_nope, k_rope, v, mask_t, o, lse)
 
@@ -497,8 +515,8 @@ def _causal_fwd(q, k, v, scale, interpret, window):
     tiles = _causal_tiles(q)
     with jax.named_scope(_CAUSAL_SCOPE if window is None else _WINDOW_SCOPE):
         qh, kh, vh = _grouped_heads_first(q, k, v)
-        ot, lse = _forward_call(qh, kh, vh, None, tiles, scale, interpret,
-                                _band(q.shape[0], window))
+        ot, lse = _kept(*_forward_call(qh, kh, vh, None, tiles, scale,
+                                       interpret, _band(q.shape[0], window)))
         o = ot.transpose(2, 0, 1)
     return o, (q, k, v, o, lse)
 
