@@ -114,7 +114,8 @@ def test_span_is_on_disk_when_an_exception_unwinds_and_close_never_runs(
         # read while the ledger is still open and active: nothing is buffered
         events = read_ledger(path)
         assert not any(e["event"] == "run_end" for e in events)
-        spans = [e for e in events if e["event"] == "span"]
+        spans = [e for e in events if e["event"] == "span"
+                 and not e["name"].startswith("process")]
         assert [s["name"] for s in spans] == ["main.first", "main.root",
                                               "main.loop"]
         assert _one(spans, "main.root")["status"] == "ok"
@@ -240,7 +241,8 @@ def test_listener_turns_durations_into_children_and_sums_unlabelled(
     assert eager["unspanned_trace_lower_events"] == 3
     assert eager["unspanned_backend_compiles"] == 1
     assert eager["unspanned_backend_compile_s"] == pytest.approx(0.25)
-    assert len(spans) == 6  # the call, its four children, the eager region
+    # the call, its four children, the eager region (and the ledger's process)
+    assert len([s for s in spans if not s["name"].startswith("process")]) == 6
     end, = [e for e in events if e["event"] == "run_end"]
     assert end["unspanned_backend_compiles"] == 1
 
@@ -701,6 +703,167 @@ def test_the_benchmarks_readers_make_up_the_setup_on_a_tiny_main(
     assert [e["program"] for e in tiny_tune_ledger
             if e["event"] == "program_analysis"] == ["train_steps"]
     assert bench.host_between_calls_ms(ctx) > 0
+
+
+# ------------------------------- the set-up before the root: `process` ---
+
+
+def _end_ns(s):
+    return s["wall_ns"] + int(round(s["duration_s"] * 1e9))
+
+
+def test_process_reaches_from_the_process_start_to_the_root(tiny_tune_ledger):
+    """One ``process`` a ledger, written when the first live span opened: it
+    ends where ``tune.setup`` starts, starts no later than the package's
+    first line, and holds ``process.import`` then ``process.ledger_open``,
+    apart; the root's own children are as they were."""
+    spans = [e for e in tiny_tune_ledger if e["event"] == "span"]
+    process, root = _one(spans, "process"), _one(spans, "tune.setup")
+    assert abs(_end_ns(process) - root["wall_ns"]) <= 1e6
+    assert process["parent_id"] is None and process["anchor"] == "proc"
+    assert process["trace_id"] == root["trace_id"]
+    kids = sorted(_kids(spans, process), key=lambda s: s["wall_ns"])
+    assert [s["name"] for s in kids] == ["process.import",
+                                         "process.ledger_open"]
+    imported, opened = kids
+    assert process["wall_ns"] <= imported["wall_ns"]
+    assert _end_ns(imported) <= opened["wall_ns"]
+    assert _end_ns(opened) <= root["wall_ns"]
+    import videop2p_tpu
+
+    assert imported["wall_ns"] == videop2p_tpu.IMPORT_NS
+    # the first live span wrote it, once
+    assert [s["name"] for s in spans[:3]] == [
+        "process.import", "process.ledger_open", "process"]
+
+
+def test_process_starts_at_the_packages_first_line_where_proc_is_unread(
+        tmp_path, monkeypatch):
+    import videop2p_tpu
+
+    monkeypatch.setattr(obs_spans, "_PROC_STAT", str(tmp_path / "absent"))
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path):
+        with span("first") as first:
+            pass
+        with span("second"):
+            pass
+    spans = _spans(path)
+    process = _one(spans, "process")
+    assert process["anchor"] == "import"
+    assert process["wall_ns"] == videop2p_tpu.IMPORT_NS
+    assert _end_ns(process) == pytest.approx(first._wall_ns, abs=1e3)
+    assert [s["name"] for s in spans].count("process.ledger_open") == 1
+
+
+def test_the_kernels_start_time_is_read_after_the_last_parenthesis(
+        tmp_path, monkeypatch):
+    """Field 2 of ``/proc/<pid>/stat`` is the command name in parentheses,
+    which may itself hold spaces and parentheses; field 22 is the start in
+    clock ticks since boot."""
+    tick = os.sysconf("SC_CLK_TCK")
+    ticks = int((time.clock_gettime(time.CLOCK_BOOTTIME) - 5.0) * tick)
+    fields = ["S"] + ["0"] * 18 + [str(ticks)] + ["0"] * 30
+    stat = tmp_path / "stat"
+    stat.write_text("4242 (a) b (c) " + " ".join(fields) + "\n")
+    monkeypatch.setattr(obs_spans, "_PROC_STAT", str(stat))
+    start_ns, anchor = obs_spans.process_start_ns()
+    assert anchor == "proc"
+    assert (time.time_ns() - start_ns) * 1e-9 == pytest.approx(5.0, abs=0.05)
+
+
+def test_first_spans_of_many_threads_write_one_process(tmp_path):
+    import sys
+
+    path = str(tmp_path / "ledger.jsonl")
+    n = 4 * (os.cpu_count() or 1)
+    barrier = threading.Barrier(n)
+
+    def first_span():
+        barrier.wait(timeout=30)
+        with span("worker"):
+            pass
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with RunLedger(path):
+            threads = [threading.Thread(target=first_span) for _ in range(n)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    names = [s["name"] for s in _spans(path)]
+    assert names.count("process") == 1 and names.count("worker") == n
+    assert names.count("process.ledger_open") == 1
+
+
+def test_a_ledger_whose_tracer_is_off_writes_no_process(tmp_path):
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path) as led:
+        led.tracer.enabled = False
+        with span("tracing.off"):
+            pass
+    assert not any(e["event"] == "span" for e in read_ledger(path))
+    # the engine's own tracer, put in place of the ledger's: no `process`
+    path = str(tmp_path / "engine.jsonl")
+    with RunLedger(path) as led:
+        led.tracer = Tracer(led, enabled=True)
+        with span("engine.span"):
+            pass
+    assert [s["name"] for s in _spans(path)] == ["engine.span"]
+
+
+def test_the_tensorboard_writer_is_a_span_under_the_logger(
+        tiny_tune_ledger, tmp_path, monkeypatch):
+    """On a tiny ``main`` the writer's import and construction are one span
+    under ``tune.metrics_logger``; where the import fails it closes
+    ``error`` and the logger goes on with its JSONL alone."""
+    import sys
+
+    from videop2p_tpu.utils.metrics import MetricsLogger
+
+    spans = [e for e in tiny_tune_ledger if e["event"] == "span"]
+    writer = _one(spans, "metrics.tensorboard_writer")
+    logger = _one(spans, "tune.metrics_logger")
+    assert writer["parent_id"] == logger["span_id"]
+    assert writer["duration_s"] <= logger["duration_s"]
+    monkeypatch.setitem(sys.modules, "torch.utils.tensorboard", None)
+    path = str(tmp_path / "ledger.jsonl")
+    with RunLedger(path) as led:
+        with span("tune.metrics_logger"):
+            metrics = MetricsLogger(str(tmp_path / "run"), ledger=led)
+        metrics.log(1, {"train_loss": 1.0})
+        metrics.close()
+    failed = _one(_spans(path), "metrics.tensorboard_writer")
+    assert failed["status"] == "error" and metrics._tb is None
+    assert failed["parent_id"] == _one(_spans(path),
+                                       "tune.metrics_logger")["span_id"]
+    with open(metrics.path) as f:
+        assert len(f.readlines()) == 1
+
+
+def test_the_process_names_the_benchmark_reads_are_one_tuple(
+        tiny_tune_ledger):
+    """``benchmark/harness/process_spans.py`` keeps the tuple as READ_NAMES
+    (read here from its text: it may import nothing of the program, and the
+    program's tests import nothing of the benchmark); a tiny ``main`` emits
+    each name; the older tuple is untouched."""
+    import ast
+
+    with open(os.path.join(_REPO, "benchmark", "harness",
+                           "process_spans.py")) as f:
+        tree = ast.parse(f.read())
+    read_names, = [ast.literal_eval(node.value) for node in tree.body
+                   if isinstance(node, ast.Assign)
+                   and [t.id for t in node.targets] == ["READ_NAMES"]]
+    assert read_names == obs_spans.BENCHMARK_PROCESS_SPAN_NAMES
+    names = {e["name"] for e in tiny_tune_ledger if e["event"] == "span"}
+    assert set(read_names) <= names, set(read_names) - names
+    assert not set(read_names) & set(BENCHMARK_SPAN_NAMES)
 
 
 # --------------------------- the hybrid token model's scopes and counters ---

@@ -17,4 +17,9 @@ Layout conventions (TPU-first, deliberately different from the torch reference):
     (cf. /root/reference/ptp_utils.py:188-255).
 """
 
+import time as _time
+
+# where the span `process.import` starts (obs/spans.py): the package's first line
+IMPORT_NS = _time.time_ns()
+
 __version__ = "0.1.0"

@@ -39,7 +39,7 @@ from videop2p_tpu.cli.common import (
     enable_compile_cache,
 )
 from videop2p_tpu.obs import instrumented_jit
-from videop2p_tpu.obs.spans import span
+from videop2p_tpu.obs.spans import entry_imported, span
 from videop2p_tpu.core import DDIMScheduler, DDPMScheduler, DependentNoiseSampler
 from videop2p_tpu.data import SingleVideoDataset, TokenDocument
 from videop2p_tpu.models import decode_video, encode_video
@@ -62,13 +62,13 @@ from videop2p_tpu.utils.metrics import MetricsLogger
 from videop2p_tpu.utils.profiling import phase_timer
 from videop2p_tpu.utils.video_io import save_videos_grid
 
+entry_imported()  # the end of the span `process.import`
+
 # preemption safety (ISSUE 9 satellite): SIGTERM/SIGINT set this event; the
-# training loop checks it at every chunk boundary, saves a final checkpoint
-# through the existing train/checkpoint.py machinery and exits cleanly.
-# Auto-resume (`resume_from_checkpoint: latest`) then continues
-# BIT-IDENTICALLY: per-step noise keys derive from (run key, absolute step)
-# inside train_steps, so the resume boundary cannot change the noise
-# sequence — tests/test_train.py pins interrupted+resumed == uninterrupted.
+# loop saves a final checkpoint at the next chunk boundary and exits cleanly.
+# Auto-resume (`resume_from_checkpoint: latest`) then continues BIT-IDENTICALLY
+# (per-step noise keys derive from the run key and the absolute step inside
+# train_steps; tests/test_train.py pins interrupted+resumed == uninterrupted).
 _PREEMPT_EVENT = threading.Event()
 
 

@@ -34,6 +34,7 @@ from videop2p_tpu.cli.common import (
 )
 from videop2p_tpu.core import DependentNoiseSampler
 from videop2p_tpu.obs import instrumented_jit, program_label
+from videop2p_tpu.obs.spans import entry_imported
 from videop2p_tpu.data import load_frame_sequence
 from videop2p_tpu.models import decode_video
 from videop2p_tpu.pipelines import (
@@ -46,6 +47,7 @@ from videop2p_tpu.pipelines import (
 from videop2p_tpu.utils.profiling import phase_timer
 from videop2p_tpu.utils.video_io import save_video_gif
 
+entry_imported()  # the end of the span `process.import`
 # module-level working-point constants (run_videop2p.py:32-40)
 NUM_DDIM_STEPS = 50
 GUIDANCE_SCALE = 7.5
@@ -53,13 +55,11 @@ MASK_TH = (0.3, 0.3)
 
 
 class EditResult(tuple):
-    """What :func:`main` returns: the ``(inversion_gif, edit_gif)`` pair —
-    unpacks as it always did — carrying what the run computed as attributes
-    for in-process callers (``chip_smoke.py``): ``branch`` ("cached" or
-    "live"), ``src_err`` (cached branch: max |replayed source − encoded
+    """What :func:`main` returns: the ``(inversion_gif, edit_gif)`` pair,
+    carrying for in-process callers (``chip_smoke.py``) ``branch`` ("cached"
+    or "live"), ``src_err`` (cached branch: max |replayed source − encoded
     clip|, else None), ``videos`` ((P, F, H, W, 3) in [0, 1]), ``latents``
-    (the edited (P, F, h, w, C) latents, float32) and ``latent_devices``
-    (ids of the devices the program left them on)."""
+    (the edited (P, F, h, w, C) latents, float32) and ``latent_devices``."""
 
     def __new__(cls, inversion_gif: str, edit_gif: str, **fields):
         self = super().__new__(cls, (inversion_gif, edit_gif))

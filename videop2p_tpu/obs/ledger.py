@@ -54,8 +54,7 @@ __all__ = [
     "suppress_compile_events",
 ]
 
-# the active-ledger stack: CLI/bench push one ledger for the whole run;
-# nested ledgers (tests) shadow the outer one
+# the active-ledger stack: a run's one ledger; nested ones (tests) shadow it
 _ACTIVE: List["RunLedger"] = []
 _ACTIVE_LOCK = threading.Lock()
 
@@ -243,6 +242,7 @@ class RunLedger:
         latency: bool = False,
         max_bytes: Optional[int] = None,
     ):
+        self._opened_ns = time.time_ns()  # `process.ledger_open` starts
         self.path = path
         self.run_id = run_id or uuid.uuid4().hex[:12]
         os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
@@ -259,17 +259,13 @@ class RunLedger:
             self._bytes = 0
         self._rotations = 0
         self._lock = threading.Lock()
-        # optional flight-recorder tee (obs/flight.py, ISSUE 18): when an
-        # IncidentManager attaches a FlightRecorder here, every event
-        # record is ALSO appended to its bounded ring — one deque append;
-        # with flight=None (the default) the extra cost is one attribute
-        # check and the written stream is bit-exact either way.
+        # optional flight-recorder tee (obs/flight.py): an attached
+        # FlightRecorder's bounded ring ALSO gets every event record; with
+        # flight=None (the default) the written stream is bit-exact.
         self.flight: Optional[Any] = None
         # program-analysis observers (ISSUE 19): callbacks fired with
-        # (program, record) on every program_analysis event — the serving
-        # CostModel registers here to mine static costs as they compile.
-        # Empty list (the default) adds one truthiness check; observers
-        # never raise into the ledger.
+        # (program, record) on every program_analysis event (the serving
+        # CostModel's); they never raise into the ledger.
         self.analysis_observers: List[Any] = []
         self._t0 = time.perf_counter()
         self._closed = False
@@ -562,6 +558,10 @@ class RunLedger:
             if not self._activated:
                 _ACTIVE.append(self)
                 self._activated = True
+                if self._opened_ns is not None:  # the first activation only
+                    self.tracer.process_pending = (self._opened_ns,
+                                                   time.time_ns())
+                    self._opened_ns = None
         return self
 
     def close(self) -> None:

@@ -26,27 +26,39 @@ the :class:`Tracer` of the active ledger, and enters a
 that an open profiler session shows the region in the xplane's host plane
 on the clock the device ops are on. ``utils.profiling.phase_timer`` and
 ``obs.ledger.instrumented_jit`` are thin callers of it.
+
+The ``process`` span reaches back from a ledger's first live span to the
+process's own start, as the kernel records it, so that what ran before the
+ledger existed (the interpreter, ``import jax``, the backend's start-up, the
+package's import, the ledger's construction) is on the same record.
 """
 
 from __future__ import annotations
 
 import contextvars
+import os
+import threading
 import time
 import uuid
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
+import videop2p_tpu
+
 __all__ = [
+    "BENCHMARK_PROCESS_SPAN_NAMES",
     "BENCHMARK_SPAN_NAMES",
     "SPAN_EVENT_FIELDS",
     "SPAN_SEGMENTS",
     "Tracer",
     "current_span",
+    "entry_imported",
     "format_traceparent",
     "make_span_id",
     "make_trace_id",
     "parse_traceparent",
+    "process_start_ns",
     "span",
 ]
 
@@ -95,6 +107,49 @@ BENCHMARK_SPAN_NAMES = (
     "program.analysis",
     "program.execute",
 )
+
+# The set-up's spans before and beside the root that `benchmark/layer_metrics/`
+# reads (benchmark/harness/process_spans.py keeps the same tuple as
+# READ_NAMES; tests/test_spans.py holds the two equal and a tiny `main` to
+# emitting each)
+BENCHMARK_PROCESS_SPAN_NAMES = (
+    "process",
+    "process.import",
+    "metrics.tensorboard_writer",
+)
+
+# the kernel's record of this process; its 22nd field is the start, in clock
+# ticks since boot
+_PROC_STAT = "/proc/self/stat"
+
+# wall clock at the end of the entry CLI's module-level imports: where
+# `process.import` ends (it starts at videop2p_tpu.IMPORT_NS)
+_ENTRY_IMPORTED_NS: Optional[int] = None
+_PROCESS_LOCK = threading.Lock()
+
+
+def entry_imported() -> None:
+    """Stamp the end of the entry module's imports; each CLI that opens a
+    ledger calls it at the foot of its import block."""
+    global _ENTRY_IMPORTED_NS
+    _ENTRY_IMPORTED_NS = time.time_ns()
+
+
+def process_start_ns() -> Tuple[int, str]:
+    """``(wall_ns, anchor)`` of this process's start: the kernel's start
+    time (``anchor`` ``"proc"``, good to a clock tick, 10 ms), set against
+    the boot clock and the wall clock read together; where ``/proc`` cannot
+    be read, the package's first line (``"import"``)."""
+    try:
+        with open(_PROC_STAT) as f:
+            stat = f.read()
+        # the command name in field 2 may hold spaces and parentheses
+        ticks = int(stat[stat.rindex(")") + 1:].split()[19])
+        age_s = (time.clock_gettime(time.CLOCK_BOOTTIME)
+                 - ticks / os.sysconf("SC_CLK_TCK"))
+        return time.time_ns() - int(age_s * 1e9), "proc"
+    except (OSError, ValueError, IndexError, AttributeError):
+        return videop2p_tpu.IMPORT_NS, "import"
 
 
 def make_trace_id() -> str:
@@ -149,6 +204,9 @@ class Tracer:
     def __init__(self, ledger=None, *, enabled: bool = False):
         self.ledger = ledger
         self.enabled = bool(enabled) and ledger is not None
+        # (start, end) wall ns of the ledger's construction, set when the
+        # ledger activates, until its first live span writes `process`
+        self.process_pending: Optional[Tuple[int, int]] = None
 
     def emit(self, name: str, *, trace_id: str, span_id: str,
              parent_id: Optional[str] = None,
@@ -170,6 +228,30 @@ class Tracer:
         fields.update(attrs)
         self.ledger.event("span", **fields)
         return fields
+
+    def write_process(self, end_ns: int) -> None:
+        """The ``process`` span, from the process's start to ``end_ns`` (where
+        the ledger's first live span opens), with its children
+        ``process.import`` (the package's first line to the end of the entry
+        CLI's imports, where a CLI stamped it) and ``process.ledger_open``.
+        What lies between them is the caller's: left as gaps."""
+        with _PROCESS_LOCK:  # two threads' first spans write it once
+            opened, self.process_pending = self.process_pending, None
+        if opened is None:
+            return
+        start_ns, anchor = process_start_ns()
+        trace_id, span_id = self.ledger.trace_id, make_span_id()
+        children = [("process.ledger_open",) + opened]
+        if _ENTRY_IMPORTED_NS is not None:
+            children.insert(0, ("process.import", videop2p_tpu.IMPORT_NS,
+                                _ENTRY_IMPORTED_NS))
+        for name, begin, end in children:
+            self.emit(name, trace_id=trace_id, span_id=make_span_id(),
+                      parent_id=span_id, wall_ns=begin,
+                      duration_s=(end - begin) * 1e-9)
+        self.emit("process", trace_id=trace_id, span_id=span_id,
+                  wall_ns=start_ns, duration_s=(end_ns - start_ns) * 1e-9,
+                  anchor=anchor)
 
 
 # the innermost open LIVE span of this thread / context: a child's
@@ -263,6 +345,8 @@ class span:
             self.span_id = make_span_id()
             self._token = _CURRENT.set(self)
             self._wall_ns = time.time_ns()
+            if self._tracer.process_pending is not None:
+                self._tracer.write_process(self._wall_ns)
         self._annotation = jax.profiler.TraceAnnotation(self.name)
         self._annotation.__enter__()
         self._open = True

@@ -44,12 +44,18 @@ class MetricsLogger:
         self._ledger = ledger
         self._tb = None
         if use_tensorboard:
-            try:
-                from torch.utils.tensorboard import SummaryWriter
+            from videop2p_tpu.obs.spans import span
 
-                self._tb = SummaryWriter(
-                    log_dir=os.path.join(run_dir, "tb"), comment=project
-                )
+            try:
+                # the writer's import pulls in torch (and TensorFlow where
+                # installed): seconds of a run's set-up
+                with span("metrics.tensorboard_writer",
+                          tracer=getattr(ledger, "tracer", None)):
+                    from torch.utils.tensorboard import SummaryWriter
+
+                    self._tb = SummaryWriter(
+                        log_dir=os.path.join(run_dir, "tb"), comment=project
+                    )
             except Exception:
                 self._tb = None  # tensorboard optional; JSONL always written
 
