@@ -818,12 +818,11 @@ def test_a_ledger_whose_tracer_is_off_writes_no_process(tmp_path):
 
 
 def test_the_tensorboard_writer_is_a_span_under_the_logger(
-        tiny_tune_ledger, tmp_path, monkeypatch):
-    """On a tiny ``main`` the writer's import and construction are one span
-    under ``tune.metrics_logger``; where the import fails it closes
-    ``error`` and the logger goes on with its JSONL alone."""
-    import sys
-
+        tiny_tune_ledger, tmp_path):
+    """On a tiny ``main`` the event file writer's construction is one span
+    under ``tune.metrics_logger``, naming its format; where the writer
+    cannot be built (a plain file where its ``tb/`` directory goes) the span
+    closes ``error`` and the logger goes on with its JSONL alone."""
     from videop2p_tpu.utils.metrics import MetricsLogger
 
     spans = [e for e in tiny_tune_ledger if e["event"] == "span"]
@@ -831,11 +830,14 @@ def test_the_tensorboard_writer_is_a_span_under_the_logger(
     logger = _one(spans, "tune.metrics_logger")
     assert writer["parent_id"] == logger["span_id"]
     assert writer["duration_s"] <= logger["duration_s"]
-    monkeypatch.setitem(sys.modules, "torch.utils.tensorboard", None)
+    assert writer["status"] == "ok" and writer["format"] == "tfevents"
+    run = tmp_path / "run"
+    run.mkdir()
+    (run / "tb").write_text("not a directory")
     path = str(tmp_path / "ledger.jsonl")
     with RunLedger(path) as led:
         with span("tune.metrics_logger"):
-            metrics = MetricsLogger(str(tmp_path / "run"), ledger=led)
+            metrics = MetricsLogger(str(run), ledger=led)
         metrics.log(1, {"train_loss": 1.0})
         metrics.close()
     failed = _one(_spans(path), "metrics.tensorboard_writer")

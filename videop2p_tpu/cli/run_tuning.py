@@ -439,7 +439,7 @@ def main(
         # TensorBoard trackers, run_tuning.py:234,337,377-378); with an active
         # ledger every logged step also becomes a ledger `metric` event
         lr_schedule = make_lr_schedule(tune_cfg)
-        with span("tune.metrics_logger"):  # imports TensorBoard's writer
+        with span("tune.metrics_logger"):  # opens the TensorBoard event file
             metrics = MetricsLogger(output_dir, ledger=run_ledger)
         losses = []
         grad_norms = []  # telemetry mode only: per-step pre-clip global norm
