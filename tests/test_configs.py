@@ -136,6 +136,30 @@ def test_third_token_family_config_loads_and_builds_the_published_model():
         Cohere2MoeConfig.from_dict({**cfg["model"], "n_group": 8})
 
 
+def test_fourth_token_family_config_loads_and_builds_the_published_model():
+    """Keye-VL-2.0's YAML loads through ``load_config``, names a known
+    family, and its ``model`` dict is the published config.json (the nested
+    ``rope_scaling`` and ``sa_config`` as published) at this chip's share
+    (4 whole layers of 48, 1/8 of the vocabulary; every head,
+    every expert; no width cut)."""
+    from videop2p_tpu.cli.common import MODEL_FAMILIES, check_model_family
+    from videop2p_tpu.models.keye_vl2 import KeyeVL2Config
+
+    cfg = load_config(os.path.join(ROOT, "configs", "keye-vl2-30b-a3b-s1-tune.yaml"))
+    assert check_model_family(cfg["model_family"]) == "keye_vl2"
+    assert cfg["model_family"] in MODEL_FAMILIES
+    model = KeyeVL2Config.from_dict(cfg["model"])
+    model.check()
+    published = KeyeVL2Config()
+    cut = {"num_hidden_layers": 4, "vocab_size": 18992}
+    for f in inspect.signature(KeyeVL2Config).parameters:
+        assert getattr(model, f) == cut.get(f, getattr(published, f)), f
+    assert list(cfg["trainable_modules"]) == ["q_proj"]
+    assert cfg["train_data"]["n_tokens"] == 16384
+    with pytest.raises(ValueError, match="unknown KeyeVL2Config keys"):
+        KeyeVL2Config.from_dict({**cfg["model"], "n_shared_experts": 1})
+
+
 def test_unknown_model_family_is_rejected_with_the_known_ones():
     from videop2p_tpu.cli.common import check_model_family
 
@@ -144,7 +168,7 @@ def test_unknown_model_family_is_rejected_with_the_known_ones():
     assert "'sdxl'" in str(err.value)
     assert "unet3d" in str(err.value) and "deepseek_v32" in str(err.value)
     assert "granitemoehybrid" in str(err.value)
-    assert "cohere2_moe" in str(err.value)
+    assert "cohere2_moe" in str(err.value) and "keye_vl2" in str(err.value)
     with pytest.raises(ValueError, match="known:"):
         tune_main(pretrained_model_path=None, output_dir="unused",
                   train_data={}, model_family="nope")

@@ -484,3 +484,36 @@ def test_reference_layer_by_layer_is_the_whole_reference(model):
         assert float(jnp.abs(mu - layered["mu"][k]).max()) < 1e-5 * float(jnp.abs(mu).max()), k
     for a, b in zip(whole["chosen"], layered["chosen"]):
         assert a["mask"].dtype == jnp.uint8 and bool(jnp.array_equal(a["mask"], b["mask"]))
+
+
+def test_select_keys_takes_the_scorer_from_its_caller(model):
+    """``select_keys`` reads its query input, widths, rotary width and top-k
+    from its caller: handed this family's (``scorer_widths``: 4 index heads
+    of 16, the rotary's 8 dims, 16 keys) it gives the reference's selection
+    of the family, key for key, on layer 0's normed input, as the layer's
+    own ``_layer_mask`` does; a smaller top-k is another selection."""
+    cfg, params, ids = model
+    assert ds.scorer_widths(cfg) == dict(heads=4, head_dim=16, rope_dim=8,
+                                         topk=16)
+    p = params["layers_0"]
+    angles = ds.rope_angles(cfg, jnp.arange(T))
+    x = ds._rms_norm(params["embed"]["embedding"][ids], p["input_norm"]["scale"],
+                     cfg.rms_norm_eps)
+    c_q = ds._rms_norm(ds._dense(x, p["attn"]["q_a_proj"]["kernel"]),
+                       p["attn"]["q_a_norm"]["scale"], cfg.rms_norm_eps)
+
+    def mask(**widths):
+        return jax.jit(lambda: ds.select_keys(p["attn"]["indexer"], x, c_q,
+                                              angles, **widths))()
+
+    arch = arch_of(cfg)
+    W = ref.Weights(named(params), "params/layers_0/attn/indexer/")
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda: ref.selection(
+            W, arch, ref._Nx("float32"), x, c_q, ref.rope_angles(arch, T)))()
+    got = mask(**ds.scorer_widths(cfg))
+    assert bool(jnp.array_equal(got, want))
+    assert bool(jnp.array_equal(got, ds._layer_mask(p, cfg, params["embed"][
+        "embedding"][ids], angles)))
+    assert not bool(jnp.array_equal(got, mask(**dict(
+        ds.scorer_widths(cfg), topk=8))))

@@ -355,3 +355,71 @@ def test_on_the_cpu_path_the_policy_keeps_nothing(monkeypatch, family):
         assert bool(jnp.array_equal(g, w))
         moved += float(jnp.abs(w).max()) > 0
     assert moved > 4
+
+
+# ------------------------------------- a selection over grouped-query heads
+
+GROUPED_CASES = {
+    # tokens, (query heads, key / value heads), tiles, heads a cell, keys a
+    # query (None: every earlier key)
+    "groups_8_to_1": (256, (8, 1), (128, 128), (4, 2, 1), 40),
+    "groups_1_to_1": (256, (2, 2), (128, 128), (2, 1), 40),
+    "selection_narrower_than_a_tile": (384, (4, 2), (128, 128), (2, 1), 20),
+    "every_key_selected": (256, (4, 1), (128, 128), (2, 1), None),
+}
+
+
+@pytest.mark.parametrize("case", GROUPED_CASES)
+def test_grouped_selection_equals_the_xla_path(monkeypatch, case):
+    """``grouped_selected_attention`` in interpret mode against the XLA path
+    of the fourth token family (``keye_vl2._chunked_selected_attend``, the
+    row blocks of ``deepseek._chunked_attend``'s form): the values and dq,
+    dk, dv, float32 on both sides; with every earlier key selected it is
+    ``causal_attention`` to the bit."""
+    from videop2p_tpu.models import keye_vl2
+
+    t_len, (hq, hkv), tiles, cell_heads, keys = GROUPED_CASES[case]
+    monkeypatch.setattr(sa, "_TILES", (tiles,))
+    monkeypatch.setattr(sa, "_HEADS", cell_heads)
+    monkeypatch.setattr(ds, "Q_CHUNK", 128)
+    monkeypatch.setattr(ds, "ATTN_ROWS", 64)
+    assert sa.selected_attention_tiles(t_len, hq, 128, 0, 128,
+                                       jnp.float32)[:2] == tiles
+    mask = (jnp.tril(jnp.ones((t_len, t_len), bool)) if keys is None
+            else selection(t_len, keys, seed=3))
+    ks = jax.random.split(jax.random.key(9), 4)
+    q = jax.random.normal(ks[0], (t_len, hq, 128), jnp.float32)
+    k = jax.random.normal(ks[1], (t_len, hkv, 128), jnp.float32)
+    v = jax.random.normal(ks[2], (t_len, hkv, 128), jnp.float32)
+    w = jax.random.normal(ks[3], (t_len, hq, 128), jnp.float32)
+    scale = 128 ** -0.5
+
+    def pair(attend):
+        out, vjp = jax.vjp(attend, q, k, v)
+        return (out,) + vjp(w)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda: pair(lambda q, k, v: sa.grouped_selected_attention(
+            q, k, v, mask, scale, True)))()
+        want = jax.jit(lambda: pair(lambda q, k, v: (
+            keye_vl2._chunked_selected_attend(q, k, v, mask, scale))))()
+        causal = jax.jit(lambda: pair(lambda q, k, v: sa.causal_attention(
+            q, k, v, scale, True)))()
+    for name, g, x, c in zip(("o", "dq", "dk", "dv"), got, want, causal):
+        assert g.shape == x.shape and bool(jnp.isfinite(g).all()), name
+        top = float(jnp.max(jnp.abs(x)))
+        assert float(jnp.max(jnp.abs(g - x))) < 1e-5 * top, name
+        if keys is None:
+            assert bool(jnp.array_equal(g, c)), name
+        else:
+            assert float(jnp.max(jnp.abs(c - x))) > 1e-3 * top, name
+
+
+def test_grouped_selection_fits_at_the_cells_shape():
+    """16384 tokens, 32 query heads of 128 on 4 key / value heads, bfloat16:
+    512 x 512 tiles, 8 heads a forward cell and 4 a backward cell (the
+    resident dQ of 4 heads is 2 x 4 x 128 x 16384 x 4 B = 67.1 MB)."""
+    assert sa.selected_attention_tiles(16384, 32, 128, 0, 128,
+                                       jnp.bfloat16) == sa.Tiles(512, 512, 8, 4)
+    assert sa._bwd_vmem_bytes(4, 16384, 512, 512, 128, 128, 2) <= sa._VMEM_BUDGET
+    assert sa._bwd_vmem_bytes(8, 16384, 512, 512, 128, 128, 2) > sa._VMEM_BUDGET

@@ -969,3 +969,43 @@ def test_third_token_family_main_emits_the_scopes_and_counters_read():
     metrics = _token_family_main_emits(cm, cm.Cohere2MoeConfig.tiny(),
                                        "command-a-plus-s8-tune.yaml")
     assert metrics[0]["window_tile_share"] == 1.0
+
+
+def test_fourth_token_family_main_emits_the_scopes_and_counters_read():
+    """The same for ``models/keye_vl2.SCOPES`` / ``COUNTERS``: the
+    projections, QK-norm and rotary under ``lm.attn_proj`` (what
+    ``attn_proj_ms.tune`` reads), the pair under ``lm.sparse_attention``,
+    the experts under ``lm.experts`` — forward and backward — and the
+    scorer's ``lm.indexer`` / ``lm.select`` in the forward only (no gradient
+    flows through it); ``keys_selected_mean`` a step beside the loss: 16 keys
+    of up to 32 a query at the tiny size, and the ties with the 16th."""
+    import types
+
+    from videop2p_tpu.models import keye_vl2 as ky
+
+    assert ky.SCOPES == ("lm.attn_proj", "lm.indexer", "lm.select",
+                         "lm.sparse_attention", "lm.router", "lm.experts",
+                         "lm.head_loss")
+    assert ky.COUNTERS == ("keys_selected_mean", "expert_load_max_over_mean",
+                           "held_pair_share")
+    cfg = ky.KeyeVL2Config.tiny()
+    scorer = ("lm.indexer", "lm.select")
+    differentiated = types.SimpleNamespace(
+        SCOPES=tuple(s for s in ky.SCOPES if s not in scorer),
+        COUNTERS=ky.COUNTERS, abstract_params=ky.abstract_params,
+        forward_loss=ky.forward_loss)
+    metrics = _token_family_main_emits(differentiated, cfg,
+                                       "keye-vl2-30b-a3b-s1-tune.yaml")
+    # ties with the 16th score are kept: a few keys over the count
+    assert 12.25 == (16 * 17 / 2 + 16 * 16) / 32 <= metrics[0][
+        "keys_selected_mean"] < 13
+    assert metrics[0]["held_pair_share"] == 1.0
+    params = ky.abstract_params(cfg, jnp.float32)["params"]
+    text = jax.jit(jax.grad(
+        lambda p, i: ky.forward_loss(p, cfg, i, jnp.float32)[0])).lower(
+            params, jax.ShapeDtypeStruct((32,), jnp.int32)).as_text(
+                debug_info=True)
+    names = set(re.findall(r'loc\("([^"]*)"', text))
+    for scope in scorer:
+        mine = [n for n in names if scope in n]
+        assert mine and not [n for n in mine if "transpose(" in n], scope

@@ -36,6 +36,7 @@ from videop2p_tpu.ops.grouped_experts import grouped_expert_tiles
 from videop2p_tpu.ops.groupnorm import fits_fused_group_norm, fused_group_norm
 from videop2p_tpu.ops.selected_attention import (
     causal_attention,
+    grouped_selected_attention,
     keep_attention_outputs,
     selected_attention_tiles,
     selected_key_attention,
@@ -67,7 +68,10 @@ EXPERT_LAYERS = {"granite_18x768": (32768, 4096, (18, 72), 768, 10),
                  # the third family's: 4096 x 4096 experts in inner tiles of
                  # 1024, 16 held of 128, beside 2048 held columns of its four
                  # shared experts
-                 "command_16x4096": (32768, 4096, (16, 128), 4096, 8, 2048)}
+                 "command_16x4096": (32768, 4096, (16, 128), 4096, 8, 2048),
+                 # the fourth family's: a WHOLE layer, all 128 experts of
+                 # 768, and no shared expert
+                 "keye_128x768": (16384, 2048, (128, 128), 768, 8, 0)}
 # the third family's sliding layers (models/cohere2_moe.py): 16 query heads on
 # ONE key / value head of 128, a window of 4096 keys, at the cell's document
 # and at an eighth of it (one window: the causal walk)
@@ -251,6 +255,37 @@ def test_windowed_attention_compiles(one_chip, t_len, grad):
             assert "lm.window_attention" in name and "lm.attention" not in name
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+def test_grouped_selected_attention_compiles(one_chip, grad):
+    """The pair WITH its selection operand over grouped-query heads at the
+    fourth family's cell — 16384 tokens, 32 query heads of 128 on 4 key /
+    value heads (8 heads a forward cell, 4 a backward cell) — forward alone
+    and through ``jax.grad`` of q, k and v; the kernels lie under
+    ``lm.sparse_attention``, what ``sparse_attention_ms.tune`` and the
+    roofline read."""
+    t_len = 16384
+    assert selected_attention_tiles(t_len, 32, 128, 0, 128, jnp.bfloat16) is not None
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, k, v, mask):
+        return grouped_selected_attention(q, k, v, mask, 128 ** -0.5)
+
+    want = {"lm_selected_attention": 1}
+    if grad:
+        want["lm_selected_attention_bwd"] = 1
+        fn = jax.grad(lambda *ops, fn=fn: jnp.sum(
+            fn(*ops).astype(jnp.float32) ** 2), argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(arg((t_len, 32, 128)), arg((t_len, 4, 128)),
+                             arg((t_len, 4, 128)),
+                             arg((t_len, t_len), jnp.bool_)).compile().as_text()
+    assert tpu_custom_call_counts(text) == want
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            assert "lm.sparse_attention" in line.split('op_name="')[1].split('"')[0]
+
+
 def _family_attention(family):
     """``(module, cfg, tokens, layer)`` of a token family at its cell: the
     configuration the tuner's YAML states, and in ``layer(p, x, *given)`` the
@@ -261,7 +296,8 @@ def _family_attention(family):
     name, _, kind = family.partition(":")
     tune = load_config({"deepseek_v32": "configs/deepseek-v32-s16-tune.yaml",
                         "granitemoehybrid": "configs/granite-4.0-h-small-s4-tune.yaml",
-                        "cohere2_moe": "configs/command-a-plus-s8-tune.yaml"}[name])
+                        "cohere2_moe": "configs/command-a-plus-s8-tune.yaml",
+                        "keye_vl2": "configs/keye-vl2-30b-a3b-s1-tune.yaml"}[name])
     module, config_cls = _token_families()[name]
     cfg = config_cls.from_dict(tune["model"])
     t_len = tune["train_data"]["n_tokens"]
@@ -272,6 +308,10 @@ def _family_attention(family):
     elif name == "granitemoehybrid":
         def layer(p, x):
             return x + module.attention(p, cfg, x)
+    elif name == "keye_vl2":
+        def layer(p, x, mask):
+            return x + module.attention(
+                p, cfg, x, module.rope_angles(cfg, jnp.arange(t_len)), mask)
     else:
         def layer(p, x):
             angles = (module.rope_angles(cfg, jnp.arange(t_len))
@@ -281,7 +321,8 @@ def _family_attention(family):
 
 
 @pytest.mark.parametrize("family", ["deepseek_v32", "granitemoehybrid",
-                                    "cohere2_moe:sliding", "cohere2_moe:full"])
+                                    "cohere2_moe:sliding", "cohere2_moe:full",
+                                    "keye_vl2"])
 def test_a_layers_recompute_keeps_the_attention_pairs_outputs(one_chip,
                                                               monkeypatch,
                                                               family):
@@ -303,7 +344,7 @@ def test_a_layers_recompute_keeps_the_attention_pairs_outputs(one_chip,
     args = [jax.tree.map(lambda spec: arg(spec[0]), attn,
                          is_leaf=ds._is_spec),
             arg((t_len, cfg.hidden_size))]
-    if family == "deepseek_v32":
+    if family in ("deepseek_v32", "keye_vl2"):
         args.append(arg((t_len, t_len), jnp.bool_))
 
     def kernels(policy):
@@ -336,8 +377,8 @@ def test_fused_group_norm_compiles(one_chip, slab, act):
 def _expert_layer(one_chip, t_len, hidden, held, inner, k, shared=128):
     """``held_expert_ffn`` GIVEN a routing, as every token family calls it,
     and its abstract arguments: the held experts' stacked matrices, a shared
-    expert (``shared``: the columns held), ``x``, the experts a token and
-    their gates."""
+    expert (``shared``: the columns held; 0, none), ``x``, the experts a
+    token and their gates."""
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -346,7 +387,9 @@ def _expert_layer(one_chip, t_len, hidden, held, inner, k, shared=128):
                 "up_proj": {"kernel": arg(lead + (hidden, width))},
                 "down_proj": {"kernel": arg(lead + (width, hidden))}}
 
-    p = {"experts": swiglu((held[0],), inner), "shared": swiglu((), shared)}
+    p = {"experts": swiglu((held[0],), inner)}
+    if shared:
+        p["shared"] = swiglu((), shared)
 
     def routed(p, x, experts, gates):
         return ds.held_expert_ffn(p, x, experts, gates, (0, held[0]))[0]

@@ -88,6 +88,7 @@ __all__ = [
     "init_params",
     "attention",
     "select_keys",
+    "scorer_widths",
     "route",
     "select_experts",
     "held_expert_ffn",
@@ -408,15 +409,26 @@ def _kth_largest(scores, k: int):
     return lax.bitcast_convert_type(back, jnp.float32)
 
 
-def select_keys(p, cfg: DeepSeekV32Config, x, c_q, angles):
-    """(T, T) bool: ``mask[t, s]`` is True where key ``s`` is in S_t. Query
-    chunk ``c`` scores the keys up to its own end only."""
+def scorer_widths(cfg: DeepSeekV32Config) -> Dict[str, int]:
+    """This family's scorer as :func:`select_keys` takes it."""
+    return dict(heads=cfg.index_n_heads, head_dim=cfg.index_head_dim,
+                rope_dim=cfg.qk_rope_head_dim, topk=cfg.index_topk)
+
+
+def select_keys(p, x, q_in, angles, *, heads: int, head_dim: int,
+                rope_dim: int, topk: int):
+    """(T, T) bool: ``mask[t, s]`` is True where key ``s`` is in S_t, the
+    ``topk`` largest index scores over s <= t. ``heads`` index heads of
+    ``head_dim`` read their queries from ``q_in`` (``wq_b``), the one key and
+    the head weights from ``x``; rotary (halves, ``angles``) on the first
+    ``rope_dim`` dims of both. Query chunk ``c`` scores the keys up to its
+    own end only."""
     t_len = x.shape[0]
     qc = min(Q_CHUNK, t_len)
     assert t_len % qc == 0, (t_len, qc)
-    nh, hd, rd = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    nh, hd, rd = heads, head_dim, rope_dim
     with jax.named_scope("lm.indexer"):
-        q = _dense(c_q, p["wq_b"]["kernel"]).reshape(t_len, nh, hd)
+        q = _dense(q_in, p["wq_b"]["kernel"]).reshape(t_len, nh, hd)
         q = jnp.concatenate(
             [_rotate(q[..., :rd], angles, interleaved=False), q[..., rd:]], -1)
         k = _layer_norm(_dense(x, p["wk"]["kernel"]), p["k_norm"]["scale"],
@@ -432,7 +444,7 @@ def select_keys(p, cfg: DeepSeekV32Config, x, c_q, angles):
         """``ir`` queries from position ``first`` against ``keys``."""
         kb = keys.shape[0]
         causal = jnp.arange(kb)[None, :] <= (first + jnp.arange(ir))[:, None]
-        if kb <= cfg.index_topk:
+        if kb <= topk:
             return causal
         with jax.named_scope("lm.indexer"):
             # float32 logits: rounded to bfloat16 (0.4 % each) they flip a
@@ -442,7 +454,7 @@ def select_keys(p, cfg: DeepSeekV32Config, x, c_q, angles):
             score = jnp.sum(jax.nn.relu(logits) * w_rows[:, :, None], axis=1)
         with jax.named_scope("lm.select"):
             score = jnp.where(causal, score, -jnp.inf)
-            thr = _kth_largest(score, cfg.index_topk)
+            thr = _kth_largest(score, topk)
             return causal & (score >= thr[:, None])
 
     rows = []
@@ -526,8 +538,8 @@ def attention(p, cfg: DeepSeekV32Config, x, angles, mask=None):
         k_nope, v = kvb[..., :nd], kvb[..., nd:]
     if mask is None:
         # no gradient through the scorer or into its inputs
-        mask = select_keys(p["indexer"], cfg, lax.stop_gradient(x),
-                           lax.stop_gradient(c_q), angles)
+        mask = select_keys(p["indexer"], lax.stop_gradient(x),
+                           lax.stop_gradient(c_q), angles, **scorer_widths(cfg))
     with jax.named_scope("lm.sparse_attention"):
         if _kernel_applies(q_nope, q_rope, v):
             o = selected_key_attention(q_nope, q_rope, k_nope, k_rope, v, mask,
@@ -719,8 +731,10 @@ def held_expert_ffn(p, x, experts, gates, held: Tuple[int, int]):
     (:func:`expert_blocks`, under ``lm.router``), and
     :func:`_grouped_swiglu` walks the blocks under ``lm.experts`` — as the
     Pallas pair of ``ops/grouped_experts.py`` on the TPU, as an XLA loop
-    elsewhere. ``p["shared"]`` runs on every token. Every token family's
-    expert layer calls this with its own router's ``(experts, gates)``."""
+    elsewhere. ``p["shared"]`` runs on every token; a layer without one
+    gives ``shared`` None and no ``routed_over_shared``. Every token
+    family's expert layer calls this with its own router's ``(experts,
+    gates)``."""
     t_len, k = experts.shape
     en = held[1]
     block = EXPERT_BLOCK
@@ -731,8 +745,10 @@ def held_expert_ffn(p, x, experts, gates, held: Tuple[int, int]):
         routed = _grouped_swiglu(x, tok, gate, tbl, ex["gate_proj"]["kernel"],
                                  ex["up_proj"]["kernel"],
                                  ex["down_proj"]["kernel"], block)
-    with jax.named_scope("lm.shared_expert"):
-        shared = _swiglu(p["shared"], x)
+    shared = None
+    if "shared" in p:
+        with jax.named_scope("lm.shared_expert"):
+            shared = _swiglu(p["shared"], x)
     held_pairs = jnp.sum(count).astype(jnp.float32)
     square = lambda a: jnp.sum(jnp.square(a.astype(jnp.float32)))  # noqa: E731
     counters = {
@@ -741,10 +757,12 @@ def held_expert_ffn(p, x, experts, gates, held: Tuple[int, int]):
             jnp.max(count) * en / jnp.maximum(held_pairs, 1.0),
         # share of routed (token, expert) pairs that land on held experts
         "held_pair_share": held_pairs / (t_len * k),
+    }
+    if shared is not None:
         # the held experts' part over the shared expert's, root mean square:
         # it carries the gates' scale whatever tokens were chosen
-        "routed_over_shared": jnp.sqrt(square(routed) / square(shared)),
-    }
+        counters["routed_over_shared"] = jnp.sqrt(square(routed)
+                                                  / square(shared))
     return routed, shared, counters
 
 
@@ -784,7 +802,7 @@ def _layer_mask(p, cfg, x, angles):
     with jax.named_scope("lm.mla_proj"):
         c_q = _rms_norm(_dense(x, a["q_a_proj"]["kernel"]),
                         a["q_a_norm"]["scale"], cfg.rms_norm_eps)
-    return select_keys(a["indexer"], cfg, x, c_q, angles)
+    return select_keys(a["indexer"], x, c_q, angles, **scorer_widths(cfg))
 
 
 def head_loss(norm_scale, matrix, x, ids, eps, *, tied: bool = False,

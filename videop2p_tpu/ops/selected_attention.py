@@ -30,7 +30,10 @@ scope ``lm.attention``. With a ``window`` (key s is seen by query t iff
 0 <= t - s < window) the step tables hold only the tile pairs that
 intersect that band, the in-tile rule is the band's, and the scope is
 ``lm.window_attention``; without one the program is the causal one, as it
-was before the window existed.
+was before the window existed. ``grouped_selected_attention`` hands the
+grouped-query heads the selection tile again (one selection for every head,
+scope ``lm.sparse_attention``): the MLA-shaped call's mask with the grouped
+calls' spread of the key / value heads and sum over a group.
 
 Precision as ``models.deepseek._attend``: float32 scores, statistics, dS and
 accumulators; the operands' dtype only as matmul operands. No key is
@@ -51,8 +54,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["selected_key_attention", "causal_attention",
-           "selected_attention_tiles", "causal_tile_pairs", "Tiles",
-           "KEPT_NAMES", "keep_attention_outputs"]
+           "grouped_selected_attention", "selected_attention_tiles",
+           "causal_tile_pairs", "Tiles", "KEPT_NAMES", "keep_attention_outputs"]
 
 _SCOPE = "lm.sparse_attention"
 
@@ -511,26 +514,31 @@ def causal_attention(q, k, v, scale: float, interpret: bool = False,
     return _causal_fwd(q, k, v, scale, interpret, window)[0]
 
 
-def _causal_fwd(q, k, v, scale, interpret, window):
+def _grouped_fwd(q, k, v, mask, scale, interpret, window, scope):
+    """The forward of both grouped-head calls: ``mask`` (T, T) bool or None
+    → o (T, H, D), the log-sum-exp and the selection as the kernels take it
+    (None without one)."""
     tiles = _causal_tiles(q)
-    with jax.named_scope(_CAUSAL_SCOPE if window is None else _WINDOW_SCOPE):
+    with jax.named_scope(scope):
+        mask_t = None if mask is None else mask.T.astype(jnp.int8)
         qh, kh, vh = _grouped_heads_first(q, k, v)
-        ot, lse = _kept(*_forward_call(qh, kh, vh, None, tiles, scale,
+        ot, lse = _kept(*_forward_call(qh, kh, vh, mask_t, tiles, scale,
                                        interpret, _band(q.shape[0], window)))
         o = ot.transpose(2, 0, 1)
-    return o, (q, k, v, o, lse)
+    return o, lse, mask_t
 
 
-def _causal_bwd(scale, interpret, window, res, g):
-    q, k, v, o, lse = res
+def _grouped_bwd(q, k, v, o, lse, mask_t, g, scale, interpret, window, scope):
+    """(dq, dk, dv) of :func:`_grouped_fwd`: the backward kernel over every
+    query head, then each key / value head's group summed."""
     tiles = _causal_tiles(q)
     t_len, heads, width = q.shape
     kv_heads = k.shape[1]
-    with jax.named_scope(_CAUSAL_SCOPE if window is None else _WINDOW_SCOPE):
+    with jax.named_scope(scope):
         qh, kh, vh = _grouped_heads_first(q, k, v)
         delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
         dqt, dk, dv = _backward_call(
-            qh, g.transpose(1, 0, 2), kh, vh, lse, delta.T[:, None, :], None,
+            qh, g.transpose(1, 0, 2), kh, vh, lse, delta.T[:, None, :], mask_t,
             tiles, scale, interpret, _band(t_len, window))
         dq = dqt.transpose(1, 3, 0, 2).reshape(t_len, heads, width) * scale
 
@@ -542,4 +550,44 @@ def _causal_bwd(scale, interpret, window, res, g):
                 gathered(dv).astype(v.dtype))
 
 
+def _causal_fwd(q, k, v, scale, interpret, window):
+    o, lse, _ = _grouped_fwd(q, k, v, None, scale, interpret, window,
+                             _CAUSAL_SCOPE if window is None else _WINDOW_SCOPE)
+    return o, (q, k, v, o, lse)
+
+
+def _causal_bwd(scale, interpret, window, res, g):
+    return _grouped_bwd(*res, None, g, scale, interpret, window,
+                        _CAUSAL_SCOPE if window is None else _WINDOW_SCOPE)
+
+
 causal_attention.defvjp(_causal_fwd, _causal_bwd)
+
+
+# --------------------------------------- a selection over grouped-query heads
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def grouped_selected_attention(q, k, v, mask, scale: float,
+                               interpret: bool = False):
+    """Softmax over the SELECTED keys with grouped-query heads: ``q`` (T, H,
+    D), ``k``, ``v`` (T, H_kv, D) with H a multiple of H_kv (query head ``i``
+    reads key / value head ``i // (H / H_kv)``), ``mask`` (T, T) bool and
+    causal, the same for every head → (T, H, D). The kernel pair with its
+    mask operand, the heads spread and gathered as :func:`causal_attention`
+    does; differentiable in all but the mask, under the scope
+    ``lm.sparse_attention``. Raises where :func:`selected_attention_tiles`
+    refuses the shape (asked as ``(T, H, D, 0, D)``)."""
+    return _grouped_fwd(q, k, v, mask, scale, interpret, None, _SCOPE)[0]
+
+
+def _selected_fwd(q, k, v, mask, scale, interpret):
+    o, lse, mask_t = _grouped_fwd(q, k, v, mask, scale, interpret, None, _SCOPE)
+    return o, (q, k, v, o, lse, mask_t)
+
+
+def _selected_bwd(scale, interpret, res, g):
+    return _grouped_bwd(*res, g, scale, interpret, None, _SCOPE) + (None,)
+
+
+grouped_selected_attention.defvjp(_selected_fwd, _selected_bwd)
