@@ -214,3 +214,114 @@ def test_dispatch_rules():
 
     with pytest.raises(ValueError, match="unknown frame attention impl"):
         make_frame_attention_fn("nope")
+
+
+def _pallas_calls(jaxpr) -> dict:
+    """Pallas calls of a jaxpr by kernel name, sub-jaxprs included."""
+    import collections
+
+    counts = collections.Counter(
+        eqn.params["name"] for eqn in jaxpr.eqns
+        if eqn.primitive.name == "pallas_call")
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            counts.update(_pallas_calls(sub))
+    return dict(counts)
+
+
+def _named(jaxpr) -> list:
+    """``name`` equations of a jaxpr, sub-jaxprs included."""
+    found = [eqn for eqn in jaxpr.eqns if eqn.primitive.name == "name"]
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _named(sub)
+    return found
+
+
+def _site(attend):
+    """A frame-attention site as a UNet block holds one: q from the stream,
+    frame 0's k / v, the output back onto the stream through a nonlinearity."""
+    def site(x):  # (B, F, H, N, D)
+        return x + jnp.tanh(attend(x * 1.5, x[:, 0], x[:, 0] * 0.5))
+    return site
+
+
+@pytest.mark.parametrize("frames", [2, 3])
+def test_the_policy_keeps_the_forward_kernel_out_of_the_recompute(frames):
+    """Two checkpointed sites over the kernel pair, in interpret mode: with
+    ``keep_frame_attention_output`` the gradient holds each site's forward
+    kernel ONCE (a bare ``jax.checkpoint`` runs it again in the recompute),
+    the backward kernel once either way, and the gradient is the bare
+    checkpoint's and the uncheckpointed stack's to the bit."""
+    from videop2p_tpu.ops.attention import (
+        fused_frame_attention,
+        keep_frame_attention_output,
+    )
+
+    site = _site(lambda q, k, v: fused_frame_attention(q, k, v, 128, True))
+    x = jax.random.normal(jax.random.key(12), (1, frames, 2, 256, 8))
+
+    def grad(wrap):
+        return jax.grad(lambda x: jnp.sum(wrap(site)(wrap(site)(x)) ** 2))
+
+    kept = grad(lambda f: jax.checkpoint(f, policy=keep_frame_attention_output))
+    bare = grad(jax.checkpoint)
+    assert _pallas_calls(jax.make_jaxpr(kept)(x).jaxpr) == {
+        "fused_frame_attention": 2, "fused_frame_attention_bwd": 2}
+    assert _pallas_calls(jax.make_jaxpr(bare)(x).jaxpr) == {
+        "fused_frame_attention": 4, "fused_frame_attention_bwd": 2}
+    want = jax.jit(grad(lambda f: f))(x)
+    assert float(jnp.abs(want).max()) > 0
+    assert bool(jnp.array_equal(jax.jit(kept)(x), want))
+    assert bool(jnp.array_equal(jax.jit(bare)(x), want))
+
+
+def test_the_kernels_output_carries_the_name():
+    """The forward kernel's output, folded back to (B, F, H, N, D), is the
+    one value named ``FRAME_ATTENTION_KEPT``; outside a checkpoint the name is
+    an identity."""
+    from videop2p_tpu.ops.attention import (
+        FRAME_ATTENTION_KEPT,
+        fused_frame_attention,
+    )
+
+    q, k, v = _rand_qkv(jax.random.key(13), F=2, N=256, D=8)
+    fn = lambda q, k, v: fused_frame_attention(q, k, v, 128, True)  # noqa: E731
+    names = _named(jax.make_jaxpr(fn)(q, k, v).jaxpr)
+    assert [eqn.params["name"] for eqn in names] == [FRAME_ATTENTION_KEPT]
+    assert names[0].outvars[0].aval.shape == q.shape
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(fn)(q, k, v)),
+        np.asarray(dense_frame_attention(q, k, v)), atol=1e-5)
+
+
+@pytest.mark.parametrize("path", ["dense", "chunked", "fused_indivisible"])
+def test_off_the_kernel_no_value_carries_the_name(path):
+    """The dense and chunked paths, and the kernel's own fallback where the
+    query grid does not divide, name nothing: under the policy the recompute
+    is the bare checkpoint's, and the gradient equal to it to the bit."""
+    from videop2p_tpu.ops.attention import (
+        fused_frame_attention,
+        keep_frame_attention_output,
+    )
+
+    n = 192 if path == "fused_indivisible" else 1024
+    attend = {
+        "dense": dense_frame_attention,
+        "chunked": make_frame_attention_fn("chunked", q_chunk=256),
+        "fused_indivisible": lambda q, k, v: fused_frame_attention(
+            q, k, v, 256, True),
+    }[path]
+    site = _site(attend)
+    x = jax.random.normal(jax.random.key(14), (1, 1, 2, n, 4))
+
+    def grad(policy):
+        return jax.grad(lambda x: jnp.sum(
+            jax.checkpoint(site, policy=policy)(x) ** 2))
+
+    kept = grad(keep_frame_attention_output)
+    assert _named(jax.make_jaxpr(kept)(x).jaxpr) == []
+    assert _pallas_calls(jax.make_jaxpr(kept)(x).jaxpr) == {}
+    want = jax.jit(grad(None))(x)
+    assert float(jnp.abs(want).max()) > 0
+    assert bool(jnp.array_equal(jax.jit(kept)(x), want))

@@ -359,6 +359,51 @@ def test_a_layers_recompute_keeps_the_attention_pairs_outputs(one_chip,
         "lm_selected_attention": 2, "lm_selected_attention_bwd": 1}
 
 
+@pytest.mark.parametrize("shape", TUNE_ATTENTION_SHAPES[:2],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_a_blocks_recompute_keeps_the_frame_attention_output(one_chip,
+                                                             monkeypatch,
+                                                             shape):
+    """A UNet transformer site at the tune's 64² and 32² shapes, as the model
+    dispatches it on the chip, differentiated through the remat the UNet
+    wraps its blocks in: with ``remat_block`` (the policy
+    ``keep_frame_attention_output``) the compiled gradient holds the forward
+    frame-attention kernel ONCE, under a bare ``nn.remat`` twice — so this
+    fails if the name ever slips off the value the recompute needs."""
+    from flax import linen as nn
+
+    from videop2p_tpu.models import Transformer3DModel
+    from videop2p_tpu.models.unet_blocks import remat_block
+    from videop2p_tpu.ops.attention import make_frame_attention_fn
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, f, heads, n, d = shape
+    side = int(n ** 0.5)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x, context = arg((b, f, side, side, heads * d)), arg((b, 77, 768))
+
+    def kernels(wrap):
+        model = wrap(Transformer3DModel)(
+            heads=heads, dim_head=d, dtype=jnp.bfloat16,
+            frame_attention_fn=make_frame_attention_fn("auto"))
+        params = jax.tree.map(
+            lambda s: arg(s.shape, s.dtype),
+            jax.eval_shape(model.init, jax.random.key(0), x, context))
+        counts = _kernels(jax.grad(
+            lambda p, x, c: jnp.sum(model.apply(p, x, c).astype(jnp.float32) ** 2),
+            argnums=(0, 1)), params, x, context)
+        return {k: v for k, v in counts.items()
+                if k.startswith("fused_frame_attention")}
+
+    assert kernels(remat_block) == {
+        "fused_frame_attention": 1, "fused_frame_attention_bwd": 1}
+    assert kernels(nn.remat) == {
+        "fused_frame_attention": 2, "fused_frame_attention_bwd": 1}
+
+
 @pytest.mark.parametrize("act", ["none", "silu"])
 @pytest.mark.parametrize("slab", GROUP_NORM_SLABS,
                          ids=lambda s: "x".join(map(str, s)))

@@ -337,42 +337,67 @@ def test_tuning_preemption_checkpoint_and_bit_identical_resume(
         )
 
 
-@pytest.mark.slow  # ~19 s: two full UNet grad compiles (policy vs none)
-def test_remat_policy_threads_through_blocks():
-    """remat_policy selects a jax.checkpoint policy for the per-block remat;
-    gradients must flow and match the no-policy remat numerically."""
+@pytest.mark.slow  # ~1 min: three UNet grad compiles, kernels in interpret mode
+def test_block_remat_keeps_the_frame_attention_output(monkeypatch):
+    """The UNet's remat'd blocks keep the frame-attention kernel's output and
+    nothing else: at 32x32 latents the tiny UNet has three sites on the
+    kernel pair (interpret mode), and its gradient holds three forward and
+    three backward kernels — six forward under a bare ``nn.remat`` — while
+    matching the gradient without remat."""
+    import collections
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from videop2p_tpu.models import UNet3DConditionModel, UNet3DConfig
+    from videop2p_tpu.models import UNet3DConditionModel, UNet3DConfig, unet_blocks
+    from videop2p_tpu.ops.attention import dense_frame_attention, fused_frame_attention
     from videop2p_tpu.pipelines import make_unet_fn
 
-    x = jax.random.normal(jax.random.key(0), (1, 2, 8, 8, 4))
-    text = jax.random.normal(jax.random.key(1), (1, 7, 16))
+    def attend(q, k, v):
+        if q.shape[3] < 1024:
+            return dense_frame_attention(q, k, v)
+        return fused_frame_attention(q, k, v, 256, True)
 
-    grads = {}
+    def kernels(jaxpr):
+        counts = collections.Counter(
+            e.params["name"] for e in jaxpr.eqns if e.primitive.name == "pallas_call")
+        for e in jaxpr.eqns:
+            for sub in jax.core.jaxprs_in_params(e.params):
+                counts.update(kernels(sub))
+        return dict(counts)
+
+    x = jax.random.normal(jax.random.key(0), (1, 2, 32, 32, 4))
+    text = jax.random.normal(jax.random.key(1), (1, 7, 16))
     params = None
-    for policy in (None, "dots_saveable"):
-        cfg = UNet3DConfig.tiny(gradient_checkpointing=True, remat_policy=policy)
-        model = UNet3DConditionModel(config=cfg)
+
+    def grad_of(remat):
+        nonlocal params
+        model = UNet3DConditionModel(
+            config=UNet3DConfig.tiny(gradient_checkpointing=remat),
+            frame_attention_fn=attend)
         if params is None:
-            # the param pytree is policy-independent — one init serves both
             params = jax.jit(model.init)(jax.random.key(2), x, jnp.asarray(3), text)
         fn = make_unet_fn(model)
 
         def loss(p):
             out, _ = fn(p, x, jnp.asarray(3), text)
-            return jnp.mean(out**2)
+            return jnp.mean(out ** 2)
 
-        # jitted: eager (op-by-op) grad of even the tiny UNet costs ~minutes
-        # of dispatch overhead on this host, and only jitted programs hit the
-        # persistent compilation cache
-        grads[policy] = jax.jit(jax.grad(loss))(params)
-    a = jax.tree_util.tree_leaves(grads[None])
-    b = jax.tree_util.tree_leaves(grads["dots_saveable"])
-    for ga, gb in zip(a, b):
-        np.testing.assert_allclose(np.asarray(ga), np.asarray(gb), atol=1e-5)
+        grad = jax.grad(loss)
+        return kernels(jax.make_jaxpr(grad)(params).jaxpr), jax.jit(grad)(params)
+
+    want_kernels, want = grad_of(False)
+    assert want_kernels == {"fused_frame_attention": 3,
+                            "fused_frame_attention_bwd": 3}
+    got_kernels, got = grad_of(True)
+    assert got_kernels == want_kernels
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-7)
+    monkeypatch.setattr(unet_blocks, "keep_frame_attention_output", None)
+    bare_kernels, _ = grad_of(True)
+    assert bare_kernels == {"fused_frame_attention": 6,
+                            "fused_frame_attention_bwd": 3}
 
 
 def _train_step_before_the_split(unet_fn, tx, state, scheduler, latents,

@@ -141,6 +141,28 @@ def test_gradient_checkpointing_matches(tiny_unet):
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ckpt), atol=1e-5)
 
 
+def test_gradient_checkpointing_gradient_matches(tiny_unet):
+    """The remat'd blocks (``unet_blocks.remat_block``: all but the
+    frame-attention kernel's output recomputed) differentiate to the
+    gradient of the UNet without remat."""
+    model, params, sample, text = tiny_unet
+    model_ckpt = UNet3DConditionModel(
+        config=UNet3DConfig.tiny(gradient_checkpointing=True)
+    )
+
+    def grad(m):
+        return jax.jit(jax.grad(lambda p: jnp.mean(
+            m.apply(p, sample, jnp.asarray(10), text) ** 2)))(params)
+
+    want, got = grad(model), grad(model_ckpt)
+    moved = 0
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-7)
+        moved += float(jnp.abs(w).max()) > 0
+    assert moved > 10
+
+
 def test_transformer3d_per_frame_norm():
     """The transformer's input GroupNorm must normalize each frame separately
     (the reference folds frames into batch before the norm, attention.py:94-101).

@@ -76,13 +76,9 @@ class UNet3DConfig:
     norm_num_groups: int = 32
     flip_sin_to_cos: bool = True
     freq_shift: float = 0.0
+    # remat every block: recompute all but the frame-attention kernel's
+    # output in the backward pass (unet_blocks.remat_block)
     gradient_checkpointing: bool = False
-    # jax.checkpoint_policies name for remat (None → full recompute inside
-    # each block). Measured on v5e at the SD null-text working point:
-    # "dots_with_no_batch_dims_saveable" was 2.8× SLOWER (187 s → 521 s) —
-    # the saved dot outputs push a 16 GB chip into spills — so full
-    # recompute is the default; the knob stays for bigger-HBM parts.
-    remat_policy: Optional[str] = None
     # frame-attention kernel: "auto" (the Pallas forward / backward pair on
     # TPU, dense elsewhere), "fused", "dense", "chunked" (memory-bounded
     # backward: what training takes off the TPU); see ops/attention.py
@@ -257,7 +253,6 @@ class UNet3DConditionModel(nn.Module):
             block = unet_blocks.get_down_block(
                 block_type,
                 remat=cfg.gradient_checkpointing,
-                remat_policy=cfg.remat_policy,
                 out_channels=cfg.block_out_channels[i],
                 num_layers=cfg.layers_per_block,
                 transformer_depth=depths[i],
@@ -286,10 +281,7 @@ class UNet3DConditionModel(nn.Module):
         if deep_mode != "shallow":
             # --- mid (unet.py:377) ---
             mid_cls = (
-                nn.remat(
-                    unet_blocks.UNetMidBlock3DCrossAttn,
-                    policy=unet_blocks.resolve_remat_policy(cfg.remat_policy),
-                )
+                unet_blocks.remat_block(unet_blocks.UNetMidBlock3DCrossAttn)
                 if cfg.gradient_checkpointing
                 else unet_blocks.UNetMidBlock3DCrossAttn
             )
@@ -334,7 +326,6 @@ class UNet3DConditionModel(nn.Module):
             block = unet_blocks.get_up_block(
                 block_type,
                 remat=cfg.gradient_checkpointing,
-                remat_policy=cfg.remat_policy,
                 out_channels=rev_channels[i],
                 num_layers=num_layers,
                 transformer_depth=rev_depths[i],

@@ -6,10 +6,10 @@ blocks thread the text context and the functional attention control. Down
 blocks return their per-layer outputs for the skip connections; up blocks
 consume them via channel concat (unet_blocks.py:486-488).
 
-Gradient checkpointing is applied by the parent UNet via ``nn.remat`` around
-these blocks (the reference checkpoints per resnet/attn pair inside each block,
-unet_blocks.py:290-306 — block-level remat is the XLA-friendly equivalent:
-coarser boundaries, same activation-memory/recompute trade).
+Gradient checkpointing is applied by the parent UNet via :func:`remat_block`
+around these blocks (the reference checkpoints per resnet/attn pair inside
+each block, unet_blocks.py:290-306 — block-level remat is the XLA-friendly
+equivalent: coarser boundaries, same activation-memory/recompute trade).
 """
 
 from __future__ import annotations
@@ -22,6 +22,7 @@ from flax import linen as nn
 
 from videop2p_tpu.models.attention import AttnControl, Transformer3DModel
 from videop2p_tpu.models.layers import Downsample3D, ResnetBlock3D, Upsample3D
+from videop2p_tpu.ops.attention import keep_frame_attention_output
 
 __all__ = [
     "CrossAttnDownBlock3D",
@@ -266,38 +267,35 @@ _ATTN_ONLY_KWARGS = (
 )
 
 
-def resolve_remat_policy(name):
-    """jax.checkpoint_policies entry by name (None → full recompute)."""
-    if name is None:
-        return None
-    import jax
+def remat_block(mod_cls):
+    """``mod_cls`` recomputed in the backward pass, all but the frame-attention
+    kernel's output (``ops.attention.keep_frame_attention_output``): the
+    backward kernel reads only q / k / v, so the forward kernel runs once a
+    site and step; where the kernel did not run nothing is kept."""
+    return nn.remat(mod_cls, policy=keep_frame_attention_output)
 
-    return getattr(jax.checkpoint_policies, name)
 
-
-def _make(mod_cls, remat: bool, kwargs, policy=None):
+def _make(mod_cls, remat: bool, kwargs):
     if remat:
-        mod_cls = nn.remat(mod_cls, policy=resolve_remat_policy(policy))
+        mod_cls = remat_block(mod_cls)
     return mod_cls(**kwargs)
 
 
-def get_down_block(block_type: str, *, remat: bool = False,
-                   remat_policy=None, **kwargs):
+def get_down_block(block_type: str, *, remat: bool = False, **kwargs):
     """Factory mirroring unet_blocks.py:11-65; raises on unknown types."""
     if block_type == "CrossAttnDownBlock3D":
-        return _make(CrossAttnDownBlock3D, remat, kwargs, remat_policy)
+        return _make(CrossAttnDownBlock3D, remat, kwargs)
     if block_type == "DownBlock3D":
         kwargs = {k: v for k, v in kwargs.items() if k not in _ATTN_ONLY_KWARGS}
-        return _make(DownBlock3D, remat, kwargs, remat_policy)
+        return _make(DownBlock3D, remat, kwargs)
     raise ValueError(f"unknown down block type: {block_type!r}")
 
 
-def get_up_block(block_type: str, *, remat: bool = False,
-                 remat_policy=None, **kwargs):
+def get_up_block(block_type: str, *, remat: bool = False, **kwargs):
     """Factory mirroring unet_blocks.py:68-122; raises on unknown types."""
     if block_type == "CrossAttnUpBlock3D":
-        return _make(CrossAttnUpBlock3D, remat, kwargs, remat_policy)
+        return _make(CrossAttnUpBlock3D, remat, kwargs)
     if block_type == "UpBlock3D":
         kwargs = {k: v for k, v in kwargs.items() if k not in _ATTN_ONLY_KWARGS}
-        return _make(UpBlock3D, remat, kwargs, remat_policy)
+        return _make(UpBlock3D, remat, kwargs)
     raise ValueError(f"unknown up block type: {block_type!r}")

@@ -35,6 +35,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 __all__ = [
     "dense_frame_attention",
@@ -43,6 +44,8 @@ __all__ = [
     "fused_bwd_block",
     "make_frame_attention_fn",
     "training_frame_attention",
+    "FRAME_ATTENTION_KEPT",
+    "keep_frame_attention_output",
 ]
 
 # shapes: q (B, F, H, N, D); k, v (B, H, N, D) — frame-0 KV shared across F
@@ -53,6 +56,17 @@ FrameAttentionFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
 # forward's lax.map body and its checkpointed backward included. Where an
 # implementation hands over to another, the scope opens after that return.
 _SCOPE = "ops.frame_attention"
+
+# What the forward kernel hands downstream, by name: its output folded back
+# to (B, F, H, N, D), tagged inside the forward rule. The backward kernel
+# needs only (q, k, v), so a block wrapped in
+# ``nn.remat(block, policy=keep_frame_attention_output)`` keeps this one value
+# across its recompute and the forward kernel runs once a site and step (under
+# ``jax.shard_map`` too); where the kernel did not run (dense, chunked) no
+# value carries the name and the policy keeps nothing.
+FRAME_ATTENTION_KEPT = "fused_frame_attention_o"
+keep_frame_attention_output = jax.checkpoint_policies.save_only_these_names(
+    FRAME_ATTENTION_KEPT)
 
 
 @jax.named_scope(_SCOPE)
@@ -159,21 +173,22 @@ def fused_frame_attention(
     :func:`fused_bwd_block` finds a block that fits VMEM, and the vjp of
     :func:`chunked_frame_attention` (memory-bounded, exact) otherwise.
     """
+    return _fused_fwd(q, k, v, q_blk, interpret)[0]
+
+
+def _fused_fwd(q, k, v, q_blk, interpret):
     b, f, h, n, d = q.shape
     if (f * n) % q_blk != 0:
         # the grid would silently drop the remainder queries — fall back to
         # the exact chunked kernel (same convention as its own fallback)
-        return chunked_frame_attention(q, k, v)
+        return chunked_frame_attention(q, k, v), (q, k, v)
     with jax.named_scope(_SCOPE):
         qr = q.transpose(0, 2, 1, 3, 4).reshape(b * h, f * n, d)
         kr = k.reshape(b * h, n, d)
         vr = v.reshape(b * h, n, d)
         out = _fused_rect(qr, kr, vr, q_blk, interpret)
-        return out.reshape(b, h, f, n, d).transpose(0, 2, 1, 3, 4)
-
-
-def _fused_fwd(q, k, v, q_blk, interpret):
-    return fused_frame_attention(q, k, v, q_blk, interpret), (q, k, v)
+        o = out.reshape(b, h, f, n, d).transpose(0, 2, 1, 3, 4)
+    return checkpoint_name(o, FRAME_ATTENTION_KEPT), (q, k, v)
 
 
 # The backward kernel's VMEM: one 64²-site block is over the default scoped
