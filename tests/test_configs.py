@@ -160,6 +160,30 @@ def test_fourth_token_family_config_loads_and_builds_the_published_model():
         KeyeVL2Config.from_dict({**cfg["model"], "n_shared_experts": 1})
 
 
+def test_fifth_token_family_config_loads_and_builds_the_published_model():
+    """Falcon-H1-34B's YAML loads through ``load_config``, names a known
+    family, and its ``model`` dict is the published config.json (every
+    multiplier, ``mamba_n_groups`` 2, the untied head) at this chip's share:
+    4 whole layers of 72 (one pipeline stage of 18) and 1/8 of the
+    vocabulary; no width cut."""
+    from videop2p_tpu.cli.common import MODEL_FAMILIES, check_model_family
+    from videop2p_tpu.models.falcon_h1 import FalconH1Config
+
+    cfg = load_config(os.path.join(ROOT, "configs", "falcon-h1-34b-s1-tune.yaml"))
+    assert check_model_family(cfg["model_family"]) == "falcon_h1"
+    assert cfg["model_family"] in MODEL_FAMILIES
+    model = FalconH1Config.from_dict(cfg["model"])
+    model.check()
+    published = FalconH1Config()
+    cut = {"num_hidden_layers": 4, "vocab_size": 32640}
+    for f in inspect.signature(FalconH1Config).parameters:
+        assert getattr(model, f) == cut.get(f, getattr(published, f)), f
+    assert list(cfg["trainable_modules"]) == ["q_proj", "in_proj_c"]
+    assert cfg["train_data"]["n_tokens"] == 32768
+    with pytest.raises(ValueError, match="unknown FalconH1Config keys"):
+        FalconH1Config.from_dict({**cfg["model"], "n_group": 8})
+
+
 def test_unknown_model_family_is_rejected_with_the_known_ones():
     from videop2p_tpu.cli.common import check_model_family
 
@@ -169,6 +193,7 @@ def test_unknown_model_family_is_rejected_with_the_known_ones():
     assert "unet3d" in str(err.value) and "deepseek_v32" in str(err.value)
     assert "granitemoehybrid" in str(err.value)
     assert "cohere2_moe" in str(err.value) and "keye_vl2" in str(err.value)
+    assert "falcon_h1" in str(err.value)
     with pytest.raises(ValueError, match="known:"):
         tune_main(pretrained_model_path=None, output_dir="unused",
                   train_data={}, model_family="nope")

@@ -450,3 +450,63 @@ def test_the_shared_tuner_steps_on_the_hybrid_loss(model):
     assert all(v.shape == (4,) for v in aux.values())
     for a, b in zip(jax.tree.leaves(new.frozen), jax.tree.leaves(state.frozen)):
         assert np.array_equal(a, b)
+
+
+# ------------------------------------------------- the shared scan at G = 1
+
+
+def _normalised_jaxpr_digest(fn, *args) -> str:
+    """sha256 of ``fn``'s jaxpr text with addresses and source lines taken
+    out: what the program computes, not where its code sits."""
+    import hashlib
+    import re
+
+    text = str(jax.make_jaxpr(fn)(*args))
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    text = re.sub(r"[\w/\.-]+\.py:\d+(:\d+)?", "SRC", text)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+# the digest of the loss-and-gradient below as the code read before the scan
+# and the gated norm took B / C in groups (Falcon-H1): one group must lower
+# to the same program, equation for equation
+ONE_GROUP_DIGEST = (
+    "a1411bceb3e0e4baf6e8c8a283ea6b7657570fa7a3908c3aa79cd14acdefe343")
+
+
+def test_one_group_lowers_to_the_program_it_was_before_groups():
+    """Granite-4.0-H's loss and its gradient (tiny, bfloat16, 64 tokens, the
+    scan in 8 chunks of 8, 2 a group) trace to the same jaxpr as before
+    ``ssd_scan`` / ``mamba_scan_part`` / ``mamba_out_part`` took B / C in
+    groups and in-projection multipliers: the one-group path is the old
+    code, to the equation."""
+    cfg = gh.GraniteHybridConfig.tiny()
+    params = gh.abstract_params(cfg)["params"]
+    ids = jax.ShapeDtypeStruct((64,), jnp.int32)
+    fn = jax.value_and_grad(lambda p, i: gh.forward_loss(p, cfg, i)[0])
+    assert _normalised_jaxpr_digest(fn, params, ids) == ONE_GROUP_DIGEST
+
+
+# the same digest at the Granite cell's own size (its CLI config: 4 layers,
+# 32768 tokens) on the chip's branch (``jax.default_backend`` reads "tpu",
+# so the attention and the scan take the paths the chip runs), as the code
+# read before groups
+CELL_ONE_GROUP_DIGEST = (
+    "648fe4728d936cf46d04c43fba607b7dc7ea66371768b714c017ed859d84e6cc")
+
+
+def test_one_group_at_the_cells_size_traces_to_the_program_it_was(
+        monkeypatch):
+    """Granite-4.0-H's loss and gradient at the cell's widths and length,
+    traced abstractly (no array is made) on the TPU branch: the jaxpr the
+    chip lowers is the one from before B / C came in groups."""
+    from videop2p_tpu.cli.common import load_config
+
+    monkeypatch.undo()  # the program's own block sizes, not ``small_blocks``
+    tune = load_config("configs/granite-4.0-h-small-s4-tune.yaml")
+    cfg = gh.GraniteHybridConfig.from_dict(tune["model"])
+    params = gh.abstract_params(cfg)["params"]
+    ids = jax.ShapeDtypeStruct((tune["train_data"]["n_tokens"],), jnp.int32)
+    fn = jax.value_and_grad(lambda p, i: gh.forward_loss(p, cfg, i)[0])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _normalised_jaxpr_digest(fn, params, ids) == CELL_ONE_GROUP_DIGEST

@@ -1009,3 +1009,22 @@ def test_fourth_token_family_main_emits_the_scopes_and_counters_read():
     for scope in scorer:
         mine = [n for n in names if scope in n]
         assert mine and not [n for n in mine if "transpose(" in n], scope
+
+
+def test_fifth_token_family_main_emits_the_scopes_and_counters_read():
+    """The same for ``models/falcon_h1.SCOPES`` / ``COUNTERS``: the mixer
+    under ``lm.mamba_proj`` / ``lm.ssd`` and the attention under
+    ``lm.attention`` (the names the hybrid cell's readers take), the dense
+    feed-forward under ``lm.dense_ffn`` (what ``dense_ffn_ms.tune`` reads),
+    forward and backward; ``ssd_state_rms`` and ``branch_rms_ratio`` a step
+    beside the loss, both above 0 (the state is handed on; both branches
+    contribute)."""
+    from videop2p_tpu.models import falcon_h1 as fh
+
+    assert fh.SCOPES == ("lm.mamba_proj", "lm.ssd", "lm.attention",
+                         "lm.dense_ffn", "lm.head_loss")
+    assert fh.COUNTERS == ("ssd_state_rms", "branch_rms_ratio")
+    metrics = _token_family_main_emits(fh, fh.FalconH1Config.tiny(),
+                                       "falcon-h1-34b-s1-tune.yaml")
+    assert metrics[0]["ssd_state_rms"] > 0
+    assert 0 < metrics[0]["branch_rms_ratio"] < 10

@@ -297,7 +297,8 @@ def _family_attention(family):
     tune = load_config({"deepseek_v32": "configs/deepseek-v32-s16-tune.yaml",
                         "granitemoehybrid": "configs/granite-4.0-h-small-s4-tune.yaml",
                         "cohere2_moe": "configs/command-a-plus-s8-tune.yaml",
-                        "keye_vl2": "configs/keye-vl2-30b-a3b-s1-tune.yaml"}[name])
+                        "keye_vl2": "configs/keye-vl2-30b-a3b-s1-tune.yaml",
+                        "falcon_h1": "configs/falcon-h1-34b-s1-tune.yaml"}[name])
     module, config_cls = _token_families()[name]
     cfg = config_cls.from_dict(tune["model"])
     t_len = tune["train_data"]["n_tokens"]
@@ -312,6 +313,10 @@ def _family_attention(family):
         def layer(p, x, mask):
             return x + module.attention(
                 p, cfg, x, module.rope_angles(cfg, jnp.arange(t_len)), mask)
+    elif name == "falcon_h1":
+        def layer(p, x):
+            return x + module.attention(
+                p, cfg, x, module.rope_angles(cfg, jnp.arange(t_len)))
     else:
         def layer(p, x):
             angles = (module.rope_angles(cfg, jnp.arange(t_len))
@@ -322,7 +327,7 @@ def _family_attention(family):
 
 @pytest.mark.parametrize("family", ["deepseek_v32", "granitemoehybrid",
                                     "cohere2_moe:sliding", "cohere2_moe:full",
-                                    "keye_vl2"])
+                                    "keye_vl2", "falcon_h1"])
 def test_a_layers_recompute_keeps_the_attention_pairs_outputs(one_chip,
                                                               monkeypatch,
                                                               family):

@@ -582,7 +582,7 @@ def build_models(
 # the tune config's ``model_family``: which model Stage 1 builds and which
 # loss it steps on (``tiny`` picks a size inside a family, not a family)
 MODEL_FAMILIES = ("unet3d", "deepseek_v32", "granitemoehybrid", "cohere2_moe",
-                  "keye_vl2")
+                  "keye_vl2", "falcon_h1")
 
 
 def check_model_family(name: str) -> str:
@@ -606,14 +606,15 @@ class TokenModelBundle:
 
 def _token_families() -> Dict[str, Any]:
     """``model_family`` → (module, configuration class) of the token models."""
-    from videop2p_tpu.models import (cohere2_moe, deepseek, granite_hybrid,
-                                     keye_vl2)
+    from videop2p_tpu.models import (cohere2_moe, deepseek, falcon_h1,
+                                     granite_hybrid, keye_vl2)
 
     return {"deepseek_v32": (deepseek, deepseek.DeepSeekV32Config),
             "granitemoehybrid": (granite_hybrid,
                                  granite_hybrid.GraniteHybridConfig),
             "cohere2_moe": (cohere2_moe, cohere2_moe.Cohere2MoeConfig),
-            "keye_vl2": (keye_vl2, keye_vl2.KeyeVL2Config)}
+            "keye_vl2": (keye_vl2, keye_vl2.KeyeVL2Config),
+            "falcon_h1": (falcon_h1, falcon_h1.FalconH1Config)}
 
 
 def build_token_model(
@@ -626,7 +627,8 @@ def build_token_model(
     seed: int = 0,
 ) -> TokenModelBundle:
     """A token family (``models/deepseek.py``, ``models/granite_hybrid.py``,
-    ``models/cohere2_moe.py``, ``models/keye_vl2.py``) from the tune config's
+    ``models/cohere2_moe.py``, ``models/keye_vl2.py``,
+    ``models/falcon_h1.py``) from the tune config's
     ``model`` dict — ``config.json`` keys plus the
     chip's share, unknown keys an error — with seeded random weights in the
     checkpoint's dtype (bfloat16): no checkpoint of these families ships,

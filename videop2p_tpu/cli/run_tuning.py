@@ -290,8 +290,8 @@ def main(
     # which model Stage 1 tunes (cli/common.MODEL_FAMILIES): the inflated
     # video UNet on a clip, or a token model on a document of token ids —
     # ``model`` is that family's configuration (models/: deepseek.py,
-    # granite_hybrid.py, cohere2_moe.py, keye_vl2.py). The token
-    # model has no VAE, no text encoder and no validation edit.
+    # granite_hybrid.py, cohere2_moe.py, keye_vl2.py, falcon_h1.py). The
+    # token model has no VAE, no text encoder and no validation edit.
     model_family: str = "unet3d",
     model: Optional[Dict[str, Any]] = None,
     # extras (not in the reference)

@@ -33,7 +33,8 @@ a short scan, and the state a chunk starts from adds C_t h exp(cum_t) to
 its outputs. Log-decays, their cumulative sums and the states are float32;
 bfloat16 only as matmul operands. The backward is autodiff's, with groups
 of ``SSD_GROUP`` chunks recomputed so that the float32 (heads, chunk, chunk)
-decay tiles are not kept. No kernel runs here yet.
+decay tiles are not kept. No kernel runs here yet. The scan and the mixer's
+two halves also take B / C in several groups (``models/falcon_h1.py``).
 
 **The chip's share.** ``experts_held``, ``heads_held`` (query heads; the key
 / value heads follow from the grouping) and ``mamba_heads_held`` are
@@ -82,6 +83,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.scipy.special import ndtr
 
@@ -352,7 +354,23 @@ def ssd_scan(x, dt, a, b, c, chunk: int):
     state (H, P, N) float32, and the mean square over the LAST chunk's
     outputs of the term the state handed to that chunk adds (0 for a single
     chunk, and 0 if chunks stop handing their state on). T must be a
-    multiple of ``chunk`` or below it."""
+    multiple of ``chunk`` or below it.
+
+    ``b``, ``c`` (T, G, N) are G groups: head h reads group h // (H / G),
+    the heads of a group contiguous (Mamba-2's ``ngroups``). Each group is
+    the one-group scan above over its own heads, one group at a time and
+    each recomputed in the backward pass: a group's float32 chunk states
+    (chunks, H / G, P, N) are what the scan holds most of."""
+    if b.ndim == 3:
+        groups = b.shape[1]
+        split = lambda v: v.reshape(v.shape[:1] + (groups, -1) + v.shape[2:])  # noqa: E731
+        first = lambda v: jnp.moveaxis(v, 1, 0)  # noqa: E731
+        y, last, handed_sq = lax.map(
+            jax.checkpoint(lambda g: ssd_scan(*g, chunk)),
+            (first(split(x)), first(split(dt)), a.reshape(groups, -1),
+             first(b), first(c)))
+        return (jnp.moveaxis(y, 0, 1).reshape(x.shape),
+                last.reshape((-1,) + last.shape[2:]), jnp.mean(handed_sq))
     t_len, heads, width = x.shape
     q_len = min(chunk, t_len)
     assert t_len % q_len == 0, (t_len, q_len)
@@ -380,24 +398,41 @@ def ssd_scan(x, dt, a, b, c, chunk: int):
     return y.reshape(t_len, heads, width), last, handed_sq.reshape(-1)[-1]
 
 
-def mamba_scan_part(p, cfg: GraniteHybridConfig, u):
+def mamba_scan_part(p, cfg: GraniteHybridConfig, u, multipliers=None):
     """The first half of the mixer on the normed input ``u`` (T, h): the
     held heads' gated scan outputs ``g = y * silu(z)`` (T, d held) float32,
-    their sum of squares (T, 1) — this share's part of the gated norm's
-    statistic — and :func:`ssd_scan`'s mean square of what the last chunk's
-    outputs got through the state it was handed."""
+    their sum of squares (T, G) over each group's channels (G =
+    ``mamba_n_groups``; G = 1: (T, 1) over all of them) — this share's part
+    of the gated norm's statistic — and :func:`ssd_scan`'s mean square of
+    what the last chunk's outputs got through the state it was handed.
+    ``multipliers`` (z, x, B, C, dt): scales of the in-projection's
+    segments, applied to its float32 products (Falcon-H1's μP
+    ``ssm_multipliers``); None: none."""
     t_len = u.shape[0]
-    mh, hp, n = cfg.mamba_heads_held[1], cfg.mamba_d_head, cfg.mamba_d_state
+    mh, hp = cfg.mamba_heads_held[1], cfg.mamba_d_head
+    groups = cfg.mamba_n_groups
+    n = groups * cfg.mamba_d_state
     d = mh * hp
+    if groups > 1:  # the groups are read whole: every head is held
+        assert cfg.mamba_heads_held == (0, cfg.mamba_n_heads)
     with jax.named_scope("lm.mamba_proj"):
-        zxbdt = _dense(u, p["in_proj"]["kernel"])
+        if multipliers is None:
+            zxbdt = _dense(u, p["in_proj"]["kernel"])
+        else:
+            mz, mx, mb, mc, mdt = multipliers
+            zxbdt = _scaled_dense(u, p["in_proj"]["kernel"], np.repeat(
+                np.float32([mz, mx, mb, mdt]), [d, d, n, mh]))
         z, dt_raw = zxbdt[:, :d], zxbdt[:, 2 * d + n:]
-        xbc = jnp.concatenate(
-            [zxbdt[:, d:2 * d + n], _dense(u, p["in_proj_c"]["kernel"])], axis=-1)
+        xb = zxbdt[:, d:2 * d + n]
+        c_in = (_dense(u, p["in_proj_c"]["kernel"]) if multipliers is None
+                else _scaled_dense(u, p["in_proj_c"]["kernel"], np.float32(mc)))
+        xbc = jnp.concatenate([xb, c_in], axis=-1)
         xbc = jax.nn.silu(_causal_conv(xbc, p["conv"]["kernel"],
                                        p["conv"]["bias"])).astype(u.dtype)
         x = xbc[:, :d].reshape(t_len, mh, hp)
         b, c = xbc[:, d:d + n], xbc[:, d + n:]
+        if groups > 1:
+            b, c = (v.reshape(t_len, groups, -1) for v in (b, c))
     with jax.named_scope("lm.ssd"):
         dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
                              + p["dt_bias"].astype(jnp.float32))
@@ -406,15 +441,30 @@ def mamba_scan_part(p, cfg: GraniteHybridConfig, u):
         y = y + p["D"].astype(jnp.float32)[None, :, None] * x.astype(jnp.float32)
     with jax.named_scope("lm.mamba_proj"):
         g = y.reshape(t_len, d) * jax.nn.silu(z.astype(jnp.float32))
-        return g, jnp.sum(g * g, axis=-1, keepdims=True), handed_sq
+        sq = g * g if groups == 1 else jnp.square(g.reshape(t_len, groups, -1))
+        return g, jnp.sum(sq, axis=-1, keepdims=groups == 1), handed_sq
+
+
+def _scaled_dense(x, kernel, scale):
+    """``x kernel``, its float32 products times ``scale`` (a column's or
+    one), rounded once to ``x``'s dtype."""
+    y = jnp.matmul(x, kernel.astype(x.dtype), preferred_element_type=jnp.float32)
+    return (y * scale).astype(x.dtype)
 
 
 def mamba_out_part(p, cfg: GraniteHybridConfig, g, mean_square):
-    """The second half: the gated norm of ``g`` GIVEN the mean square (T, 1)
-    over ALL inner channels, and the held rows of ``out_proj`` → (T, h)."""
+    """The second half: the gated norm of ``g`` GIVEN the mean square (T, G)
+    over each group's inner channels (G = 1: over ALL of them), and the held
+    rows of ``out_proj`` → (T, h)."""
     with jax.named_scope("lm.mamba_proj"):
-        y = (g * lax.rsqrt(mean_square + cfg.rms_norm_eps)
-             * p["norm"]["scale"].astype(jnp.float32))
+        groups = mean_square.shape[-1]
+        if groups == 1:
+            y = (g * lax.rsqrt(mean_square + cfg.rms_norm_eps)
+                 * p["norm"]["scale"].astype(jnp.float32))
+        else:  # a group's channels by their own statistic
+            y = (g.reshape(g.shape[0], groups, -1)
+                 * lax.rsqrt(mean_square[..., None] + cfg.rms_norm_eps)
+                 ).reshape(g.shape) * p["norm"]["scale"].astype(jnp.float32)
         kernel = p["out_proj"]["kernel"]
         return _dense(y.astype(kernel.dtype), kernel)
 
