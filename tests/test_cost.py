@@ -401,7 +401,7 @@ def test_cost_report_renders_chargeback_and_savings(tmp_path):
 def test_tools_inventory_is_complete():
     """The smoke below covers every entry point: pin the inventory so a
     new tool must join the contract."""
-    assert len(_TOOLS) == 13
+    assert len(_TOOLS) == 14
     assert {"cost_report", "fleet_dash", "incident_report",
             "ledger_summary", "obs_diff", "probe_report",
             "serve_loadgen"} <= set(_TOOLS)

@@ -489,17 +489,21 @@ def test_one_group_lowers_to_the_program_it_was_before_groups():
 
 # the same digest at the Granite cell's own size (its CLI config: 4 layers,
 # 32768 tokens) on the chip's branch (``jax.default_backend`` reads "tpu",
-# so the attention and the scan take the paths the chip runs), as the code
-# read before groups
+# so the attention, the expert loop and the scan take the paths the chip
+# runs): the program with the scan on its Pallas pair (``ops/ssd_scan.py``),
+# whose kernels' bodies are part of the jaxpr. Before the pair it read
+# 648fe4728d936cf46d04c43fba607b7dc7ea66371768b714c017ed859d84e6cc (the XLA
+# scan, as the code read before groups).
 CELL_ONE_GROUP_DIGEST = (
-    "648fe4728d936cf46d04c43fba607b7dc7ea66371768b714c017ed859d84e6cc")
+    "f09326175d9edfbdf6b6dd8a87015f62e3a875775b9c9ea96c26e166b73610ee")
 
 
 def test_one_group_at_the_cells_size_traces_to_the_program_it_was(
         monkeypatch):
     """Granite-4.0-H's loss and gradient at the cell's widths and length,
     traced abstractly (no array is made) on the TPU branch: the jaxpr the
-    chip lowers is the one from before B / C came in groups."""
+    chip lowers is the one pinned above, kernels and all — a change to what
+    the chip runs shows here."""
     from videop2p_tpu.cli.common import load_config
 
     monkeypatch.undo()  # the program's own block sizes, not ``small_blocks``

@@ -33,8 +33,14 @@ a short scan, and the state a chunk starts from adds C_t h exp(cum_t) to
 its outputs. Log-decays, their cumulative sums and the states are float32;
 bfloat16 only as matmul operands. The backward is autodiff's, with groups
 of ``SSD_GROUP`` chunks recomputed so that the float32 (heads, chunk, chunk)
-decay tiles are not kept. No kernel runs here yet. The scan and the mixer's
-two halves also take B / C in several groups (``models/falcon_h1.py``).
+decay tiles are not kept. The scan and the mixer's two halves also take B / C
+in several groups (``models/falcon_h1.py``).
+
+**Which code scans.** On the TPU, where its fit test takes the shape, the
+Pallas pair of ``ops/ssd_scan.py`` (``lm_ssd_scan`` / ``_bwd``: a block of
+heads' state stays in VMEM from chunk to chunk, the groups a grid
+coordinate); elsewhere the XLA code of ``ssd_scan``, the tests' oracle.
+Chosen from the backend and the shapes.
 
 **The chip's share.** ``experts_held``, ``heads_held`` (query heads; the key
 / value heads follow from the grouping) and ``mamba_heads_held`` are
@@ -102,6 +108,7 @@ from videop2p_tpu.ops.selected_attention import (
     keep_attention_outputs,
     selected_attention_tiles,
 )
+from videop2p_tpu.ops.ssd_scan import ssd_scan_kernel, ssd_scan_plan
 
 __all__ = [
     "GraniteHybridConfig",
@@ -346,6 +353,18 @@ def _chunk_outputs(x_dt, b, c, cum, h_prev):
         jnp.mean(jnp.square(handed), axis=(1, 2, 3)))
 
 
+def _scan_kernel_applies(x, b, chunk: int) -> bool:
+    """Whether the scan runs as the Pallas pair: on the TPU, where its fit
+    test takes the shape. Chosen from what the input is, never by an
+    option."""
+    if jax.default_backend() != "tpu":
+        return False
+    t_len, heads, width = x.shape
+    groups = 1 if b.ndim == 2 else b.shape[1]
+    return ssd_scan_plan(t_len, heads, width, b.shape[-1], chunk, groups,
+                         x.dtype) is not None
+
+
 def ssd_scan(x, dt, a, b, c, chunk: int):
     """The selective state-space recurrence, chunked: ``x`` (T, H, P),
     ``dt`` (T, H) float32 step sizes, ``a`` (H,) float32 negative rates,
@@ -360,7 +379,14 @@ def ssd_scan(x, dt, a, b, c, chunk: int):
     the heads of a group contiguous (Mamba-2's ``ngroups``). Each group is
     the one-group scan above over its own heads, one group at a time and
     each recomputed in the backward pass: a group's float32 chunk states
-    (chunks, H / G, P, N) are what the scan holds most of."""
+    (chunks, H / G, P, N) are what the scan holds most of.
+
+    Where :func:`_scan_kernel_applies` — the TPU at shapes the kernels'
+    fit test takes — the scan is ``ops.ssd_scan.ssd_scan_kernel`` (all
+    groups in one call), the same mathematics and rounding points;
+    everywhere else the XLA code below."""
+    if _scan_kernel_applies(x, b, chunk):
+        return ssd_scan_kernel(x, dt, a, b, c, chunk)
     if b.ndim == 3:
         groups = b.shape[1]
         split = lambda v: v.reshape(v.shape[:1] + (groups, -1) + v.shape[2:])  # noqa: E731
