@@ -24,6 +24,7 @@ from videop2p_tpu.models import cohere2_moe as cm
 from videop2p_tpu.models import deepseek as ds
 from videop2p_tpu.models import granite_hybrid as gh
 from videop2p_tpu.ops import selected_attention as sa
+from videop2p_tpu.ops.grouped_experts import ExpertTiles
 from videop2p_tpu.train import (
     TrainState,
     TuneConfig,
@@ -408,3 +409,26 @@ def test_the_shared_tuner_steps_on_this_loss(model):
     assert all(v.shape == (4,) for v in aux.values())
     for a, b in zip(jax.tree.leaves(new.frozen), jax.tree.leaves(state.frozen)):
         assert np.array_equal(a, b)
+
+
+def test_expert_rows_packed_survives_the_layers_mean(model, monkeypatch):
+    """``expert_rows_packed`` reads 0 here, where the XLA loop runs, and the
+    1 that ``held_expert_ffn`` hands out where the pair takes packed rows
+    comes through the family's counters as it is (the
+    rescale of ``routed_over_shared`` leaves it alone) — steered from the
+    test: the dispatch reports packed tiles and the XLA loop stands in for
+    the kernels, so the loss is the same number."""
+    cfg, params, ids = model
+
+    def run():
+        return jax.jit(lambda p: cm.forward_loss(p, cfg, ids, jnp.float32))(
+            params)
+
+    loss, aux = run()
+    assert float(aux["expert_rows_packed"]) == 0.0
+    monkeypatch.setattr(ds, "_experts_kernel_tiles",
+                        lambda *a: ExpertTiles(128, 0, 0, True))
+    monkeypatch.setattr(ds, "grouped_experts_forward", ds._grouped_loop_fwd)
+    steered, aux = run()
+    assert float(aux["expert_rows_packed"]) == 1.0
+    assert float(steered) == float(loss)

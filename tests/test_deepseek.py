@@ -120,6 +120,7 @@ def test_logits_and_loss_match_reference(model):
     assert abs(float(loss) - float(loss_ref)) < 1e-5 * float(loss_ref)
     assert float(aux["held_pair_share"]) == 1.0  # every expert is held
     assert 1.0 <= float(aux["expert_load_max_over_mean"]) <= cfg.n_routed_experts
+    assert float(aux["expert_rows_packed"]) == 0.0  # the XLA loop ran
 
 
 def test_choices_match_reference(model):
@@ -369,7 +370,8 @@ def test_train_steps_on_the_new_loss_move_only_the_trainable_leaves(model):
     assert losses.shape == (3,) and bool(jnp.isfinite(losses).all())
     assert float(losses[-1]) < float(losses[0])  # the same document every step
     assert set(aux) == {"expert_load_max_over_mean", "held_pair_share",
-                        "routed_over_shared", "keys_selected_mean"}
+                        "routed_over_shared", "keys_selected_mean",
+                        "expert_rows_packed"}
     assert all(v.shape == (3,) for v in aux.values())
     moved = {k for k, v in named(new.trainable).items()
              if not bool(jnp.array_equal(v, named(state.trainable)[k]))}

@@ -23,6 +23,7 @@ from flax import traverse_util
 from videop2p_tpu.models import deepseek as ds
 from videop2p_tpu.models import granite_hybrid as gh
 from videop2p_tpu.ops import selected_attention as sa
+from videop2p_tpu.ops.grouped_experts import ExpertTiles
 from videop2p_tpu.train import (
     TrainState,
     TuneConfig,
@@ -218,8 +219,7 @@ def test_logits_and_loss_match_reference(model):
     nll = jax.nn.logsumexp(want, -1) - jnp.take_along_axis(
         want, jnp.roll(ids, -1)[:, None], -1)[:, 0]
     assert abs(float(loss) - float(jnp.mean(nll[:-1]))) < 1e-5 * float(loss)
-    assert set(aux) == {"expert_load_max_over_mean", "held_pair_share",
-                        "routed_over_shared", "ssd_state_rms"}
+    assert set(aux) == set(gh.COUNTERS)
     assert float(aux["held_pair_share"]) == 1.0 and float(aux["ssd_state_rms"]) > 0
 
 
@@ -445,8 +445,7 @@ def test_the_shared_tuner_steps_on_the_hybrid_loss(model):
         lambda s, k: loss_steps(step_loss, tx, s, k, num_steps=4))(
             state, jax.random.key(0))
     assert float(losses[-1]) < float(losses[0])
-    assert set(aux) == {"expert_load_max_over_mean", "held_pair_share",
-                        "routed_over_shared", "ssd_state_rms"}
+    assert set(aux) == set(gh.COUNTERS)
     assert all(v.shape == (4,) for v in aux.values())
     for a, b in zip(jax.tree.leaves(new.frozen), jax.tree.leaves(state.frozen)):
         assert np.array_equal(a, b)
@@ -469,9 +468,11 @@ def _normalised_jaxpr_digest(fn, *args) -> str:
 
 # the digest of the loss-and-gradient below as the code read before the scan
 # and the gated norm took B / C in groups (Falcon-H1): one group must lower
-# to the same program, equation for equation
+# to the same program, equation for equation. It moved once since, for the
+# counter ``expert_rows_packed`` (the layers' mean of a constant); without
+# it the digest was a1411bceb3e0e4baf6e8c8a283ea6b7657570fa7a3908c3aa79cd14acdefe343.
 ONE_GROUP_DIGEST = (
-    "a1411bceb3e0e4baf6e8c8a283ea6b7657570fa7a3908c3aa79cd14acdefe343")
+    "e22e2a4eb306ebd52d3969fc9f7869dbdddb0104375c3c97595cd8c0c69f5d3f")
 
 
 def test_one_group_lowers_to_the_program_it_was_before_groups():
@@ -493,9 +494,12 @@ def test_one_group_lowers_to_the_program_it_was_before_groups():
 # runs): the program with the scan on its Pallas pair (``ops/ssd_scan.py``),
 # whose kernels' bodies are part of the jaxpr. Before the pair it read
 # 648fe4728d936cf46d04c43fba607b7dc7ea66371768b714c017ed859d84e6cc (the XLA
-# scan, as the code read before groups).
+# scan, as the code read before groups); before the expert pair took its
+# bfloat16 rows packed and the counter ``expert_rows_packed`` came,
+# f09326175d9edfbdf6b6dd8a87015f62e3a875775b9c9ea96c26e166b73610ee (the
+# same with both taken out again).
 CELL_ONE_GROUP_DIGEST = (
-    "f09326175d9edfbdf6b6dd8a87015f62e3a875775b9c9ea96c26e166b73610ee")
+    "c8ce0455da254d9311c6ca48fe6b985651e7c8ba362613257711ad1ef598d2d3")
 
 
 def test_one_group_at_the_cells_size_traces_to_the_program_it_was(
@@ -514,3 +518,25 @@ def test_one_group_at_the_cells_size_traces_to_the_program_it_was(
     fn = jax.value_and_grad(lambda p, i: gh.forward_loss(p, cfg, i)[0])
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert _normalised_jaxpr_digest(fn, params, ids) == CELL_ONE_GROUP_DIGEST
+
+
+def test_expert_rows_packed_survives_the_layers_mean(model, monkeypatch):
+    """``expert_rows_packed`` reads 0 here, where the XLA loop runs, and the
+    1 that ``held_expert_ffn`` hands out where the pair takes packed rows
+    comes through the family's counters as it is — steered from the
+    test: the dispatch reports packed tiles and the XLA loop stands in for
+    the kernels, so the loss is the same number."""
+    cfg, params, ids = model
+
+    def run():
+        return jax.jit(lambda p: gh.forward_loss(p, cfg, ids, jnp.float32))(
+            params)
+
+    loss, aux = run()
+    assert float(aux["expert_rows_packed"]) == 0.0
+    monkeypatch.setattr(ds, "_experts_kernel_tiles",
+                        lambda *a: ExpertTiles(128, 0, 0, True))
+    monkeypatch.setattr(ds, "grouped_experts_forward", ds._grouped_loop_fwd)
+    steered, aux = run()
+    assert float(aux["expert_rows_packed"]) == 1.0
+    assert float(steered) == float(loss)

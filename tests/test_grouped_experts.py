@@ -5,12 +5,17 @@ against a per-token dense reference written here: the output, ``dx``,
 ``dgate`` and the matrices' zero cotangent, in float32 so that what is
 compared is the mathematics (the table's walk, the row copies, the in-place
 accumulation, the inner tiles) and not bfloat16 rounding; one bfloat16 case
-with its own tolerance; the fit test's arithmetic; and the property the
-in-place accumulation rests on — a block's tokens are distinct. What the
-chip's compiler says of the kernels is ``tests/test_tpu_compile.py``'s.
+with its own tolerance; bfloat16 rows packed two to a word (hidden 2048 and
+4096) against the float32 rows, to the bit; the fit test's arithmetic and
+row form; the program at widths that keep float32 rows, pinned; the counter
+``expert_rows_packed``; and the property the in-place accumulation rests on
+— a block's tokens are distinct. What the chip's compiler says of the
+kernels is ``tests/test_tpu_compile.py``'s.
 """
 
 import functools
+import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -166,6 +171,138 @@ def test_pair_in_bfloat16_is_the_loop_in_bfloat16(monkeypatch, as_on_the_tpu):
                                    err_msg=name)
 
 
+# hidden, block: a row of one (8, 128) tile of words, and of two
+PACKED_CASES = {"hidden_2048": (2048, 16), "hidden_4096": (4096, 64)}
+
+
+@pytest.mark.parametrize("case", PACKED_CASES)
+def test_packed_rows_are_the_float32_rows_to_the_bit(case):
+    """bfloat16 ``x`` / ``dy`` at a hidden width of whole 2048s travel packed
+    two to a word; handed the same values widened to float32, the pair takes
+    float32 rows (the form before packing). Forward and backward, ``y``,
+    ``dx`` and ``dgate`` are equal to the bit: the unpacked words are the
+    values, every product sees the same operands in the same order. Against
+    the XLA loop, the bfloat16 case's tolerance (the float32 rows' sums run
+    in another order there)."""
+    hidden, block = PACKED_CASES[case]
+    t_len, held, inner, bf16 = 256, (0, 4), 128, jnp.bfloat16
+    experts, gates = routing(t_len, 16, seed=hidden)
+    ks = jax.random.split(jax.random.key(hidden), 5)
+    x, dy = (jax.random.normal(k, (t_len, hidden), bf16) for k in ks[:2])
+    wg, wu = (jax.random.normal(k, (held[1], hidden, inner), bf16)
+              * hidden ** -0.5 for k in ks[2:4])
+    wd = jax.random.normal(ks[4], (held[1], inner, hidden), bf16) * inner ** -0.5
+    tok, gate, tbl, _ = ds.expert_blocks(experts, gates, held, block)
+    for x_dtype, packed in ((bf16, True), (jnp.float32, False)):
+        assert ge.grouped_expert_tiles(tok.shape[0], hidden, inner, block, bf16,
+                                       x_dtype).packed is packed
+    ws = (wg, wu, wd)
+
+    @jax.jit
+    def kernels(x, dy):
+        y = ge.grouped_experts_forward(x, tok, gate, tbl, *ws, block,
+                                       interpret=True)
+        return (y,) + ge.grouped_experts_backward(x, dy, tok, gate, tbl, *ws,
+                                                  block, interpret=True)
+
+    got = kernels(x, dy)
+    wide = kernels(x.astype(jnp.float32), dy.astype(jnp.float32))
+    want = jax.jit(lambda x, dy: (ds._grouped_loop_fwd(x, tok, gate, tbl, *ws,
+                                                       block),)
+                   + ds._grouped_loop_bwd(x, dy, tok, gate, tbl, *ws, block))(
+                       x, dy)
+    assert [a.dtype for a in got] == [bf16, bf16, jnp.float32]
+    for name, a, b, c in zip(("y", "dx", "dgate"), got, wide, want):
+        b = b.astype(a.dtype)  # float32 x gets y / dx back in float32
+        a, b, c = (np.asarray(v, np.float32) for v in (a, b, c))
+        assert np.any(a), name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+        np.testing.assert_allclose(a, c, rtol=0, atol=2.0 ** -7 * np.abs(c).max(),
+                                   err_msg=name)
+
+
+def _normalised_jaxpr_digest(fn, *args) -> str:
+    """sha256 of ``fn``'s jaxpr text with addresses and source lines taken
+    out: what the program computes, not where its code sits."""
+    text = str(jax.make_jaxpr(fn)(*args))
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    text = re.sub(r"[\w/\.-]+\.py:\d+(:\d+)?", "SRC", text)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+# the pair's forward and its vjp through ``_grouped_swiglu`` on the chip's
+# branch, the kernels' bodies included, as the code read before bfloat16 rows
+# were packed: at widths that are no multiple of 2048 the rows stay float32
+# and the program must be that one, equation for equation — tokens, hidden,
+# all experts, held, inner, top-k: the DeepSeek cell's layer, and a 3072-wide one
+FLOAT32_ROW_DIGESTS = {
+    "deepseek_7168": ((16384, 7168, 256, 16, 2048, 8),
+                      "98cdeff8761bddcc71110fb726fef336"
+                      "a273ba68a6cec97bfd629dcfc9c16625"),
+    "hidden_3072": ((2048, 3072, 16, 4, 256, 8),
+                    "a5b662c347643021fde284a8c5078ada"
+                    "9ebc6ef80f466810bb9abb46ff13058e"),
+}
+
+
+@pytest.mark.parametrize("layer", FLOAT32_ROW_DIGESTS)
+def test_float32_rows_trace_to_the_program_they_were(monkeypatch, layer):
+    """Traced abstractly (no array is made) with ``jax.default_backend``
+    reading "tpu" and the model's ``EXPERT_BLOCK``: the expert pair at a
+    width the packed form does not take is the program pinned above."""
+    (t_len, hidden, _, held, inner, k), digest = FLOAT32_ROW_DIGESTS[layer]
+    block, bf16 = ds.EXPERT_BLOCK, jnp.bfloat16
+    assert not ge.grouped_expert_tiles(t_len * k, hidden, inner, block,
+                                       bf16).packed
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def fn(x, experts, gates, wg, wu, wd, w):
+        def f(x, gates):
+            tok, gate, tbl, _ = ds.expert_blocks(experts, gates, (0, held), block)
+            return ds._grouped_swiglu(x, tok, gate, tbl, wg, wu, wd, block)
+
+        y, vjp = jax.vjp(f, x, gates)
+        return (y,) + vjp(w)
+
+    S = jax.ShapeDtypeStruct
+    assert _normalised_jaxpr_digest(
+        fn, S((t_len, hidden), bf16), S((t_len, k), jnp.int32),
+        S((t_len, k), jnp.float32), S((held, hidden, inner), bf16),
+        S((held, hidden, inner), bf16), S((held, inner, hidden), bf16),
+        S((t_len, hidden), bf16)) == digest
+
+
+@pytest.mark.parametrize("hidden,packed,on_the_tpu", [
+    (2048, 1.0, True), (1024, 0.0, True), (2048, 0.0, False)])
+def test_expert_rows_packed_says_which_rows_the_pair_took(monkeypatch, hidden,
+                                                          packed, on_the_tpu):
+    """``held_expert_ffn``'s counter: 1 where the dispatch gives the pair
+    packed rows (bfloat16 at a width of whole 2048s, on the TPU), 0 where it
+    gives float32 rows or the XLA loop runs (the CPU). The kernels are
+    steered out — the XLA loop stands in for them — so only the dispatch's
+    choice is read."""
+    t_len, k, held, inner, bf16 = 256, 8, 4, 128, jnp.bfloat16
+    monkeypatch.setattr(ds, "EXPERT_BLOCK", 16)
+    if on_the_tpu:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ds, "grouped_experts_forward", ds._grouped_loop_fwd)
+    experts, gates = routing(t_len, 16, seed=1)
+    ks = jax.random.split(jax.random.key(2), 4)
+    swiglu = {"gate_proj": {"kernel": jax.random.normal(ks[0], (held, hidden,
+                                                                inner), bf16)},
+              "up_proj": {"kernel": jax.random.normal(ks[1], (held, hidden,
+                                                              inner), bf16)},
+              "down_proj": {"kernel": jax.random.normal(ks[2], (held, inner,
+                                                                hidden), bf16)}}
+    x = jax.random.normal(ks[3], (t_len, hidden), bf16)
+    tiles = ds._experts_kernel_tiles(x, jnp.zeros((t_len * k,), jnp.int32),
+                                     swiglu["gate_proj"]["kernel"], 16)
+    assert (tiles is not None) is on_the_tpu
+    counters = jax.jit(lambda x: ds.held_expert_ffn(
+        {"experts": swiglu}, x, experts, gates, (0, held))[2])(x)
+    assert float(counters["expert_rows_packed"]) == packed
+
+
 @pytest.mark.parametrize("seed", range(4))
 def test_an_experts_tokens_are_distinct(seed):
     """What the in-place accumulation rests on: whatever top-k routing
@@ -209,9 +346,15 @@ def test_the_fit_test_reads_shapes_only():
     bf16 = jnp.bfloat16
     hybrid = ge.grouped_expert_tiles(32768 * 10, 4096, 768, 384, bf16)
     other = ge.grouped_expert_tiles(16384 * 8, 7168, 2048, 384, bf16)
-    assert hybrid.inner == 768  # 3 x 6.3 MB: resident across an expert's blocks
+    keye = ge.grouped_expert_tiles(16384 * 8, 2048, 768, 384, bf16)
+    command = ge.grouped_expert_tiles(32768 * 8, 4096, 4096, 384, bf16)
+    assert hybrid.inner == keye.inner == 768  # resident across an expert's blocks
     assert other.inner < 2048 and 2048 % other.inner == 0
-    for tiles in (hybrid, other):
+    # 3 x 4096 x 2048 x 2 bytes, double-buffered, is past the budget alone
+    assert command.inner == 1024
+    assert [t.packed for t in (hybrid, other, keye, command)] == [
+        True, False, True, True]
+    for tiles in (hybrid, other, keye, command):
         assert max(tiles.fwd_vmem, tiles.bwd_vmem) <= ge._VMEM_BUDGET
     refused = [
         (2048, 64, 128, 8, jnp.float32),      # the tiny size: 64 wide
@@ -232,3 +375,26 @@ def test_the_fit_test_reads_shapes_only():
     with pytest.raises(ValueError, match="do not apply"):
         ge.grouped_experts_forward(x, tok, gate, dict(tbl, n=jnp.int32(0)), w, w,
                                    jnp.zeros((2, 128, 64)), 8)
+
+
+@pytest.mark.parametrize("hidden,packed", [
+    (1024, False), (2048, True), (3072, False), (4096, True), (7168, False)])
+def test_the_row_form_reads_the_width_and_the_dtype(hidden, packed):
+    """bfloat16 rows travel packed where a row of words is whole (8, 128)
+    tiles — ``hidden`` a multiple of 2048 — and as float32 elsewhere;
+    float32 ``x`` never packs. The choice is a function of the shapes and
+    dtypes handed in (plain numbers here, no array), and the packed form's
+    VMEM is the float32 form's less half of the ``x`` / ``dy`` buffers."""
+    bf16 = jnp.bfloat16
+    rows, inner, block = 4096 * 8, 256, 384
+    tiles = ge.grouped_expert_tiles(rows, hidden, inner, block, bf16)
+    assert tiles.packed is packed
+    assert tiles == ge.grouped_expert_tiles(rows, hidden, inner, block, bf16,
+                                            bf16)
+    wide = ge.grouped_expert_tiles(rows, hidden, inner, block, bf16,
+                                   jnp.float32)
+    assert not wide.packed and wide.inner == tiles.inner
+    slab = block * hidden * 4
+    assert (wide.fwd_vmem - tiles.fwd_vmem,
+            wide.bwd_vmem - tiles.bwd_vmem) == ((slab, 2 * slab) if packed
+                                                else (0, 0))

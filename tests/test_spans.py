@@ -948,7 +948,8 @@ def test_hybrid_token_model_main_emits_the_scopes_and_counters_read():
                          "lm.router", "lm.experts", "lm.shared_expert",
                          "lm.head_loss")
     assert gh.COUNTERS == ("expert_load_max_over_mean", "held_pair_share",
-                           "routed_over_shared", "ssd_state_rms")
+                           "routed_over_shared", "ssd_state_rms",
+                           "expert_rows_packed")
     metrics = _token_family_main_emits(gh, gh.GraniteHybridConfig.tiny(),
                                        "granite-4.0-h-small-s4-tune.yaml")
     assert metrics[0]["ssd_state_rms"] > 0
@@ -965,7 +966,8 @@ def test_third_token_family_main_emits_the_scopes_and_counters_read():
     assert cm.SCOPES == ("lm.window_attention", "lm.attention", "lm.router",
                          "lm.experts", "lm.shared_expert", "lm.head_loss")
     assert cm.COUNTERS == ("expert_load_max_over_mean", "held_pair_share",
-                           "routed_over_shared", "window_tile_share")
+                           "routed_over_shared", "window_tile_share",
+                           "expert_rows_packed")
     metrics = _token_family_main_emits(cm, cm.Cohere2MoeConfig.tiny(),
                                        "command-a-plus-s8-tune.yaml")
     assert metrics[0]["window_tile_share"] == 1.0
@@ -987,7 +989,7 @@ def test_fourth_token_family_main_emits_the_scopes_and_counters_read():
                          "lm.sparse_attention", "lm.router", "lm.experts",
                          "lm.head_loss")
     assert ky.COUNTERS == ("keys_selected_mean", "expert_load_max_over_mean",
-                           "held_pair_share")
+                           "held_pair_share", "expert_rows_packed")
     cfg = ky.KeyeVL2Config.tiny()
     scorer = ("lm.indexer", "lm.select")
     differentiated = types.SimpleNamespace(

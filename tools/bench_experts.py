@@ -1,16 +1,24 @@
-"""The grouped expert loop at the two token cells' shapes: the Pallas pair of
+"""The grouped expert loop at the token cells' shapes: the Pallas pair of
 ``ops/grouped_experts.py`` against the XLA loop of ``models/deepseek.py``.
 
 For each shape — ``hybrid`` (32768 tokens x 4096, 18 of 72 experts 768 wide,
-top-10: ``granite-4.0-h-small-s4-tune.doc32k-steps``) and ``other`` (16384 x
-7168, 16 of 256 experts 2048 wide, top-8: ``deepseek-v32-s16-tune.doc16k-steps``)
-— a level random routing, the block table ``expert_blocks`` builds, and per
-``EXPERT_BLOCK`` given (default: the model's), at the tiles the fit test
-chooses, the wall time a call of the forward and of the backward kernel
-(warm, ``REPS`` calls, ready at the end), the same of the XLA loop, and the
-kernels' results against the XLA loop's on this device — the check that
-interpret mode cannot make: on the chip the copies are really asynchronous
-(PERF.md §6, PR 33).
+top-10: ``granite-4.0-h-small-s4-tune.doc32k-steps``), ``other`` (16384 x
+7168, 16 of 256 experts 2048 wide, top-8: ``deepseek-v32-s16-tune.doc16k-steps``),
+``keye`` (16384 x 2048, 128 of 128 experts 768 wide, top-8:
+``keye-vl2-30b-a3b-s1-tune.doc16k-steps``) and ``command`` (32768 x 4096, 16 of
+128 experts 4096 wide, top-8: ``command-a-plus-s8-tune.doc32k-steps``) — a
+level random routing, the block table ``expert_blocks`` builds, and per
+``EXPERT_BLOCK`` given (default: the model's), at the tiles and row form the
+fit test chooses: the bytes the row streams move a call (``x`` / ``dy`` in,
+the float32 accumulator in and out, for the rows held), the wall time a call
+of the forward and of the backward kernel (warm, ``REPS`` calls, ready at the
+end), the same of the XLA loop, and the kernels' results against the XLA
+loop's on this device — the check that interpret mode cannot make: on the
+chip the copies are really asynchronous (PERF.md §6, PR 33). Where the rows
+travel packed (bfloat16 pairs in 32-bit words, ``hidden`` a multiple of
+2048) the pair is also run on the float32 rows of the same values (``x`` /
+``dy`` widened, the form the kernels take elsewhere), timed, and its results
+compared with the packed form's to the bit.
 
 ``split <trace dir>`` reads a traced call a benchmark run left behind
 (``outputs/bench/<cell>/trace``) and prints the device self time under the
@@ -19,7 +27,7 @@ nesting of events: what the loop's gathers, scatter-adds, products and
 kernels each take.
 
 Usage (from the root of the checkout):
-       python tools/bench_experts.py [hybrid] [other] [block ...]
+       python tools/bench_experts.py [hybrid] [other] [keye] [command] [block ...]
        python tools/bench_experts.py split <trace dir>
 """
 
@@ -41,7 +49,9 @@ from videop2p_tpu.ops import grouped_experts as ge  # noqa: E402
 
 # tokens, hidden, all experts, held, inner, top-k
 SHAPES = {"hybrid": (32768, 4096, 72, 18, 768, 10),
-          "other": (16384, 7168, 256, 16, 2048, 8)}
+          "other": (16384, 7168, 256, 16, 2048, 8),
+          "keye": (16384, 2048, 128, 128, 768, 8),
+          "command": (32768, 4096, 128, 16, 4096, 8)}
 REPS = 3
 
 
@@ -84,17 +94,38 @@ def gap(name, a, b):
           f"{float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))):.6f}", flush=True)
 
 
+def stream_bytes(rows, hidden, packed):
+    """Bytes the row streams move for ``rows`` held rows: the forward's
+    ``x`` in and accumulator in and out, the backward's ``x``, ``dy`` in and
+    accumulator in and out."""
+    x = rows * hidden * (2 if packed else 4)
+    acc = rows * hidden * 4
+    return x + 2 * acc, 2 * x + 2 * acc
+
+
+def same(name, a, b):
+    print(f"    {name:6s} packed == float32 rows, to the bit: "
+          f"{bool(jnp.array_equal(a, b.astype(a.dtype)))}", flush=True)
+
+
 def bench(name, block):
     x, dy, experts, gates, ws, held = operands(name)
     tok, gate, tbl, count = jax.jit(
         lambda e, g: ds.expert_blocks(e, g, held, block))(experts, gates)
     tiles = ge.grouped_expert_tiles(tok.shape[0], x.shape[1], ws[0].shape[2],
-                                    block, ws[0].dtype)
+                                    block, ws[0].dtype, x.dtype)
     print(f"{name}: block {block}, {int(tbl['n'])} live blocks of "
           f"{tbl['expert'].shape[0]}, rows an expert {int(count.min())}–"
           f"{int(count.max())}, tiles {tiles}", flush=True)
     if tiles is None:
         return
+    rows = int(count.sum())
+    for form, packed in (("packed", True), ("float32", False)):
+        if packed and not tiles.packed:
+            continue
+        f, b = stream_bytes(rows, x.shape[1], packed)
+        print(f"  row streams, {form} rows: forward {f / 1e9:.3f} GB, backward "
+              f"{b / 1e9:.3f} GB ({rows} held rows)", flush=True)
 
     def fwd(x, tok, gate, tbl, *ws):
         return ge.grouped_experts_forward(x, tok, gate, tbl, *ws, block)
@@ -104,6 +135,17 @@ def bench(name, block):
 
     y = timed("lm_grouped_experts", fwd, x, tok, gate, tbl, *ws)
     grads = timed("lm_grouped_experts_bwd", bwd, x, dy, tok, gate, tbl, *ws)
+    if tiles.packed:
+        x32, dy32 = x.astype(jnp.float32), dy.astype(jnp.float32)
+        y32 = timed("lm_grouped_experts, float32 rows", fwd, x32, tok, gate,
+                    tbl, *ws)
+        grads32 = timed("lm_grouped_experts_bwd, float32 rows", bwd, x32, dy32,
+                        tok, gate, tbl, *ws)
+        if y is not None and y32 is not None:
+            same("y", y, y32)
+        if grads is not None and grads32 is not None:
+            same("dx", grads[0], grads32[0])
+            same("dgate", grads[1], grads32[1])
     want_y = timed("XLA loop forward",
                    lambda *a: ds._grouped_loop_fwd(*a, block),
                    x, tok, gate, tbl, *ws)

@@ -108,7 +108,7 @@ __all__ = [
 SCOPES = ("lm.window_attention", "lm.attention", "lm.router", "lm.experts",
           "lm.shared_expert", "lm.head_loss")
 COUNTERS = ("expert_load_max_over_mean", "held_pair_share",
-            "routed_over_shared", "window_tile_share")
+            "routed_over_shared", "window_tile_share", "expert_rows_packed")
 
 _SLIDING, _FULL = "sliding_attention", "full_attention"
 _PUBLISHED_LAYERS = ((_SLIDING,) * 3 + (_FULL,)) * 8

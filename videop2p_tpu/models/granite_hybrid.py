@@ -128,7 +128,7 @@ __all__ = [
 SCOPES = ("lm.mamba_proj", "lm.ssd", "lm.attention", "lm.router",
           "lm.experts", "lm.shared_expert", "lm.head_loss")
 COUNTERS = ("expert_load_max_over_mean", "held_pair_share",
-            "routed_over_shared", "ssd_state_rms")
+            "routed_over_shared", "ssd_state_rms", "expert_rows_packed")
 
 # How the work is cut (no effect on the mathematics). Not configuration:
 # one value is in use, tests patch them.

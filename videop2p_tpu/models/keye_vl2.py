@@ -106,7 +106,7 @@ __all__ = [
 SCOPES = ("lm.attn_proj", "lm.indexer", "lm.select", "lm.sparse_attention",
           "lm.router", "lm.experts", "lm.head_loss")
 COUNTERS = ("keys_selected_mean", "expert_load_max_over_mean",
-            "held_pair_share")
+            "held_pair_share", "expert_rows_packed")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -21,14 +21,26 @@ and back, and the matrices stay in VMEM while an expert's blocks pass:
 **How a row travels.** The chip's DMA moves whole (8, 128) tiles of 32-bit
 words, and one row of a ``(T, hidden)`` array is a one-sublane sliver of
 ``hidden / 128`` tiles (the compiler refuses the slice). So both kernels see
-the row arrays as ``(T * hidden / 128, 128)`` float32, the plain row-major
-reshape: token ``t`` is the ``R = hidden / 128`` sublanes from ``R t``, whole
-tiles in one piece, and sublane ``c`` of them holds its columns ``128 c ..``.
-A block in VMEM is ``(block * R, 128)``; lane tile ``c`` of all its rows is
-one strided read (every ``R``-th sublane from ``c``), and the tiles side by
-side are the ``(block, hidden)`` matmul operand. The callers make that view
-(``x`` and ``dy`` widened to float32 — exact) and undo it with the cast the
-XLA loop ends on.
+the row arrays as ``(T * R, 128)`` 32-bit words, the plain row-major reshape:
+token ``t`` is the ``R`` sublanes from ``R t``, whole tiles in one piece. A
+block in VMEM is ``(block * R, 128)``; lane tile ``c`` of all its rows is one
+strided read (every ``R``-th sublane from ``c``). Two row forms, ``R`` a
+stream:
+
+  * **float32** (``R = hidden / 128``): sublane ``c`` holds columns
+    ``128 c ..``; the tiles side by side are the ``(block, hidden)`` operand.
+    The accumulators ``y`` / ``dx`` always, and ``x`` / ``dy`` widened
+    (exact) where the packed form does not apply.
+  * **packed** bfloat16 ``x`` / ``dy`` where ``hidden`` is a multiple of
+    2048 (``R = hidden / 256``, a row of whole tiles): a uint32 word holds
+    column ``j`` in its low half and ``j + hidden / 2`` in its high half, so
+    the low halves of the tiles side by side, then the high halves, are the
+    columns in their order. A bfloat16 is the high half of the float32 of
+    the same value: each half shifted there is its value, exactly. Half the
+    bytes of the float32 form a row.
+
+The callers make the views and undo the accumulators' with the cast the XLA
+loop ends on.
 
 **What flies when, and what it costs.** A row copy costs its bytes — 45 ns
 a 16 KB row with one stream in flight, 30 ns with three (370–560 GB/s, my
@@ -89,6 +101,9 @@ _GROUP = _SUBLANES * _LANES
 _VMEM_BUDGET = 100 * 1024 * 1024
 # row copies started a trip of the issuing loop
 _ISSUE_UNROLL = 8
+# bfloat16 rows travel packed two to a word where a row is whole (8, 128)
+# tiles of words: hidden a multiple of 2 x 8 x 128
+_PACKED_WIDTH = 2 * _SUBLANES * _LANES
 
 _NN = (((1,), (0,)), ((), ()))
 _NT = (((1,), (1,)), ((), ()))
@@ -98,6 +113,7 @@ class ExpertTiles(NamedTuple):
     inner: int  # columns of the inner dimension a grid step
     fwd_vmem: int  # bytes the forward's grid step holds
     bwd_vmem: int  # bytes the backward's
+    packed: bool  # x and dy rows as bfloat16 pairs in uint32 words
 
 
 def _group_rows(block: int) -> int:
@@ -107,14 +123,14 @@ def _group_rows(block: int) -> int:
 
 
 def _vmem_bytes(hidden: int, tile: int, block: int, itemsize: int,
-                backward: bool) -> int:
+                backward: bool, packed: bool = False) -> int:
     """VMEM of one grid step, from its shapes: over what the chip's compiler
     asks for by 3–12 MiB at both cells' shapes and blocks of 256–512 (found
     by halving the limit until it refused, PERF.md §6, PR 33)."""
     weights = 2 * 3 * hidden * tile * itemsize  # three matrices, two buffers
     slab = block * hidden * 4
     # x (and dy) for this block and the next, the accumulator's rows
-    rows = (5 if backward else 3) * slab
+    rows = (4 if backward else 2) * (slab // 2 if packed else slab) + slab
     # the operand block(s) and the float32 (block, hidden) products as the
     # compiler keeps them: a slab and a half, two in the backward
     values = (4 if backward else 3) * slab // 2 + (2 << 20)
@@ -122,32 +138,37 @@ def _vmem_bytes(hidden: int, tile: int, block: int, itemsize: int,
 
 
 def grouped_expert_tiles(rows: int, hidden: int, inner: int, block: int,
-                         dtype) -> Optional[ExpertTiles]:
+                         dtype, x_dtype=None) -> Optional[ExpertTiles]:
     """The inner-dimension tile of the kernel pair for ``rows`` sorted
     (token, expert) rows of ``hidden`` columns, experts ``inner`` wide, blocks
-    of ``block`` rows, matrices of ``dtype`` — the widest tile of ``inner``
-    (the whole of it first) whose backward fits the VMEM budget — or None
-    where the pair does not apply: ``hidden`` not eight lane tiles a row,
-    ``inner`` off the lane tiles, ``block`` off the operand's sublane tile,
-    ``rows`` not whole groups of 1024, or no tile that fits. The one fit test
-    the model's dispatch and the calls' ``vmem_limit_bytes`` share."""
+    of ``block`` rows, matrices of ``dtype``, ``x`` (and ``dy``) of
+    ``x_dtype`` (the matrices' where None) — the widest tile of ``inner``
+    (the whole of it first) whose backward fits the VMEM budget, and the rows'
+    form: packed where ``x`` is bfloat16 and ``hidden`` a multiple of 2048 —
+    or None where the pair does not apply: ``hidden`` not eight lane tiles a
+    row, ``inner`` off the lane tiles, ``block`` off the operand's sublane
+    tile, ``rows`` not whole groups of 1024, or no tile that fits. The one
+    fit test the model's dispatch and the calls' ``vmem_limit_bytes``
+    share."""
     itemsize = jnp.dtype(dtype).itemsize
     if (hidden % (_SUBLANES * _LANES) or inner % _LANES or itemsize not in (2, 4)
             or block % (_SUBLANES * 4 // itemsize) or rows % _GROUP
             or rows // _LANES < _group_rows(block)):
         return None
+    packed = (jnp.dtype(dtype if x_dtype is None else x_dtype) == jnp.bfloat16
+              and hidden % _PACKED_WIDTH == 0)
     for tile in sorted({inner} | {t for t in (1024, 512, 256, 128)
                                   if inner % t == 0}, reverse=True):
-        need = [_vmem_bytes(hidden, tile, block, itemsize, bw)
+        need = [_vmem_bytes(hidden, tile, block, itemsize, bw, packed)
                 for bw in (False, True)]
         if max(need) <= _VMEM_BUDGET:
-            return ExpertTiles(tile, *need)
+            return ExpertTiles(tile, *need, packed)
     return None
 
 
 def _tiles_of(x, tok, wg, block) -> ExpertTiles:
     tiles = grouped_expert_tiles(tok.shape[0], x.shape[1], wg.shape[2], block,
-                                 wg.dtype)
+                                 wg.dtype, x.dtype)
     if tiles is None:
         raise ValueError(
             f"the grouped expert kernels do not apply to x {x.shape}, "
@@ -174,11 +195,10 @@ def _start_rows(valid, tok_s, place, block: int, copies):
     runs ``_ISSUE_UNROLL`` rows a trip, and the rows left over one by one."""
     from jax.experimental.pallas import tpu as pltpu
 
-    per_row = copies[0][1].shape[0] // block
-
     def row(r):
         t = tok_s[lax.div(place + r, _LANES), lax.rem(place + r, _LANES)]
         for hbm, vmem, sem, to_hbm in copies:
+            per_row = vmem.shape[0] // block  # the stream's own row form
             src, dst = hbm.at[_slab(t, per_row)], vmem.at[_slab(r, per_row)]
             if to_hbm:
                 src, dst = dst, src
@@ -230,13 +250,24 @@ def _column(group, place, block: int):
 
 def _block_value(buf, block: int, dtype):
     """The (block, hidden) block a slab buffer holds, in ``dtype``: lane tile
-    ``c`` of every row is every ``R``-th sublane from ``c``."""
+    ``c`` of every row is every ``R``-th sublane from ``c``; a packed
+    buffer's words give the low halves' columns, then the high halves'."""
     from jax.experimental import pallas as pl
 
     per_row = buf.shape[0] // block
+
+    def tile(c):
+        return buf[pl.ds(c, block, stride=per_row), :]
+
+    if buf.dtype != jnp.uint32:
+        return jnp.concatenate([tile(c).astype(dtype) for c in range(per_row)],
+                               axis=1)
+    words = [tile(c) for c in range(per_row)]
+    halves = ([w << 16 for w in words]
+              + [w & jnp.uint32(0xFFFF0000) for w in words])
     return jnp.concatenate(
-        [buf[pl.ds(c, block, stride=per_row), :].astype(dtype)
-         for c in range(per_row)], axis=1)
+        [lax.bitcast_convert_type(h, jnp.float32).astype(dtype) for h in halves],
+        axis=1)
 
 
 def _add_to_block(buf, value, block: int):
@@ -438,10 +469,16 @@ def _table(tbl):
             jnp.reshape(tbl["n"], (1,)).astype(jnp.int32))
 
 
-def _rows_view(a):
-    """(T, hidden) → (T * hidden / 128, 128) float32: a row as one aligned
-    slab of whole tiles."""
-    return a.astype(jnp.float32).reshape(-1, _LANES)
+def _rows_view(a, packed: bool):
+    """(T, hidden) → (T * hidden / 128, 128) float32, or where ``packed``
+    bfloat16 (T * hidden / 256, 128) uint32, column ``j`` in a word's low
+    half and ``j + hidden / 2`` in its high half: a row as one aligned slab
+    of whole tiles."""
+    if not packed:
+        return a.astype(jnp.float32).reshape(-1, _LANES)
+    bits = lax.bitcast_convert_type(a, jnp.uint16).astype(jnp.uint32)
+    half = a.shape[1] // 2
+    return (bits[:, :half] | (bits[:, half:] << 16)).reshape(-1, _LANES)
 
 
 def _lanes_view(a):
@@ -449,16 +486,23 @@ def _lanes_view(a):
     return a.reshape(-1, _LANES)
 
 
-def _scratch(block: int, per_row: int, group_rows: int, row_buffers,
-             streams: int):
+def _row_forms(hidden: int, tiles: ExpertTiles):
+    """``(sublanes a row, dtype)`` of the ``x`` / ``dy`` streams and of the
+    accumulator's."""
+    acc = (hidden // _LANES, jnp.float32)
+    return ((hidden // (2 * _LANES), jnp.uint32) if tiles.packed else acc), acc
+
+
+def _scratch(block: int, group_rows: int, row_buffers, streams: int):
     """The groups of ``tok`` (SMEM) and ``gate`` of three blocks in a row,
-    the slab buffers (``row_buffers``: how many of each), the semaphores."""
+    the slab buffers (``row_buffers``: ``(how many, (sublanes a row,
+    dtype))`` of each), the semaphores."""
     from jax.experimental.pallas import tpu as pltpu
 
     return ([pltpu.SMEM((3, group_rows, _LANES), jnp.int32),
              pltpu.VMEM((3, group_rows, _LANES), jnp.float32)]
-            + [pltpu.VMEM((n, block * per_row, _LANES), jnp.float32)
-               for n in row_buffers]
+            + [pltpu.VMEM((n, block * per_row, _LANES), dtype)
+               for n, (per_row, dtype) in row_buffers]
             + [pltpu.SemaphoreType.DMA((1 + streams, 2))])
 
 
@@ -473,7 +517,7 @@ def _forward_call(table, tok, gate, x, wg, wu, wd, y, *, block: int,
     from jax.experimental.pallas import tpu as pltpu
 
     hidden = wg.shape[1]
-    per_row = hidden // _LANES
+    rows, acc = _row_forms(hidden, tiles)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, block=block),
         # explicit name: the compiled program's custom call and the trace
@@ -483,7 +527,7 @@ def _forward_call(table, tok, gate, x, wg, wu, wd, y, *, block: int,
         grid_spec=_grid_spec(
             table[0].shape[0], hidden, wg.shape[2], tiles.inner, 3, 1,
             pl.BlockSpec(memory_space=pl.ANY),
-            _scratch(block, per_row, _group_rows(block), (2, 1), 3)),
+            _scratch(block, _group_rows(block), ((2, rows), (1, acc)), 3)),
         # the float32 accumulator is updated in place (operand 10 of 11,
         # the four table columns counted)
         input_output_aliases={10: 0},
@@ -504,9 +548,10 @@ def grouped_experts_forward(x, tok, gate, tbl, wg, wu, wd, block: int,
     inner) and ``wd`` (experts, inner, hidden) stacked. Raises where
     :func:`grouped_expert_tiles` refuses the shape."""
     tiles = _tiles_of(x, tok, wg, block)
-    xv = _rows_view(x)
+    xv = _rows_view(x, tiles.packed)
     y = _forward_call(_table(tbl), _lanes_view(tok), _lanes_view(gate), xv,
-                      wg, wu, wd, jnp.zeros(xv.shape, jnp.float32),
+                      wg, wu, wd, jnp.zeros((x.size // _LANES, _LANES),
+                                            jnp.float32),
                       block=block, tiles=tiles, interpret=interpret)
     return y.reshape(x.shape).astype(x.dtype)
 
@@ -593,9 +638,10 @@ def _backward_call(table, tok, gate, x, dy, wg, wu, wd, dx, dgate, *,
     from jax.experimental.pallas import tpu as pltpu
 
     hidden = wg.shape[1]
-    per_row, group_rows = hidden // _LANES, _group_rows(block)
+    rows, acc = _row_forms(hidden, tiles)
+    group_rows = _group_rows(block)
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    scratch = _scratch(block, per_row, group_rows, (2, 2, 1), 5)
+    scratch = _scratch(block, group_rows, ((2, rows), (2, rows), (1, acc)), 5)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, block=block),
         name="lm_grouped_experts_bwd",
@@ -624,9 +670,10 @@ def grouped_experts_backward(x, dy, tok, gate, tbl, wg, wu, wd, block: int,
     ``dgate`` (rows,) float32, zero on the rows no held expert has. Equal to
     ``models.deepseek``'s XLA loop's backward; the matrices are frozen."""
     tiles = _tiles_of(x, tok, wg, block)
-    xv, tok_v = _rows_view(x), _lanes_view(tok)
+    xv, tok_v = _rows_view(x, tiles.packed), _lanes_view(tok)
     dx, dgate = _backward_call(
-        _table(tbl), tok_v, _lanes_view(gate), xv, _rows_view(dy), wg, wu, wd,
-        jnp.zeros(xv.shape, jnp.float32), jnp.zeros(tok_v.shape, jnp.float32),
+        _table(tbl), tok_v, _lanes_view(gate), xv, _rows_view(dy, tiles.packed),
+        wg, wu, wd, jnp.zeros((x.size // _LANES, _LANES), jnp.float32),
+        jnp.zeros(tok_v.shape, jnp.float32),
         block=block, tiles=tiles, interpret=interpret)
     return dx.reshape(x.shape).astype(x.dtype), dgate.reshape(-1)
